@@ -1,7 +1,8 @@
 """ctypes bindings for the C++ packing/transport sidecar.
 
 Builds native/sidecar.cpp on first use (g++ -O3 -shared, cached in the
-source tree next to the .cpp) and exposes:
+source tree next to the .cpp as ``libctsidecar-<sha12>.so``, keyed on
+the source's contents) and exposes:
 
 - scatter_time_major / scatter_batch_major — fused pad+layout of ragged
   event rows into the dense tensors the replay scan consumes
@@ -16,6 +17,7 @@ which path is live), and the test suite runs both differentially.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,7 +29,16 @@ _SRC = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
     "native", "sidecar.cpp",
 )
-_LIB_PATH = os.path.join(os.path.dirname(_SRC), "libctsidecar.so")
+
+
+def _lib_path() -> str:
+    """The library built from THIS sidecar.cpp: the name carries a hash
+    of the source, so a .so built from other source (a stale build, or
+    one copied in from another tree) is never loaded."""
+    with open(_SRC, "rb") as f:
+        sha = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(os.path.dirname(_SRC), f"libctsidecar-{sha}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -35,22 +46,21 @@ HAVE_NATIVE = False
 
 
 def _build() -> Optional[str]:
-    if os.path.exists(_LIB_PATH) and (
-        os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC)
-    ):
-        return _LIB_PATH
+    lib_path = _lib_path()
+    if os.path.exists(lib_path):
+        return lib_path
     # compile to a temp path and rename atomically: a killed compile or
     # two processes racing must never leave a half-written .so that
-    # every later process accepts (fresh mtime) and fails to dlopen
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    # every later process accepts and fails to dlopen
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
              "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
-        os.replace(tmp, _LIB_PATH)
-        return _LIB_PATH
+        os.replace(tmp, lib_path)
+        return lib_path
     except Exception:
         try:
             os.unlink(tmp)

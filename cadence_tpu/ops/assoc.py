@@ -3,7 +3,7 @@ transition updates.
 
 The sequential replay scan (ops/replay.py) pays O(T) *depth*: one
 ``lax.scan`` step per event, each a full pass over the state carry.
-BENCH_r05 shows per-step cost is ~flat in batch width on CPU, so deep
+A pre-PR-1 CPU run showed per-step cost ~flat in batch width, so deep
 histories (retry_deep at 1k events, ndc_storm) are bound by scan depth
 alone. But the transition function is composable: for every event the
 kernel's update to each state cell is an *affine* map

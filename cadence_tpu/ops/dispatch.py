@@ -364,12 +364,9 @@ class DeviceDispatcher:
         open ROADMAP item)."""
         if use_pallas or self.scan_mode == "scan":
             return False
-        try:
-            import jax
+        import jax
 
-            return jax.default_backend() != "tpu"
-        except Exception:
-            return False
+        return jax.default_backend() != "tpu"
 
     def _assoc_hist(self, use_pallas: bool, present) -> bool:
         """Should this unpacked batch ride the associative kernel?
@@ -655,12 +652,9 @@ class DeviceDispatcher:
 
     def _use_pallas(self) -> bool:
         if self._kernel == "auto":
-            try:
-                import jax
+            import jax
 
-                return jax.default_backend() == "tpu"
-            except Exception:
-                return False
+            return jax.default_backend() == "tpu"
         return self._kernel == "pallas"
 
     # -- consumer side ----------------------------------------------------

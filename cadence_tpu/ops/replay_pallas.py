@@ -47,11 +47,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 from cadence_tpu.core.enums import (
     CloseStatus, EventType as E, WorkflowState,
     WORKFLOW_CLOSE_STATUS, decision_attempt_increment,
@@ -157,12 +152,12 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
 
     The batch tile is shaped (SL, 128) with SL a multiple of 8 — whole
     int32 VPU tiles — so every row update runs at full sublane x lane
-    utilization (a flat [BT] row would occupy 1 of 8 sublanes). With
-    forced-materialization timing the kernel is bound by streaming the
-    event blocks from HBM, not by the step body: an empty-body ablation
-    (ablate=5) measures the same wall time as the full FSM at B=65536
-    (scripts/probe4.py, v5e, 2026-07), so SL mainly trades VMEM for
-    fewer grid steps; bt=8192 (SL=64) measured best.
+    utilization (a flat [BT] row would occupy 1 of 8 sublanes). A
+    pre-PR-1 v5e run found the kernel bound by streaming the event
+    blocks from HBM, not by the step body (an empty-body ablation,
+    ablate=5, took the same wall time as the full FSM at B=65536), so
+    SL mainly trades VMEM for fewer grid steps; bt=8192 (SL=64) was
+    best there. Neither is re-measured yet (PERF.md).
 
     presence_ref: [1, TB, 4] SMEM — per-step scalar gates for this
              tile: words 0-1 are the event-type bitmask (bit e of word
@@ -765,7 +760,7 @@ def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
                                memory_space=pltpu.VMEM),
         # double-buffered blocks (events x2, init x2, out x2) exceed the
         # 16MiB default scoped-vmem budget once n_bt > 1; v5e has 128MiB
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(presence, base2, ev5, rows5)
@@ -1067,10 +1062,13 @@ def replay_scan_pallas(
 
 def _affine_scan_kernel(mul_ref, add_ref, rst_ref, om_ref, oa_ref,
                         mc, ac, *, tb: int):
-    """One time block: mul/add [TB, L, C], rst [TB, L]; scratch carries
-    the running (mul, add) composition [L, C] across grid steps."""
+    """One (lane tile, time block): mul/add [TB, C, BL], rst [TB, 1, BL];
+    scratch carries the running (mul, add) composition [C, BL] across
+    the time blocks of a lane tile. Lanes are the minor (vector-lane)
+    dimension and the reset row broadcasts over the C sublanes — Mosaic
+    has no cheap [L] -> [L, 1] relayout, so the kernel never reshapes."""
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _():
         mc[...] = jnp.ones(mc.shape, jnp.int32)
         ac[...] = jnp.zeros(ac.shape, jnp.int32)
@@ -1078,7 +1076,7 @@ def _affine_scan_kernel(mul_ref, add_ref, rst_ref, om_ref, oa_ref,
     def step(i, carry):
         m = mul_ref[i]
         a = add_ref[i]
-        rb = (rst_ref[i] != 0)[:, None]
+        rb = rst_ref[i] != 0
         # segment starts absorb the carry (the segmented combine)
         pm = jnp.where(rb, m, mc[...] * m)
         pa = jnp.where(rb, a, ac[...] * m + a)
@@ -1091,6 +1089,17 @@ def _affine_scan_kernel(mul_ref, add_ref, rst_ref, om_ref, oa_ref,
     lax.fori_loop(0, tb, step, 0)
 
 
+def _affine_lane_tile(L: int, C: int, tb: int) -> int:
+    """Widest lane tile (multiple of 128) dividing L whose double-
+    buffered blocks (4 of [tb, C, bl] plus the [tb, 1, bl] reset, each
+    padded to 8 sublanes) fit 12 MiB of the 16 MiB default scoped VMEM."""
+    rows = 4 * (-(-C // 8) * 8) + 8
+    for bl in (1024, 512, 256, 128):
+        if L % bl == 0 and 2 * tb * rows * bl * 4 <= 12 << 20:
+            return bl
+    return L
+
+
 def affine_segscan_pallas(mul, add, rst, tb: int = 8,
                           interpret: bool | None = None):
     """Segmented inclusive prefix composition of affine updates.
@@ -1099,34 +1108,35 @@ def affine_segscan_pallas(mul, add, rst, tb: int = 8,
     segment). Returns (mul, add) prefixes — bit-identical to
     ops.assoc.affine_segscan over the same stream
     (tests/test_replay_pallas.py). ``T`` must be a multiple of ``tb``.
+    The kernel runs lane-dense on [T, C, L] (the transposes fuse with
+    the caller's own layout change in ops/assoc.py).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     T, L, C = mul.shape
     if T % tb:
         raise ValueError(f"T={T} not a multiple of tb={tb}")
-    grid = (T // tb,)
+    bl = _affine_lane_tile(L, C, tb)
+    mul_t = jnp.transpose(jnp.asarray(mul, jnp.int32), (0, 2, 1))
+    add_t = jnp.transpose(jnp.asarray(add, jnp.int32), (0, 2, 1))
+    rst_t = jnp.asarray(rst, jnp.int32)[:, None, :]
+    blk = pl.BlockSpec((tb, C, bl), lambda l, t: (t, 0, l))
     om, oa = pl.pallas_call(
         functools.partial(_affine_scan_kernel, tb=tb),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, L, C), lambda t: (t, 0, 0)),
-            pl.BlockSpec((tb, L, C), lambda t: (t, 0, 0)),
-            pl.BlockSpec((tb, L), lambda t: (t, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, L, C), lambda t: (t, 0, 0)),
-            pl.BlockSpec((tb, L, C), lambda t: (t, 0, 0)),
-        ],
+        grid=(L // bl, T // tb),
+        in_specs=[blk, blk,
+                  pl.BlockSpec((tb, 1, bl), lambda l, t: (t, 0, l))],
+        out_specs=[blk, blk],
         out_shape=[
-            jax.ShapeDtypeStruct((T, L, C), jnp.int32),
-            jax.ShapeDtypeStruct((T, L, C), jnp.int32),
+            jax.ShapeDtypeStruct((T, C, L), jnp.int32),
+            jax.ShapeDtypeStruct((T, C, L), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((L, C), jnp.int32),
-            pltpu.VMEM((L, C), jnp.int32),
+            pltpu.VMEM((C, bl), jnp.int32),
+            pltpu.VMEM((C, bl), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(mul, jnp.int32), jnp.asarray(add, jnp.int32),
-      jnp.asarray(rst, jnp.int32))
-    return om, oa
+    )(mul_t, add_t, rst_t)
+    return (jnp.transpose(om, (0, 2, 1)), jnp.transpose(oa, (0, 2, 1)))

@@ -13,7 +13,7 @@ Two logical axes:
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -22,20 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SHARD_AXIS = "shard"
 SEQ_AXIS = "seq"
-
-
-# shard_map moved out of jax.experimental, and its replication-check
-# kwarg was renamed check_rep -> check_vma, across jax releases; this
-# shim presents the new-style surface on either.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_legacy(*args, **kwargs)
 
 
 def make_mesh(

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
-
 from cadence_tpu.ops import schema as S
 from cadence_tpu.ops.replay import replay_scan
 
@@ -93,7 +91,7 @@ def _pipelined_fn(mesh: Mesh, n_micro: int):
         lambda _: P(SHARD_AXIS), S.empty_state(1, S.Capacities())
     )
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             pipe,
             mesh=mesh,
             in_specs=(P(SEQ_AXIS, SHARD_AXIS), state_spec),

@@ -29,7 +29,7 @@ from cadence_tpu.ops.pack import PackedHistories
 from cadence_tpu.ops.refresh import RefreshedTasks, refresh_tasks_device
 from cadence_tpu.ops.replay import replay_scan
 
-from .mesh import SHARD_AXIS, events_spec, shard_map, shard_spec
+from .mesh import SHARD_AXIS, events_spec, shard_spec
 
 
 def _state_specs(sharding: NamedSharding) -> S.StateTensors:
@@ -60,7 +60,7 @@ def replay_sharded_fn(mesh: Mesh, scan_mode: str = "scan"):
             final = _assoc_core(events_fm, state)
             return final, refresh_tasks_device(final)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             step_local,
             mesh=mesh,
             in_specs=(P(SHARD_AXIS), P(None, SHARD_AXIS, None)),
@@ -168,7 +168,7 @@ def _ndc_exchange_fn(mesh: Mesh):
         return all_digest, all_vh, all_vh_len, replayed, max_version
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             exchange,
             mesh=mesh,
             in_specs=(spec_in, spec_in, spec_in),

@@ -232,7 +232,7 @@ class ResidentEngine:
         self._tick_no = 0
         # the resident store: one [S]-row StateTensors, rows scattered
         # in place under the lock (device-resident on TPU deployments;
-        # host numpy on the CPU fallback — same O(Δ) discipline)
+        # host numpy on the CPU backend — same O(Δ) discipline)
         self._state = S.empty_state(self.lanes, self.caps)
 
     # ------------------------------------------------------------------
