@@ -26,6 +26,13 @@ signature are all rounded/grown monotonically (``round_scan_len``,
 ``_type_set``) so a storm of arbitrary chunk shapes compiles a bounded
 set of executables.
 
+Tracing: ``submit`` captures the caller's trace context
+(utils/tracing.py) and each pump opens its spans under it on its own
+thread — ``dispatch.pack`` (host packing and layout), ``dispatch.h2d``
+(the transfers of one batch) and ``dispatch.launch`` (the kernel call,
+tagged with the batch's real ``events`` and the kernel's streamed
+``cells``). Unsampled, each is one thread-local read.
+
 Used by the replication rebuild path for storm-sized request streams
 (runtime/replication/rebuilder.py rebuild_many) and usable standalone::
 
@@ -45,6 +52,7 @@ import time as _time
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 from cadence_tpu.utils.metrics import NOOP, Scope
+from cadence_tpu.utils.tracing import TRACER
 
 from . import schema as S
 from .grid import round_scan_len, staging_depth
@@ -151,15 +159,15 @@ class DeviceDispatcher:
         metrics: Optional[Scope] = None,
     ) -> None:
         self.caps = caps or S.Capacities()
-        # device-step telemetry (utils/metrics_defs.py DEVICE_METRICS):
-        # per-batch stage/step timings, padding waste, lane occupancy,
-        # batch-width histogram and jit-cache growth, tagged by kernel
-        # and staging mode. None OR the shared NOOP sentinel (both mean
-        # "no metrics wired") disables the whole plane — the pumps
-        # check one bool and skip every measurement, including the
-        # block_until_ready that honest device timing needs (the run
-        # pump otherwise rides async dispatch; a caller passing NOOP
-        # must not pay that pipelining loss for discarded data).
+        # device telemetry (utils/metrics_defs.py DEVICE_METRICS):
+        # per-batch staging time, real and staged event cells, lanes
+        # and the histories packed into them, batch-width histogram and
+        # jit-cache growth, tagged by kernel and staging mode. Nothing
+        # here blocks on the device: kernel time comes from a device
+        # profile, where the dispatch.launch span sits on the same
+        # clock. None OR the shared NOOP sentinel (both mean "no
+        # metrics wired") disables the whole plane — the pumps check
+        # one bool and skip every measurement.
         self._telemetry = metrics is not None and metrics is not NOOP
         self._metrics = (metrics if metrics is not None else NOOP).tagged(
             layer="device"
@@ -240,7 +248,9 @@ class DeviceDispatcher:
             self._packer.start()
             self._runner.start()
             self._started = True
-        self._in.put((batch_id, histories, resume))
+        # the pumps' spans join the submitter's trace, if it has one
+        self._in.put((batch_id, histories, resume,
+                      TRACER.current_context()))
 
     def finish(self) -> None:
         """No more submits; results() ends after the queued work.
@@ -274,18 +284,18 @@ class DeviceDispatcher:
             if item is None:
                 self._staged.put(None)
                 return
-            batch_id, histories, resume = item
+            batch_id, histories, resume, ctx = item
             try:
                 t0 = _time.perf_counter()
                 if self.lane_pack:
                     staged = self._pack_lanes_item(
                         batch_id, histories, use_pallas, jax, jnp,
-                        resume=resume,
+                        resume=resume, ctx=ctx,
                     )
                 else:
                     staged = self._pack_hist_item(
                         batch_id, histories, use_pallas, jax, jnp,
-                        resume=resume,
+                        resume=resume, ctx=ctx,
                     )
                 if self._telemetry:
                     self._emit_stage_telemetry(
@@ -294,7 +304,7 @@ class DeviceDispatcher:
                     )
                 # blocks when `depth` batches are already staged — the
                 # double-buffer backpressure
-                self._staged.put(staged)
+                self._staged.put((staged, ctx))
             except Exception as e:
                 self._staged.put(DispatchError(batch_id, e))
 
@@ -306,48 +316,34 @@ class DeviceDispatcher:
     def _emit_stage_telemetry(
         self, staged, histories, use_pallas: bool, stage_s: float,
     ) -> None:
-        """Per-batch staging telemetry (pack + H2D build time, padding
-        waste, lane occupancy, width histogram) — only reached when a
-        metrics scope was wired (``self._telemetry``)."""
+        """Per-batch staging telemetry (pack + H2D build time, event
+        cells real and staged, lanes and their histories, width
+        histogram) — only reached when a metrics scope was wired
+        (``self._telemetry``). Counters, so that any window's ratios
+        come out of two reads: padding = staged ÷ real cells − 1,
+        occupancy = lane_histories ÷ lanes."""
         mode, packed = staged[0], staged[2]
         scope = self._device_scope(mode, use_pallas)
         scope.inc("device_batches")
         scope.record("host_stage_seconds", stage_s)
         if mode.startswith("lanes"):
-            # the packer's own waste/occupancy definitions — one source
-            # of truth with bench.py and the PackedLanes properties
-            padding = packed.padding_frac
+            real = packed.total_events
             width = packed.lanes
-            if packed.lanes:
-                scope.gauge(
-                    "lane_occupancy", packed.n_histories / packed.lanes
-                )
+            scope.inc("lanes", packed.lanes)
+            scope.inc("lane_histories", packed.n_histories)
         else:
-            cells = packed.batch * packed.events.shape[1]
             real = sum(history_depth(h[2]) for h in histories)
-            padding = (cells - real) / max(real, 1)
             width = packed.batch
-        scope.gauge("padding_frac", padding)
+        scope.inc("replay_event_cells", real)
+        scope.inc("replay_staged_cells", width * packed.events.shape[1])
         # batches counted per grid-rounded width: the compiled-
         # executable set in action (width cardinality is bounded by the
         # round_scan_len geometric grid, so the tag can't explode)
         scope.tagged(width=str(width)).inc("batch_width")
 
-    def _emit_step_telemetry(
-        self, mode: str, use_pallas: bool, final, t0: float,
-    ) -> None:
-        """Per-batch device-step telemetry. Blocks on ``final`` so the
-        recorded duration is device time, not async-dispatch time —
-        the documented cost of enabling device telemetry (the pack pump
-        still overlaps; only kernel-launch pipelining is lost)."""
-        try:
-            import jax
-
-            jax.block_until_ready(final)
-        except Exception:
-            pass
-        scope = self._device_scope(mode, use_pallas)
-        scope.record("device_step_seconds", _time.perf_counter() - t0)
+    def _emit_step_telemetry(self) -> None:
+        """Per-batch jit-cache growth, read without waiting for the
+        device."""
         entries = _jit_cache_total()
         if entries >= 0:
             self._metrics.gauge("jit_cache_entries", entries)
@@ -393,153 +389,133 @@ class DeviceDispatcher:
         _, non = assoc_classify_types(present)
         return not non
 
+    def _narrow(self, teb):
+        """The int16 stream of a field-major event tensor, or None
+        (narrowing off, or a gating column is wide) — then the kernel
+        takes ``teb`` as is. Returns (events, narrow_meta)."""
+        if self.narrow:
+            from .replay_pallas import narrow_events_teb
+
+            narrowed = narrow_events_teb(
+                teb, force_wide=tuple(sorted(self._wide_set))
+            )
+            if narrowed is not None:
+                ev16, nbase, nwide = narrowed
+                self._wide_set.update(nwide)
+                return ev16, (nbase, nwide)
+        return teb, None
+
     def _pack_hist_item(self, batch_id, histories, use_pallas, jax, jnp,
-                        resume=None):
+                        resume=None, ctx=None):
         import numpy as _np
 
         from .pack import pack_histories
+        from .replay import to_device
 
         b = len(histories)
-        # grid-rounded batch: distinct stream chunk sizes would
-        # otherwise each compile a fresh replay executable mid-storm
-        packed = pack_histories(
-            histories, caps=self.caps, pad_batch_to=round_scan_len(b),
-            domain_resolver=self.domain_resolver,
-            resume=resume,
-        )
-        # present-type scan is a full [B, T] host pass; skip it when the
-        # assoc path is statically off (scan/pallas/TPU backend) —
-        # _assoc_hist would ignore the result and the "hist" branch
-        # replays unspecialized
-        present = None
-        if self._assoc_enabled(use_pallas):
-            present = [
-                int(t)
-                for t in _np.unique(packed.events[:, :, S.EV_TYPE])
-                if t >= 0
-            ]
-            self._type_set.update(present)
-        if present is not None and self._assoc_hist(use_pallas, present):
-            from .assoc import events_fm_of
-            from .replay import type_signature
-
-            # field-major column planes — the assoc kernel's operand
-            # layout; built host-side so the copy overlaps device work
-            events = jax.device_put(
-                jnp.asarray(events_fm_of(packed.events)))
-            state0 = jax.tree_util.tree_map(
-                jnp.asarray,
-                packed.initial if packed.initial is not None
-                else S.empty_state(packed.batch, self.caps),
+        with TRACER.span("dispatch.pack", parent=ctx) as sp:
+            # grid-rounded batch: distinct stream chunk sizes would
+            # otherwise each compile a fresh replay executable mid-storm
+            packed = pack_histories(
+                histories, caps=self.caps, pad_batch_to=round_scan_len(b),
+                domain_resolver=self.domain_resolver,
+                resume=resume,
             )
-            sig = type_signature(self._type_set)
-            return ("hist_assoc", batch_id, packed, events, state0, sig, b)
-        narrow_meta = None
-        if use_pallas:
-            teb = packed.teb()
-            narrowed = None
-            if self.narrow:
-                from .replay_pallas import narrow_events_teb
+            if sp:
+                sp.set_tag("histories", b)
+                sp.set_tag("events", int(packed.lengths.sum()))
+                sp.set_tag("lanes", packed.batch)
+            # present-type scan is a full [B, T] host pass; skip it when
+            # the assoc path is statically off (scan/pallas/TPU backend)
+            # — _assoc_hist would ignore the result and the "hist"
+            # branch replays unspecialized
+            present = None
+            if self._assoc_enabled(use_pallas):
+                present = [
+                    int(t)
+                    for t in _np.unique(packed.events[:, :, S.EV_TYPE])
+                    if t >= 0
+                ]
+                self._type_set.update(present)
+            # checkpoint resume seeds the initial carries; padding rows
+            # of packed.initial are empty_state, so the grid pad is
+            # unchanged
+            state0 = (packed.initial if packed.initial is not None
+                      else S.empty_state(packed.batch, self.caps))
+            if present is not None and self._assoc_hist(use_pallas,
+                                                        present):
+                from .assoc import events_fm_of
+                from .replay import type_signature
 
-                narrowed = narrow_events_teb(
-                    teb, force_wide=tuple(sorted(self._wide_set))
-                )
-            if narrowed is not None:
-                ev16, nbase, nwide = narrowed
-                self._wide_set.update(nwide)
-                events = jax.device_put(jnp.asarray(ev16))
-                narrow_meta = (nbase, nwide)
+                # field-major column planes — the assoc kernel's operand
+                # layout; built host-side so the copy overlaps device
+                # work
+                mode = "hist_assoc"
+                events = events_fm_of(packed.events)
+                extra = (type_signature(self._type_set), b)
+            elif use_pallas:
+                mode = "hist"
+                events, narrow_meta = self._narrow(packed.teb())
+                extra = (narrow_meta, b)
             else:
-                events = jax.device_put(jnp.asarray(teb))
-        else:
-            events = jax.device_put(jnp.asarray(packed.time_major()))
-        # checkpoint resume seeds the initial carries; padding rows of
-        # packed.initial are empty_state, so the grid pad is unchanged
-        state0 = jax.tree_util.tree_map(
-            jnp.asarray,
-            packed.initial if packed.initial is not None
-            else S.empty_state(packed.batch, self.caps),
-        )
-        return ("hist", batch_id, packed, events, narrow_meta, state0, b)
+                mode = "hist"
+                events = packed.time_major()
+                extra = (None, b)
+        operands = to_device((events, state0), "dispatch.h2d", ctx)
+        return (mode, batch_id, packed, operands, extra)
 
     def _pack_lanes_item(self, batch_id, histories, use_pallas, jax, jnp,
-                         resume=None):
+                         resume=None, ctx=None):
         from .pack import pack_lanes
-        from .replay import type_signature
+        from .replay import to_device, type_signature
 
-        packed = pack_lanes(
-            histories, caps=self.caps, target_lane_len=self.lane_len,
-            seg_align=self.tb if use_pallas else 1,
-            domain_resolver=self.domain_resolver,
-            resume=resume,
-        )
-        self._type_set.update(packed.present_types)
-        sig = type_signature(self._type_set)
-        if self._assoc_lanes(use_pallas, packed.present_types):
-            from .assoc import assoc_lanes_operands, events_fm_of
-
-            init, hist_bm, seg_pos, seg_lane, seg_start = (
-                assoc_lanes_operands(packed))
-            arrays = (
-                jax.device_put(jnp.asarray(events_fm_of(packed.events))),
-                jnp.asarray(hist_bm), jnp.asarray(seg_pos),
-                jnp.asarray(seg_lane), jnp.asarray(seg_start),
+        with TRACER.span("dispatch.pack", parent=ctx) as sp:
+            packed = pack_lanes(
+                histories, caps=self.caps, target_lane_len=self.lane_len,
+                seg_align=self.tb if use_pallas else 1,
+                domain_resolver=self.domain_resolver,
+                resume=resume,
             )
-            init = jax.tree_util.tree_map(jnp.asarray, init)
-            return ("lanes_assoc", batch_id, packed, arrays, init, sig)
-        narrow_meta = None
-        if use_pallas:
-            teb = packed.teb()
-            narrowed = None
-            if self.narrow:
-                from .replay_pallas import narrow_events_teb
+            if sp:
+                sp.set_tag("histories", packed.n_histories)
+                sp.set_tag("events", packed.total_events)
+                sp.set_tag("lanes", packed.lanes)
+            self._type_set.update(packed.present_types)
+            sig = type_signature(self._type_set)
+            if self._assoc_lanes(use_pallas, packed.present_types):
+                from .assoc import assoc_lanes_operands, events_fm_of
 
-                narrowed = narrow_events_teb(
-                    teb, force_wide=tuple(sorted(self._wide_set))
-                )
-            if narrowed is not None:
-                ev16, nbase, nwide = narrowed
-                self._wide_set.update(nwide)
-                events = jax.device_put(jnp.asarray(ev16))
-                narrow_meta = (nbase, nwide)
+                init, hist_bm, seg_pos, seg_lane, seg_start = (
+                    assoc_lanes_operands(packed))
+                host = ((events_fm_of(packed.events), hist_bm, seg_pos,
+                         seg_lane, seg_start), init)
+                mode, extra = "lanes_assoc", (sig,)
             else:
-                events = jax.device_put(jnp.asarray(teb))
-            arrays = (
-                events,
-                jnp.asarray(packed.seg_end),
-                jnp.asarray(packed.out_row),
-            )
-        else:
-            ev_tm, seg_tm, row_tm = packed.time_major()
-            arrays = (
-                jax.device_put(jnp.asarray(ev_tm)),
-                jnp.asarray(seg_tm),
-                jnp.asarray(row_tm),
-            )
-        # checkpoint resume: lanes whose first segment resumes seed from
-        # the snapshot row; segment-end resets gather the NEXT segment's
-        # initial row via the reset table (ops/replay.replay_scan_packed)
-        state0 = jax.tree_util.tree_map(
-            jnp.asarray, packed.lane_state0()
-        )
-        resume_extra = None
-        if packed.initial is not None:
-            import numpy as _np
+                narrow_meta = None
+                if use_pallas:
+                    events, narrow_meta = self._narrow(packed.teb())
+                    arrays = (events, packed.seg_end, packed.out_row)
+                else:
+                    arrays = packed.time_major()
+                # checkpoint resume: lanes whose first segment resumes
+                # seed from the snapshot row; segment-end resets gather
+                # the NEXT segment's initial row via the reset table
+                # (ops/replay.replay_scan_packed)
+                resume_extra = None
+                if packed.initial is not None:
+                    import numpy as _np
 
-            reset = packed.reset_rows()                       # [L, T]
-            resume_extra = (
-                jax.tree_util.tree_map(jnp.asarray, packed.initial),
-                jnp.asarray(reset),
-                jnp.asarray(_np.ascontiguousarray(reset.T)),  # [T, L]
-            )
-        out0 = jax.tree_util.tree_map(
-            jnp.asarray,
-            S.empty_state(round_scan_len(packed.n_histories), self.caps),
-        )
-        return (
-            "lanes", batch_id, packed, arrays, state0, out0, sig,
-            narrow_meta, resume_extra,
-        )
+                    reset = packed.reset_rows()                   # [L, T]
+                    resume_extra = (
+                        packed.initial, reset,
+                        _np.ascontiguousarray(reset.T),           # [T, L]
+                    )
+                out0 = S.empty_state(
+                    round_scan_len(packed.n_histories), self.caps)
+                host = (arrays, packed.lane_state0(), out0, resume_extra)
+                mode, extra = "lanes", (sig, narrow_meta)
+        operands = to_device(host, "dispatch.h2d", ctx)
+        return (mode, batch_id, packed, operands, extra)
 
     def _run_pump(self) -> None:
         use_pallas = self._use_pallas()
@@ -551,104 +527,108 @@ class DeviceDispatcher:
             if isinstance(item, DispatchError):
                 self._out.put(item)
                 continue
-            mode, batch_id = item[0], item[1]
+            (mode, batch_id, packed, operands, extra), ctx = item
             try:
-                t0 = _time.perf_counter()
-                if mode == "hist_assoc":
-                    _, _, packed, events, state0, sig, b = item
-                    from .assoc import _assoc_core
-
-                    final = _assoc_core(events, state0, types=sig)
-                    if b < packed.batch:
-                        import jax
-
-                        final = jax.tree_util.tree_map(
-                            lambda x: x[:b], final
-                        )
-                elif mode == "lanes_assoc":
-                    _, _, packed, arrays, init, sig = item
-                    from .assoc import _assoc_core
-
-                    evf, hist_bm, seg_pos, seg_lane, seg_start = arrays
-                    final = _assoc_core(
-                        evf, init, hist_bm, seg_pos, seg_lane,
-                        seg_start, types=sig,
-                    )
-                    import jax
-
-                    final = jax.tree_util.tree_map(
-                        lambda x: x[: packed.n_histories], final
-                    )
-                elif mode == "lanes":
-                    (_, _, packed, arrays, state0, out0, sig,
-                     narrow_meta, resume_extra) = item
-                    if use_pallas:
-                        from .replay_pallas import replay_scan_pallas_packed
-
-                        nbase, nwide = (
-                            narrow_meta if narrow_meta is not None
-                            else (None, ())
-                        )
-                        kw = {}
-                        if resume_extra is not None:
-                            kw = dict(init=resume_extra[0],
-                                      reset_row=resume_extra[1])
-                        _, final = replay_scan_pallas_packed(
-                            state0, out0, *arrays, self.caps,
-                            tb=self.tb, bt=self.bt, base=nbase,
-                            wide_cols=nwide, **kw,
-                        )
-                    else:
-                        from .replay import replay_scan_packed_jit
-
-                        kw = {}
-                        if resume_extra is not None:
-                            kw = dict(init=resume_extra[0],
-                                      reset_row_tm=resume_extra[2])
-                        _, final = replay_scan_packed_jit(
-                            state0, out0, *arrays, types=sig, **kw
-                        )
-                    import jax
-
-                    final = jax.tree_util.tree_map(
-                        lambda x: x[: packed.n_histories], final
-                    )
-                else:
-                    _, _, packed, events, narrow_meta, state0, b = item
-                    if use_pallas:
-                        from .replay_pallas import replay_scan_pallas_teb
-
-                        nbase, nwide = (
-                            narrow_meta if narrow_meta is not None
-                            else (None, ())
-                        )
-                        final = replay_scan_pallas_teb(
-                            state0, events, self.caps, base=nbase,
-                            wide_cols=nwide, bt=self.bt, tb=self.tb,
-                        )
-                    else:
-                        from .replay import replay_scan_jit
-
-                        # the jitted form donates state0's buffer and
-                        # skips per-batch retracing on this hot
-                        # storm-drain path
-                        final = replay_scan_jit(state0, events)
-                    if b < packed.batch:
-                        import jax
-
-                        # grid padding is an implementation detail; the
-                        # consumer sees exactly its submitted batch
-                        final = jax.tree_util.tree_map(
-                            lambda x: x[:b], final
-                        )
+                with TRACER.span("dispatch.launch", parent=ctx) as sp:
+                    final, streamed = self._launch(
+                        mode, packed, operands, extra, use_pallas)
+                    if sp:
+                        sp.set_tag("events", int(packed.lengths.sum()))
+                        if streamed is not None:
+                            sp.set_tag("cells", streamed)
                 # async dispatch: the call returns while the device
                 # works; the next H2D/pack proceeds immediately
-                # (telemetry mode trades that for honest step timing)
                 if self._telemetry:
-                    self._emit_step_telemetry(mode, use_pallas, final, t0)
+                    self._emit_step_telemetry()
                 self._out.put((batch_id, packed, final))
             except Exception as e:
                 self._out.put(DispatchError(batch_id, e))
+
+    def _launch(self, mode, packed, operands, extra, use_pallas):
+        """Run one staged batch's kernel. Returns (final, streamed):
+        ``streamed`` is the event cells an XLA kernel streams (its
+        operand's shape), None where a Pallas kernel tags the current
+        span with its own, tile padding included."""
+        import jax
+
+        if mode == "hist_assoc":
+            from .assoc import _assoc_core
+
+            (events, state0), (sig, b) = operands, extra
+            final = _assoc_core(events, state0, types=sig)
+            if b < packed.batch:
+                final = jax.tree_util.tree_map(lambda x: x[:b], final)
+            return final, events.shape[1] * events.shape[2]
+        if mode == "lanes_assoc":
+            from .assoc import _assoc_core
+
+            (evf, hist_bm, seg_pos, seg_lane, seg_start), init = operands
+            final = _assoc_core(
+                evf, init, hist_bm, seg_pos, seg_lane, seg_start,
+                types=extra[0],
+            )
+            final = jax.tree_util.tree_map(
+                lambda x: x[: packed.n_histories], final
+            )
+            return final, evf.shape[1] * evf.shape[2]
+        if mode == "lanes":
+            arrays, state0, out0, resume_extra = operands
+            sig, narrow_meta = extra
+            streamed = None
+            if use_pallas:
+                from .replay_pallas import replay_scan_pallas_packed
+
+                nbase, nwide = (
+                    narrow_meta if narrow_meta is not None else (None, ())
+                )
+                kw = {}
+                if resume_extra is not None:
+                    kw = dict(init=resume_extra[0],
+                              reset_row=resume_extra[1])
+                _, final = replay_scan_pallas_packed(
+                    state0, out0, *arrays, self.caps,
+                    tb=self.tb, bt=self.bt, base=nbase,
+                    wide_cols=nwide, **kw,
+                )
+            else:
+                from .replay import replay_scan_packed_jit
+
+                kw = {}
+                if resume_extra is not None:
+                    kw = dict(init=resume_extra[0],
+                              reset_row_tm=resume_extra[2])
+                _, final = replay_scan_packed_jit(
+                    state0, out0, *arrays, types=sig, **kw
+                )
+                streamed = arrays[0].shape[0] * arrays[0].shape[1]
+            final = jax.tree_util.tree_map(
+                lambda x: x[: packed.n_histories], final
+            )
+            return final, streamed
+        (events, state0), (narrow_meta, b) = operands, extra
+        streamed = None
+        if use_pallas:
+            from .replay_pallas import replay_scan_pallas_teb
+
+            nbase, nwide = (
+                narrow_meta if narrow_meta is not None else (None, ())
+            )
+            final = replay_scan_pallas_teb(
+                state0, events, self.caps, base=nbase,
+                wide_cols=nwide, bt=self.bt, tb=self.tb,
+            )
+        else:
+            from .replay import replay_scan_jit
+
+            # the jitted form donates state0's buffer and skips
+            # per-batch retracing on this hot storm-drain path
+            final = replay_scan_jit(state0, events)
+            streamed = events.shape[0] * events.shape[1]
+        if b < packed.batch:
+            # grid padding is an implementation detail; the consumer
+            # sees exactly its submitted batch
+            final = jax.tree_util.tree_map(lambda x: x[:b], final)
+        return final, streamed
 
     def _use_pallas(self) -> bool:
         if self._kernel == "auto":

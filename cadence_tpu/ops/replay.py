@@ -43,6 +43,8 @@ from cadence_tpu.core.enums import (
 )
 from cadence_tpu.core.ids import EMPTY_EVENT_ID, EMPTY_VERSION
 
+from cadence_tpu.utils.tracing import TRACER
+
 from . import schema as S
 from .pack import PackedHistories, PackedLanes, round_scan_len
 
@@ -793,38 +795,80 @@ def replay_packed(
     mode rides the Pallas/sequential serving path, the TPU assoc
     benchmark being an open ROADMAP item. The XLA batch dimension is padded to
     the geometric shape grid (``round_scan_len``) so a storm of
-    arbitrary batch sizes compiles a bounded set of executables."""
+    arbitrary batch sizes compiles a bounded set of executables.
+
+    A trace entry point (``Tracer.entry``): the ``replay_packed`` span is
+    a child of the caller's span, else a root at the tracer's sample
+    rate."""
     check_scan_mode(scan_mode)
-    if isinstance(packed, PackedLanes):
-        # initial: [n_histories] per-history resume carries (checkpoint
-        # rows); defaults to packed.initial from pack_lanes(resume=...)
-        return replay_packed_lanes(
-            packed, initial=initial, scan_mode=scan_mode)
+    span = TRACER.entry("replay_packed", service="replay")
+    with span:
+        if isinstance(packed, PackedLanes):
+            if span:
+                span.set_tag("histories", packed.n_histories)
+                span.set_tag("events", packed.total_events)
+            # initial: [n_histories] per-history resume carries
+            # (checkpoint rows); defaults to packed.initial from
+            # pack_lanes(resume=...)
+            return replay_packed_lanes(
+                packed, initial=initial, scan_mode=scan_mode)
+        if span:
+            span.set_tag("histories", packed.batch)
+            span.set_tag("events", int(packed.lengths.sum()))
+        return _replay_histories(packed, initial, scan_mode)
+
+
+def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
+    """replay_packed of a PackedHistories. Its spans: the transfers to
+    the device (``replay.h2d``: the state, then the events), the host
+    layout of the kernel's operands (``replay.layout``: the events, then
+    on TPU the presence masks, built once the events' transfer is
+    issued, so that the two can overlap), the
+    kernel call (``replay.launch``) and the fetch of the final state
+    (``replay.fetch``: the wait for the kernel, then the copy back)."""
     if initial is None:
         initial = packed.initial
     state = initial if initial is not None else S.empty_state(packed.batch, packed.caps)
-    state = jax.tree_util.tree_map(jnp.asarray, state)
+    state = to_device(state)
     if packed.batch == 0:
         return jax.tree_util.tree_map(np.asarray, state)
-    if scan_mode != "scan" and jax.default_backend() != "tpu":
-        from .assoc import (
-            classify_types, events_fm_of, replay_assoc, replay_assoc_fm,
-        )
+    b = bp = packed.batch
+    on_tpu = jax.default_backend() == "tpu"
+    with TRACER.span("replay.layout") as sp:
+        if on_tpu:
+            kernel = "pallas_teb"
+            events = packed.teb()
+        else:
+            kernel = "scan"
+            if scan_mode != "scan":
+                from .assoc import classify_types
 
-        present = [
-            int(t)
-            for t in np.unique(packed.events[:, :, S.EV_TYPE])
-            if t >= 0
-        ]
-        _, non = classify_types(present)
-        if scan_mode == "assoc" or not non:
-            b = packed.batch
+                present = [
+                    int(t)
+                    for t in np.unique(packed.events[:, :, S.EV_TYPE])
+                    if t >= 0
+                ]
+                _, non = classify_types(present)
+                if scan_mode == "assoc" or not non:
+                    # hybrid: sequential steps only at nonaffine events
+                    kernel = "assoc_hybrid" if non else "assoc"
+            # the XLA batch dimension pads to the geometric shape grid
             bp = round_scan_len(b)
-            evf = events_fm_of(packed.events)
+            if kernel == "scan":
+                events = packed.time_major()               # [T, B, EV_N]
+                shape = (events.shape[0], bp - b, S.EV_N)
+            else:
+                from .assoc import events_fm_of
+
+                events = events_fm_of(packed.events)       # [EV_N, B, T]
+                shape = (S.EV_N, bp - b, events.shape[2])
             if bp > b:
-                pad = np.zeros((S.EV_N, bp - b, evf.shape[2]), np.int32)
-                pad[S.EV_TYPE] = -1
-                evf = np.concatenate([evf, pad], axis=1)
+                pad = np.zeros(shape, np.int32)
+                if kernel == "scan":
+                    pad[:, :, S.EV_TYPE] = -1
+                else:
+                    pad[S.EV_TYPE] = -1
+                events = np.concatenate([events, pad], axis=1)
                 state = jax.tree_util.tree_map(
                     lambda x, p: jnp.concatenate(
                         [x, jnp.asarray(p)], axis=0
@@ -832,47 +876,62 @@ def replay_packed(
                     state,
                     S.empty_state(bp - b, packed.caps),
                 )
-            if non:
-                # hybrid: sequential steps only at nonaffine events
-                final = replay_assoc(state, events_fm=evf)
-            else:
-                # unspecialized: one compile per shape (see the lanes
-                # branch above)
-                final = replay_assoc_fm(state, evf)
-            if bp > b:
-                final = jax.tree_util.tree_map(lambda x: x[:b], final)
-            return jax.tree_util.tree_map(np.asarray, final)
-    if jax.default_backend() == "tpu":
+        if sp:
+            sp.set_tag("bytes", int(events.nbytes))
+    if kernel != "assoc_hybrid":  # the hybrid reads its events on the host
+        events = to_device(events)
+    presence = None
+    if on_tpu:
         from .replay_pallas import BT, replay_scan_pallas_teb
 
         # smallest whole tile covering the batch (small rebuild batches
         # shouldn't pad to the full throughput tile)
-        bt = min(BT, ((packed.batch + 1023) // 1024) * 1024)
-        final = replay_scan_pallas_teb(
-            state, jnp.asarray(packed.teb()), packed.caps,
-            interpret=False, bt=bt, presence=packed.presence(bt),
-        )
-    else:
-        b = packed.batch
-        bp = round_scan_len(b)
-        events_tm = packed.time_major()
-        if bp > b:
-            pad = np.zeros(
-                (events_tm.shape[0], bp - b, S.EV_N), dtype=np.int32
+        bt = min(BT, ((b + 1023) // 1024) * 1024)
+        with TRACER.span("replay.layout") as sp:
+            presence = packed.presence(bt)
+            if sp and presence is not None:
+                sp.set_tag("bytes", int(presence.nbytes))
+    with TRACER.span("replay.launch") as sp:
+        if kernel == "pallas_teb":
+            final = replay_scan_pallas_teb(
+                state, events, packed.caps,
+                interpret=False, bt=bt, presence=presence,
             )
-            pad[:, :, S.EV_TYPE] = -1
-            events_tm = np.concatenate([events_tm, pad], axis=1)
-            state = jax.tree_util.tree_map(
-                lambda x, p: jnp.concatenate(
-                    [x, jnp.asarray(p)], axis=0
-                ),
-                state,
-                S.empty_state(bp - b, packed.caps),
-            )
-        final = replay_scan_jit(state, jnp.asarray(events_tm))
+        elif kernel == "scan":
+            final = replay_scan_jit(state, events)
+        elif kernel == "assoc":
+            from .assoc import replay_assoc_fm
+
+            # unspecialized: one compile per shape (see the lanes
+            # branch above)
+            final = replay_assoc_fm(state, events)
+        else:
+            from .assoc import replay_assoc
+
+            final = replay_assoc(state, events_fm=events)
         if bp > b:
             final = jax.tree_util.tree_map(lambda x: x[:b], final)
-    return jax.tree_util.tree_map(np.asarray, final)
+        if sp:
+            sp.set_tag("events", int(packed.lengths.sum()))
+            if kernel != "pallas_teb":  # the Pallas kernel tags its own
+                sp.set_tag("cells", bp * packed.events.shape[1])
+    with TRACER.span("replay.fetch") as sp:
+        out = jax.tree_util.tree_map(np.asarray, final)
+        if sp:
+            sp.set_tag("bytes", sum(
+                int(x.nbytes) for x in jax.tree_util.tree_leaves(out)))
+    return out
+
+
+def to_device(host, span: str = "replay.h2d", parent=None):
+    """A pytree of host arrays on the default device, under a span
+    (child of ``parent`` or of the thread's current span) that counts
+    its bytes."""
+    with TRACER.span(span, parent=parent) as sp:
+        if sp:
+            sp.set_tag("bytes", sum(
+                int(x.nbytes) for x in jax.tree_util.tree_leaves(host)))
+        return jax.tree_util.tree_map(jnp.asarray, host)
 
 
 # Parallel-in-time entry points (ops/assoc.py): replay_assoc is the

@@ -52,6 +52,7 @@ from cadence_tpu.core.enums import (
     WORKFLOW_CLOSE_STATUS, decision_attempt_increment,
 )
 from cadence_tpu.core.ids import EMPTY_EVENT_ID, EMPTY_VERSION
+from cadence_tpu.utils.tracing import TRACER
 
 from . import schema as S
 
@@ -798,6 +799,9 @@ def replay_scan_pallas_teb(
     rm = RowMap(caps)
     b_pad = (-B) % bt
     t_pad = (-T) % tb
+    span = TRACER.current()
+    if span is not None:  # event cells the kernel streams, padding in
+        span.set_tag("cells", (B + b_pad) * (T + t_pad))
 
     if t_pad or b_pad:
         if narrow:
@@ -901,6 +905,9 @@ def replay_scan_pallas_packed(
             )
     rm = RowMap(caps)
     b_pad = (-L) % bt
+    span = TRACER.current()
+    if span is not None:  # event cells the kernel streams, padding in
+        span.set_tag("cells", (L + b_pad) * T)
     if b_pad:
         if narrow:
             # padding must reconstruct EV_TYPE == -1 through the base

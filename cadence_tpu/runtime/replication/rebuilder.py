@@ -18,6 +18,14 @@ writes fresh checkpoints from the rebuilt state — repeat rebuilds cost
 O(new events) instead of O(depth). A checkpoint at the branch tip skips
 the device entirely (rehydrate + task refresh). Any checkpoint-plane
 failure degrades that request to a full replay.
+
+Tracing: ``rebuild_many`` is a trace entry point (utils/tracing.py
+``Tracer.entry``) — a child of the caller's span, else a root at the
+tracer's sample rate — with spans on the caller's thread for the reads
+(``rebuild.read``), each wait on the dispatcher (``rebuild.await``),
+each row's unpack (``rebuild.unpack``) and task refresh
+(``rebuild.refresh``), and each host fallback (``rebuild.fallback``);
+the dispatcher's pumps add theirs under the same trace.
 """
 
 from __future__ import annotations
@@ -30,6 +38,7 @@ from cadence_tpu.core.state_builder import StateBuilder
 from cadence_tpu.core.task_refresher import refresh_tasks
 from cadence_tpu.core.version_history import VersionHistories
 from cadence_tpu.utils.metrics import NOOP
+from cadence_tpu.utils.tracing import TRACER
 
 from ..persistence.interfaces import HistoryManager
 from ..persistence.records import BranchToken
@@ -259,6 +268,13 @@ class StateRebuilder:
         suffix (the snapshot row seeds the segment carry), tip hits skip
         the device entirely, and the rebuilt tips are written back as
         fresh checkpoints per the manager's policy."""
+        span = TRACER.entry("rebuild_many", service="history")
+        with span:
+            if span:
+                span.set_tag("requests", len(reqs))
+            return self._rebuild_many(reqs, use_device, span)
+
+    def _rebuild_many(self, reqs, use_device, span):
         if not use_device or len(reqs) == 0:
             return [self.rebuild(r) for r in reqs]
 
@@ -283,6 +299,84 @@ class StateRebuilder:
         caps = S.Capacities()
 
         # consult checkpoints, read only what must be replayed
+        with TRACER.span("rebuild.read") as sp:
+            histories, resumes, pend_req = self._read_pending(
+                reqs, caps, out)
+            if sp:
+                sp.set_tag("histories", len(histories))
+                sp.set_tag("events", sum(
+                    len(b) for h in histories for b in h[2]))
+
+        # storm drain: depth-bucket the stream (a few deep stragglers
+        # must not stretch every lane; a resumed run buckets by its
+        # SUFFIX depth), lane-pack each bucket (several whole histories
+        # per scan lane), and pump the chunks through the
+        # double-buffered host→device dispatcher (ops/dispatch.py) so
+        # packing batch k+1 overlaps replaying batch k; each failed
+        # chunk (capacity overflow etc.) falls back per-workflow to the
+        # host oracle
+        chunk = self._resolve_chunk()
+        plan = []
+        for idxs, hs in depth_buckets(histories):
+            for j in range(0, len(hs), chunk):
+                plan.append((idxs[j : j + chunk], hs[j : j + chunk]))
+        if not plan:
+            return out
+        # the dispatcher is built only once the chunk plan exists, so
+        # its staging buffer is sized per batch (staging_depth) — the
+        # one-chunk serving/small-rebuild shape gets a one-slot queue
+        d = DeviceDispatcher(
+            caps=caps, depth=staging_depth(len(plan)),
+            domain_resolver=self.domain_resolver, lane_pack=True,
+            lane_len=self.lane_len, metrics=self._raw_metrics,
+        )
+        for sub, hs in plan:
+            d.submit(
+                tuple(pend_req[i] for i in sub),
+                hs,
+                resume=[resumes[i] for i in sub],
+            )
+        d.finish()
+        results = d.results(strict=False)
+        on_device = fallbacks = 0
+        while True:
+            with TRACER.span("rebuild.await"):
+                item = next(results, None)
+            if item is None:
+                break
+            if isinstance(item, DispatchError):
+                with TRACER.span("rebuild.fallback") as sp:
+                    if sp:
+                        sp.set_tag("histories", len(item.batch_id))
+                    for gi in item.batch_id:
+                        out[gi] = self.rebuild(reqs[gi])
+                fallbacks += len(item.batch_id)
+                continue
+            idxs, packed, final = item
+            for j, gi in enumerate(idxs):
+                r = reqs[gi]
+                with TRACER.span("rebuild.unpack"):
+                    ms = state_row_to_mutable_state(
+                        final, j, packed.side[j],
+                        domain_id=r.domain_id, epoch_s=packed.epoch_s,
+                    )
+                ms.execution_info.branch_token = r.branch_token
+                with TRACER.span("rebuild.refresh"):
+                    transfer, timer = refresh_tasks(ms)
+                out[gi] = (ms, transfer, timer)
+                self._record_checkpoint(r, packed, final, j)
+            on_device += len(idxs)
+        if span:
+            span.set_tag("device_histories", on_device)
+            span.set_tag("host_fallbacks", fallbacks)
+        return out
+
+    def _read_pending(self, reqs, caps, out):
+        """Consult serving and checkpoints per request and read only
+        what must be replayed. Fills ``out`` for requests answered
+        without the device; returns the pending (wf, run, suffix
+        batches), their Optional[ResumeState]s, and each one's request
+        index."""
         histories = []           # pending (wf, run, suffix batches)
         resumes = []             # aligned Optional[ResumeState]
         pend_req: List[int] = []  # pending index -> request index
@@ -323,51 +417,4 @@ class StateRebuilder:
             histories.append((r.workflow_id, r.run_id, batches))
             resumes.append(resume)
             pend_req.append(gi)
-
-        # storm drain: depth-bucket the stream (a few deep stragglers
-        # must not stretch every lane; a resumed run buckets by its
-        # SUFFIX depth), lane-pack each bucket (several whole histories
-        # per scan lane), and pump the chunks through the
-        # double-buffered host→device dispatcher (ops/dispatch.py) so
-        # packing batch k+1 overlaps replaying batch k; each failed
-        # chunk (capacity overflow etc.) falls back per-workflow to the
-        # host oracle
-        chunk = self._resolve_chunk()
-        plan = []
-        for idxs, hs in depth_buckets(histories):
-            for j in range(0, len(hs), chunk):
-                plan.append((idxs[j : j + chunk], hs[j : j + chunk]))
-        if not plan:
-            return out
-        # the dispatcher is built only once the chunk plan exists, so
-        # its staging buffer is sized per batch (staging_depth) — the
-        # one-chunk serving/small-rebuild shape gets a one-slot queue
-        d = DeviceDispatcher(
-            caps=caps, depth=staging_depth(len(plan)),
-            domain_resolver=self.domain_resolver, lane_pack=True,
-            lane_len=self.lane_len, metrics=self._raw_metrics,
-        )
-        for sub, hs in plan:
-            d.submit(
-                tuple(pend_req[i] for i in sub),
-                hs,
-                resume=[resumes[i] for i in sub],
-            )
-        d.finish()
-        for item in d.results(strict=False):
-            if isinstance(item, DispatchError):
-                for gi in item.batch_id:
-                    out[gi] = self.rebuild(reqs[gi])
-                continue
-            idxs, packed, final = item
-            for j, gi in enumerate(idxs):
-                r = reqs[gi]
-                ms = state_row_to_mutable_state(
-                    final, j, packed.side[j],
-                    domain_id=r.domain_id, epoch_s=packed.epoch_s,
-                )
-                ms.execution_info.branch_token = r.branch_token
-                transfer, timer = refresh_tasks(ms)
-                out[gi] = (ms, transfer, timer)
-                self._record_checkpoint(r, packed, final, j)
-        return out
+        return histories, resumes, pend_req

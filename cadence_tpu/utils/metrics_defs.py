@@ -217,21 +217,24 @@ FAILOVER_METRICS = (
     "failover_conflicts_resolved",
 )
 
-# device-step kernel telemetry (ops/dispatch.py), emitted by the
+# device-dispatch telemetry (ops/dispatch.py), emitted by the
 # dispatcher per staged/replayed batch under tags (layer=device,
-# kernel=xla|pallas, mode=hist|lanes|hist_assoc|lanes_assoc):
+# kernel=xla|pallas, mode=hist|lanes|hist_assoc|lanes_assoc). Nothing
+# here waits for the device: kernel time comes from a device profile,
+# where the dispatcher's dispatch.launch span (utils/tracing.py) sits on
+# the same clock.
 #
 #   device_batches       counter — batches replayed
 #   host_stage_seconds   histogram — pack + H2D staging wall time
-#   device_step_seconds  histogram — kernel wall time (the run pump
-#                        blocks on the result when telemetry is on, so
-#                        this is honest device time, not dispatch time)
 #   batch_width          histogram — padded batch width per dispatch
 #                        (the compiled-executable grid in action)
-#   padding_frac         gauge — padded slots ÷ real events of the last
-#                        batch (the lane packer's waste)
-#   lane_occupancy       gauge — histories per lane of the last
-#                        lane-packed batch
+#   replay_event_cells   counter — real events staged
+#   replay_staged_cells  counter — event cells staged (lanes or rows ×
+#                        scan length): staged ÷ real − 1 over any window
+#                        is the packer's padding waste
+#   lanes                counter — scan lanes of lane-packed batches
+#   lane_histories       counter — histories packed into those lanes:
+#                        lane_histories ÷ lanes is the lane occupancy
 #   jit_cache_entries    gauge — total compiled executables across the
 #                        replay kernels visible to this dispatcher
 #   jit_retraces         counter — cache-size growth observed after a
@@ -240,10 +243,11 @@ FAILOVER_METRICS = (
 DEVICE_METRICS = (
     "device_batches",
     "host_stage_seconds",
-    "device_step_seconds",
     "batch_width",
-    "padding_frac",
-    "lane_occupancy",
+    "replay_event_cells",
+    "replay_staged_cells",
+    "lanes",
+    "lane_histories",
     "jit_cache_entries",
     "jit_retraces",
 )

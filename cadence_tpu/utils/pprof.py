@@ -25,7 +25,10 @@ here, all stdlib, no deps:
                                bracket a jax.profiler trace (XLA/TPU
                                device timeline, viewable in
                                tensorboard/xprof) — the device-side
-                               story Go pprof has no equivalent for
+                               story Go pprof has no equivalent for;
+                               while it runs, every sampled span
+                               (utils/tracing.py) is annotated into it
+                               under its own name
 
 The sampler is safe to run in production: it reads
 ``sys._current_frames`` from a daemon thread, never stops the world.
@@ -154,12 +157,18 @@ class _Handler(BaseHTTPRequestHandler):
             if path == "/debug/pprof/device/start":
                 import jax
 
+                from cadence_tpu.utils.tracing import TRACER
+
                 trace_dir = q.get("dir", ["/tmp/cadence-tpu-trace"])[0]
                 jax.profiler.start_trace(trace_dir)
+                TRACER.set_profiler_prefix("")
                 self._reply(200, f"device trace started -> {trace_dir}\n")
             elif path == "/debug/pprof/device/stop":
                 import jax
 
+                from cadence_tpu.utils.tracing import TRACER
+
+                TRACER.set_profiler_prefix(None)
                 jax.profiler.stop_trace()
                 self._reply(200, "device trace stopped\n")
             else:

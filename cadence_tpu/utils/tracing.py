@@ -28,7 +28,17 @@ configured ``sample_rate`` (``telemetry:`` YAML section through
 bootstrap). Every other entry point — ``span()``, ``annotate()``,
 ``bind()`` — first reads the thread-local current span and returns the
 shared no-op immediately when there is none: the unsampled path is one
-attribute lookup and a None check.
+attribute lookup and a None check. A batch entry point that may run with
+or without a caller's trace (``rebuild_many``, ``replay_packed``) opens
+its span through ``Tracer.entry``: a child of the caller's span, else a
+root rolled at ``sample_rate`` (one float compare at rate 0).
+
+Profiler annotation (``Tracer.set_profiler_prefix``, off by default):
+while on, every sampled span also opens a ``jax.profiler.TraceAnnotation``
+named ``<prefix><span name>`` on its own thread for its lifetime, so a
+captured device profile shows the program's spans on the device's clock
+beside the device operations (``POST /debug/pprof/device/start`` turns
+it on, ``.../stop`` off).
 """
 
 from __future__ import annotations
@@ -125,8 +135,8 @@ class Span:
 
     __slots__ = (
         "tracer", "name", "service", "trace_id", "span_id", "parent_id",
-        "tags", "annotations", "thread", "start_us", "_t0", "dur_us",
-        "_prev", "error",
+        "tags", "annotations", "thread", "start_us", "start_s", "dur_us",
+        "_prev", "_profiled", "error",
     )
 
     def __init__(self, tracer: "Tracer", name: str, service: str,
@@ -142,11 +152,14 @@ class Span:
         self.annotations: List[Tuple[float, str]] = []
         self.thread = threading.current_thread().name
         # wall clock anchors the Chrome-trace timeline; the monotonic
-        # clock owns every duration and annotation offset
+        # clock (start_s, perf_counter seconds) owns every duration and
+        # annotation offset, so start_s + dur_us orders spans of one
+        # process exactly (a reader's self-time arithmetic)
         self.start_us = time.time() * 1e6
-        self._t0 = time.perf_counter()
+        self.start_s = time.perf_counter()
         self.dur_us: float = 0.0
         self._prev = None
+        self._profiled = None
         self.error: str = ""
 
     sampled = True
@@ -159,7 +172,7 @@ class Span:
         """Timestamped breadcrumb (retries, fault injections, fallback
         decisions) — rendered as an instant event on the timeline."""
         self.annotations.append(
-            ((time.perf_counter() - self._t0) * 1e6, str(text))
+            ((time.perf_counter() - self.start_s) * 1e6, str(text))
         )
 
     def set_tag(self, key: str, value) -> None:
@@ -168,17 +181,26 @@ class Span:
     def finish(self) -> None:
         if self.dur_us:
             return  # idempotent: a double finish must not double-record
-        self.dur_us = max((time.perf_counter() - self._t0) * 1e6, 0.01)
+        self.dur_us = max((time.perf_counter() - self.start_s) * 1e6, 0.01)
         self.tracer._record(self)
 
     def __enter__(self) -> "Span":
         self._prev = self.tracer._activate(self)
+        prefix = self.tracer.profiler_prefix
+        if prefix is not None:
+            from jax.profiler import TraceAnnotation
+
+            self._profiled = TraceAnnotation(prefix + self.name)
+            self._profiled.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if exc_type is not None:
             self.error = exc_type.__name__
             self.tags.setdefault("error", exc_type.__name__)
+        if self._profiled is not None:
+            self._profiled.__exit__(None, None, None)
+            self._profiled = None
         self.tracer._deactivate(self._prev)
         self.finish()
 
@@ -193,6 +215,10 @@ class Tracer:
                  seed: Optional[int] = None) -> None:
         self.sample_rate = float(sample_rate)
         self.capacity = int(capacity)
+        # spans that fell off the full ring since the last clear()
+        self.dropped = 0
+        # None: no profiler annotation; a string: the annotation prefix
+        self.profiler_prefix: Optional[str] = None
         self._bind_capacity = int(bind_capacity)
         self._bind_ttl_s = float(bind_ttl_s)
         self._metrics = metrics.tagged(layer="telemetry")
@@ -221,6 +247,13 @@ class Tracer:
             if metrics is not None:
                 self._metrics = metrics.tagged(layer="telemetry")
         return self
+
+    def set_profiler_prefix(self, prefix: Optional[str]) -> Optional[str]:
+        """Profiler annotation: a string turns it on with that name
+        prefix, None turns it off. Returns the previous setting so a
+        caller can restore it."""
+        prev, self.profiler_prefix = self.profiler_prefix, prefix
+        return prev
 
     # -- context plumbing ----------------------------------------------
 
@@ -276,6 +309,15 @@ class Tracer:
             self, name, service, ctx.trace_id, ctx.span_id, tags=tags
         )
 
+    def entry(self, name: str, service: str = "app", **tags):
+        """The span of a batch entry point: a child of the thread's
+        current span, else a root rolled at ``sample_rate`` (the RPC
+        server's rule, without an inbound context)."""
+        parent = getattr(self._tls, "span", None)
+        if parent is None:
+            return self.trace(name, service=service, **tags)
+        return self.span(name, service=service, parent=parent, **tags)
+
     def annotate(self, text: str) -> None:
         """Breadcrumb on the current span, if any (the fault injector's
         and retry loops' one-liner)."""
@@ -321,6 +363,7 @@ class Tracer:
     def _record(self, span: Span) -> None:
         with self._lock:
             if len(self._spans) == self.capacity:
+                self.dropped += 1
                 self._metrics.inc("spans_dropped")
             self._spans.append(span)
         self._metrics.inc("spans_recorded")
@@ -340,6 +383,7 @@ class Tracer:
         with self._lock:
             self._spans.clear()
             self._bindings.clear()
+            self.dropped = 0
 
     # -- export --------------------------------------------------------
 

@@ -847,7 +847,7 @@ class TestMetricDecl:
             def emit(scope):
                 scope.inc("task_requests")
                 scope.gauge("replication_lag_events", 3)
-                scope.record("device_step_seconds", 0.1)
+                scope.record("host_stage_seconds", 0.1)
                 scope.inc("requests")
         """)
         assert fs == []

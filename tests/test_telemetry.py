@@ -366,15 +366,17 @@ class TestDeviceTelemetry:
         reg = metrics.registry
         assert reg.counter_value("device_batches") == 2
         stage = reg.timer_stats("host_stage_seconds")
-        step = reg.timer_stats("device_step_seconds")
         assert stage.count == 2 and stage.p50 > 0
-        assert step.count == 2 and step.p99 >= step.p50 > 0
         # per-width batch counters exist (grid-rounded widths)
         assert reg.counter_value("batch_width") == 2
+        # padding as two counters, summed over every batch: the real
+        # events and the staged cells (rows x scan length)
+        real = sum(len(b) for h in self._histories() for b in h[2])
+        assert reg.counter_value("replay_event_cells") == real
+        staged = sum(p.batch * p.events.shape[1] for p, _ in out)
+        assert reg.counter_value("replay_staged_cells") == staged > real
         snap = reg.snapshot()
-        assert any(
-            "padding_frac" in k for k in snap["gauges"]
-        ), snap["gauges"]
+        assert not any("padding_frac" in k for k in snap["gauges"])
         assert any(
             "jit_cache_entries" in k for k in snap["gauges"]
         )
@@ -383,17 +385,40 @@ class TestDeviceTelemetry:
         from cadence_tpu.ops.dispatch import replay_stream
 
         metrics = Scope()
-        replay_stream(
+        out = replay_stream(
             self._histories(), batch_size=6, kernel="xla",
             lane_pack=True, lane_len=32, scan_mode="scan",
             metrics=metrics,
         )
-        snap = metrics.registry.snapshot()
-        occ = [
-            v for k, v in snap["gauges"].items()
-            if "lane_occupancy" in k
-        ]
-        assert occ and occ[0] > 0
+        reg = metrics.registry
+        (packed, _), = out
+        # occupancy = lane_histories / lanes over any window
+        assert reg.counter_value("lanes") == packed.lanes > 0
+        assert reg.counter_value("lane_histories") == 6
+        assert reg.counter_value("replay_staged_cells") == (
+            packed.lanes * packed.scan_len)
+        assert reg.counter_value("replay_event_cells") == (
+            packed.total_events)
+        assert not any("lane_occupancy" in k
+                       for k in reg.snapshot()["gauges"])
+
+    def test_wired_telemetry_never_waits_for_the_device(self,
+                                                        monkeypatch):
+        import jax
+
+        from cadence_tpu.ops.dispatch import replay_stream
+
+        def refuse(*a, **k):
+            raise AssertionError("telemetry blocked on the device")
+
+        monkeypatch.setattr(jax, "block_until_ready", refuse)
+        metrics = Scope()
+        out = replay_stream(
+            self._histories(), batch_size=3, kernel="xla",
+            lane_pack=True, lane_len=32, metrics=metrics,
+        )
+        assert len(out) == 2
+        assert metrics.registry.counter_value("device_batches") == 2
 
     def test_default_dispatcher_pays_nothing(self):
         from cadence_tpu.ops.dispatch import DeviceDispatcher
@@ -402,8 +427,7 @@ class TestDeviceTelemetry:
         d = DeviceDispatcher()
         assert d._telemetry is False
         # the shared NOOP sentinel means "no metrics wired" too: a
-        # caller defaulting to NOOP must not pay the run pump's
-        # block_until_ready for data nobody reads
+        # caller defaulting to NOOP must not pay for data nobody reads
         assert DeviceDispatcher(metrics=NOOP)._telemetry is False
 
 
