@@ -41,7 +41,8 @@ Used by the replication rebuild path for storm-sized request streams
             d.submit(i, batch)
         d.finish()
         for batch_id, packed, final in d.results():
-            ...  # final is a device StateTensors, fetch/unpack at will
+            ...  # final is a device StateTensors: fetch it once
+                 # (jax.device_get), then unpack rows from the host copy
 """
 
 from __future__ import annotations

@@ -15,6 +15,11 @@ taskRefresher after nDCStateRebuilder.rebuild).
 ``state_row_to_mutable_state`` additionally rehydrates a full MutableState
 (strings from the side table) for the host runtime to persist — the device
 path's equivalent of nDCStateRebuilder returning a rebuilt mutableState.
+
+Both row converters read any array type, but on a device array each
+table they index is its own gather and device-to-host copy: a caller
+unpacking many rows of a device batch fetches it once first
+(``jax.device_get``) and passes the host copy.
 """
 
 from __future__ import annotations

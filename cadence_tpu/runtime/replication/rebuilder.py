@@ -23,7 +23,9 @@ Tracing: ``rebuild_many`` is a trace entry point (utils/tracing.py
 ``Tracer.entry``) — a child of the caller's span, else a root at the
 tracer's sample rate — with spans on the caller's thread for the reads
 (``rebuild.read``), each wait on the dispatcher (``rebuild.await``),
-each row's unpack (``rebuild.unpack``) and task refresh
+each device batch's one fetch of its final state to the host
+(``rebuild.fetch``, tagged ``histories`` and ``bytes``), each row's
+unpack from those host arrays (``rebuild.unpack``) and task refresh
 (``rebuild.refresh``), and each host fallback (``rebuild.fallback``);
 the dispatcher's pumps add theirs under the same trace.
 """
@@ -279,7 +281,7 @@ class StateRebuilder:
             return [self.rebuild(r) for r in reqs]
 
         try:
-            import jax  # noqa: F401 — device path needs a usable jax
+            import jax
 
             from cadence_tpu.ops.dispatch import (
                 DeviceDispatcher,
@@ -353,6 +355,16 @@ class StateRebuilder:
                 fallbacks += len(item.batch_id)
                 continue
             idxs, packed, final = item
+            # one transfer for the batch: every leaf's copy starts, then
+            # one wait; the rows below then index host arrays, where a
+            # device array would cost a gather and a copy per table per row
+            with TRACER.span("rebuild.fetch") as sp:
+                final = jax.device_get(final)
+                if sp:
+                    sp.set_tag("histories", len(idxs))
+                    sp.set_tag("bytes", sum(
+                        int(x.nbytes)
+                        for x in jax.tree_util.tree_leaves(final)))
             for j, gi in enumerate(idxs):
                 r = reqs[gi]
                 with TRACER.span("rebuild.unpack"):

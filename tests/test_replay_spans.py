@@ -33,8 +33,8 @@ from cadence_tpu.utils.tracing import NOOP_SPAN, TRACER, Tracer
 CAPS = S.Capacities(max_events=64)
 
 REBUILD_SPANS = {"rebuild_many", "rebuild.read", "rebuild.await",
-                 "rebuild.unpack", "rebuild.refresh", "dispatch.pack",
-                 "dispatch.h2d", "dispatch.launch"}
+                 "rebuild.fetch", "rebuild.unpack", "rebuild.refresh",
+                 "dispatch.pack", "dispatch.h2d", "dispatch.launch"}
 REPLAY_SPANS = {"replay_packed", "replay.layout", "replay.h2d",
                 "replay.launch", "replay.fetch"}
 
@@ -133,6 +133,38 @@ def test_rebuild_many_spans_join_one_trace_across_the_pumps(
                  for s in _byname(spans, "dispatch.launch"))
     assert got == sorted(launched)
     assert len(got) == len(packs) == len(h2d)
+    # one fetch of the final state per device batch, on the caller's
+    # thread, before that batch's rows unpack
+    fetches = _byname(spans, "rebuild.fetch")
+    assert len(fetches) == len(packs)
+    assert sum(s.tags["histories"] for s in fetches) == \
+        top.tags["device_histories"]
+    assert all(s.tags["bytes"] > 0 for s in fetches)
+    first_unpack = min(s.start_s for s in _byname(spans, "rebuild.unpack"))
+    assert min(s.start_s for s in fetches) < first_unpack
+
+
+def test_rebuild_many_unpacks_rows_from_host_arrays(stored, monkeypatch):
+    """Each row is unpacked from the batch's host copy, so no row costs
+    a device gather; the answers are the host replayer's."""
+    from cadence_tpu.ops import unpack
+    from cadence_tpu.ops.unpack import mutable_state_to_snapshot
+
+    rb, reqs, _ = stored
+    seen = []
+    orig = unpack.state_row_to_mutable_state
+
+    def spy(state, b, *a, **k):
+        seen.append([type(x) for x in jax.tree_util.tree_leaves(state)])
+        return orig(state, b, *a, **k)
+
+    monkeypatch.setattr(unpack, "state_row_to_mutable_state", spy)
+    out = rb.rebuild_many(reqs, use_device=True)
+    assert len(seen) == len(reqs)
+    assert all(t is np.ndarray for leaves in seen for t in leaves)
+    for (ms, _, _), r in zip(out, reqs):
+        want, _, _ = rb.rebuild(r)
+        assert mutable_state_to_snapshot(ms) == mutable_state_to_snapshot(want)
 
 
 def test_rebuild_many_roots_its_own_trace_at_the_sample_rate(stored):
@@ -164,6 +196,7 @@ def test_fallback_span_counts_the_batch_rebuilt_on_the_host(
     assert top.tags["host_fallbacks"] == 7
     assert top.tags["device_histories"] == 0
     assert not _byname(spans, "rebuild.unpack")
+    assert not _byname(spans, "rebuild.fetch")
 
 
 def _packed(n=6, depth=20, seed=7):
