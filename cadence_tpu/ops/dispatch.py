@@ -17,9 +17,13 @@ Two storm levers ride on top of the pipeline:
   and the run pump uses the packed scan (segment-end scatter + lane
   reset) — effective scan length per history is its own depth, not
   ``max(depth)`` over the chunk;
-* **depth bucketing** (``replay_stream(bucket=True)`` /
-  ``depth_buckets``): histories sort into geometric depth classes
-  first, so a few deep stragglers don't stretch every lane.
+* **width and depth bucketing** (``replay_stream(bucket=True)`` /
+  ``buckets``): histories sort by the slot-table widths they need
+  (``pack.bucket_caps``), so a fan-out parent with hundreds of
+  activities in flight gets a state as wide as it needs and no batch
+  pays for it but its own; those at the default widths then sort into
+  geometric depth classes, so a few deep stragglers don't stretch every
+  lane. Each batch carries its ``Capacities`` (``submit(caps=...)``).
 
 Batch width, scan length, and the packed scan's static event-type
 signature are all rounded/grown monotonically (``round_scan_len``,
@@ -30,8 +34,11 @@ Tracing: ``submit`` captures the caller's trace context
 (utils/tracing.py) and each pump opens its spans under it on its own
 thread — ``dispatch.pack`` (host packing and layout), ``dispatch.h2d``
 (the transfers of one batch) and ``dispatch.launch`` (the kernel call,
-tagged with the batch's real ``events`` and the kernel's streamed
-``cells``). Unsampled, each is one thread-local read.
+tagged with the batch's real ``events``, and by the
+Pallas kernels with the ``cells`` they stream and their ``state_rows``).
+``buckets`` opens ``dispatch.bucket`` on its caller's thread, tagged
+with the slots its buckets hold and use. Unsampled, each is one
+thread-local read.
 
 Used by the replication rebuild path for storm-sized request streams
 (runtime/replication/rebuilder.py rebuild_many) and usable standalone::
@@ -137,6 +144,67 @@ def depth_buckets(
     return out
 
 
+def buckets(
+    histories: Sequence[Tuple],
+    floor: Optional[S.Capacities] = None,
+    resume: Optional[Sequence] = None,
+) -> List[Tuple[Tuple[int, ...], List[Tuple], Optional[S.Capacities]]]:
+    """Group histories by the capacity bucket of their slot-table peaks
+    (``pack.bucket_caps``, never below ``floor``, default
+    ``Capacities()``). Histories at ``floor`` split into depth buckets
+    (``depth_buckets``, shallowest first), exactly as a stream with no
+    wide history does. Each wider bucket is one group, narrowest first:
+    lane packing fills its lanes toward the lane length whatever their
+    depths, so depth classes would only multiply its launches and the
+    shapes they compile. Histories wider than the widest bucket come
+    last, in one group with caps None, which packs at the dispatcher's
+    caps, overflows and falls back.
+
+    ``resume``: optional per-history Optional[ops.pack.ResumeState]
+    aligned with ``histories`` (a resumed history's batches are its
+    suffix; its peaks count from the snapshot's pending entries).
+    Returns ``[(original_indices, bucket_histories, caps), ...]``,
+    measured and grouped inside a ``dispatch.bucket`` span, tagged
+    ``histories``, ``wide_histories`` (those above ``floor``),
+    ``buckets``, and over the histories a bucket holds, ``slots`` (each
+    one's bucket capacity summed over the five slot tables) and
+    ``slots_used`` (their peak occupancies)."""
+    from .pack import PackOverflowError, SLOT_TABLES, bucket_caps, slot_peaks
+
+    floor = floor or S.Capacities()
+    with TRACER.span("dispatch.bucket") as sp:
+        groups: dict = {}
+        slots = used = 0
+        for i, h in enumerate(histories):
+            r = resume[i] if resume is not None else None
+            peaks = slot_peaks(h[2], r.pack if r is not None else None)
+            try:
+                caps = bucket_caps(peaks, floor)
+            except PackOverflowError:
+                caps = None
+            else:
+                slots += sum(getattr(caps, f) for f in SLOT_TABLES)
+                used += sum(peaks)
+            groups.setdefault(caps, []).append(i)
+        narrow = groups.pop(floor, [])
+        over = groups.pop(None, [])
+        out = [(tuple(narrow[j] for j in idxs), hs, floor)
+               for idxs, hs in depth_buckets([histories[i] for i in narrow])]
+        for caps in sorted(groups, key=lambda c: tuple(
+                getattr(c, f) for f in SLOT_TABLES)):
+            out.append((tuple(groups[caps]),
+                        [histories[i] for i in groups[caps]], caps))
+        if over:
+            out.append((tuple(over), [histories[i] for i in over], None))
+        if sp:
+            sp.set_tag("histories", len(histories))
+            sp.set_tag("wide_histories", sum(map(len, groups.values())))
+            sp.set_tag("buckets", len(out))
+            sp.set_tag("slots", slots)
+            sp.set_tag("slots_used", used)
+    return out
+
+
 class DeviceDispatcher:
     """Pipelines pack (host, C++ sidecar) → H2D → replay (device).
 
@@ -237,6 +305,7 @@ class DeviceDispatcher:
 
     def submit(
         self, batch_id, histories: Sequence[Tuple], resume=None,
+        caps: Optional[S.Capacities] = None,
     ) -> None:
         """Enqueue one batch of (workflow_id, run_id, event_batches).
 
@@ -244,13 +313,17 @@ class DeviceDispatcher:
         Optional[ops.pack.ResumeState] — resumed histories' events are
         their SUFFIX from the snapshot; the packed scan seeds their
         segment carries from the snapshot rows (checkpointed
-        incremental replay)."""
+        incremental replay).
+
+        ``caps``: the batch's slot-table capacities (its ``buckets``
+        group's), default the dispatcher's; the packed batch carries
+        them to its kernel."""
         if not self._started:
             self._packer.start()
             self._runner.start()
             self._started = True
         # the pumps' spans join the submitter's trace, if it has one
-        self._in.put((batch_id, histories, resume,
+        self._in.put((batch_id, histories, resume, caps or self.caps,
                       TRACER.current_context()))
 
     def finish(self) -> None:
@@ -264,10 +337,9 @@ class DeviceDispatcher:
 
     def _pack_pump(self) -> None:
         try:
-            import jax
-            import jax.numpy as jnp
+            import jax  # noqa: F401
 
-            from .pack import pack_histories
+            from .pack import pack_histories  # noqa: F401
         except Exception as e:
             # no usable jax on this host: every queued batch fails fast
             # (the rebuilder falls back per batch) instead of the pump
@@ -285,17 +357,17 @@ class DeviceDispatcher:
             if item is None:
                 self._staged.put(None)
                 return
-            batch_id, histories, resume, ctx = item
+            batch_id, histories, resume, caps, ctx = item
             try:
                 t0 = _time.perf_counter()
                 if self.lane_pack:
                     staged = self._pack_lanes_item(
-                        batch_id, histories, use_pallas, jax, jnp,
+                        batch_id, histories, use_pallas, caps,
                         resume=resume, ctx=ctx,
                     )
                 else:
                     staged = self._pack_hist_item(
-                        batch_id, histories, use_pallas, jax, jnp,
+                        batch_id, histories, use_pallas, caps,
                         resume=resume, ctx=ctx,
                     )
                 if self._telemetry:
@@ -406,7 +478,7 @@ class DeviceDispatcher:
                 return ev16, (nbase, nwide)
         return teb, None
 
-    def _pack_hist_item(self, batch_id, histories, use_pallas, jax, jnp,
+    def _pack_hist_item(self, batch_id, histories, use_pallas, caps,
                         resume=None, ctx=None):
         import numpy as _np
 
@@ -418,7 +490,7 @@ class DeviceDispatcher:
             # grid-rounded batch: distinct stream chunk sizes would
             # otherwise each compile a fresh replay executable mid-storm
             packed = pack_histories(
-                histories, caps=self.caps, pad_batch_to=round_scan_len(b),
+                histories, caps=caps, pad_batch_to=round_scan_len(b),
                 domain_resolver=self.domain_resolver,
                 resume=resume,
             )
@@ -442,7 +514,7 @@ class DeviceDispatcher:
             # of packed.initial are empty_state, so the grid pad is
             # unchanged
             state0 = (packed.initial if packed.initial is not None
-                      else S.empty_state(packed.batch, self.caps))
+                      else S.empty_state(packed.batch, caps))
             if present is not None and self._assoc_hist(use_pallas,
                                                         present):
                 from .assoc import events_fm_of
@@ -465,14 +537,14 @@ class DeviceDispatcher:
         operands = to_device((events, state0), "dispatch.h2d", ctx)
         return (mode, batch_id, packed, operands, extra)
 
-    def _pack_lanes_item(self, batch_id, histories, use_pallas, jax, jnp,
+    def _pack_lanes_item(self, batch_id, histories, use_pallas, caps,
                          resume=None, ctx=None):
         from .pack import pack_lanes
         from .replay import to_device, type_signature
 
         with TRACER.span("dispatch.pack", parent=ctx) as sp:
             packed = pack_lanes(
-                histories, caps=self.caps, target_lane_len=self.lane_len,
+                histories, caps=caps, target_lane_len=self.lane_len,
                 seg_align=self.tb if use_pallas else 1,
                 domain_resolver=self.domain_resolver,
                 resume=resume,
@@ -512,7 +584,7 @@ class DeviceDispatcher:
                         _np.ascontiguousarray(reset.T),           # [T, L]
                     )
                 out0 = S.empty_state(
-                    round_scan_len(packed.n_histories), self.caps)
+                    round_scan_len(packed.n_histories), caps)
                 host = (arrays, packed.lane_state0(), out0, resume_extra)
                 mode, extra = "lanes", (sig, narrow_meta)
         operands = to_device(host, "dispatch.h2d", ctx)
@@ -550,7 +622,7 @@ class DeviceDispatcher:
         ``streamed`` is the event cells an XLA kernel streams (its
         operand's shape), None where a Pallas kernel tags the current
         span with its own, tile padding included."""
-        import jax
+        from .replay import first_rows
 
         if mode == "hist_assoc":
             from .assoc import _assoc_core
@@ -558,7 +630,7 @@ class DeviceDispatcher:
             (events, state0), (sig, b) = operands, extra
             final = _assoc_core(events, state0, types=sig)
             if b < packed.batch:
-                final = jax.tree_util.tree_map(lambda x: x[:b], final)
+                final = first_rows(final, b)
             return final, events.shape[1] * events.shape[2]
         if mode == "lanes_assoc":
             from .assoc import _assoc_core
@@ -568,10 +640,8 @@ class DeviceDispatcher:
                 evf, init, hist_bm, seg_pos, seg_lane, seg_start,
                 types=extra[0],
             )
-            final = jax.tree_util.tree_map(
-                lambda x: x[: packed.n_histories], final
-            )
-            return final, evf.shape[1] * evf.shape[2]
+            return (first_rows(final, packed.n_histories),
+                    evf.shape[1] * evf.shape[2])
         if mode == "lanes":
             arrays, state0, out0, resume_extra = operands
             sig, narrow_meta = extra
@@ -587,7 +657,7 @@ class DeviceDispatcher:
                     kw = dict(init=resume_extra[0],
                               reset_row=resume_extra[1])
                 _, final = replay_scan_pallas_packed(
-                    state0, out0, *arrays, self.caps,
+                    state0, out0, *arrays, packed.caps,
                     tb=self.tb, bt=self.bt, base=nbase,
                     wide_cols=nwide, **kw,
                 )
@@ -602,10 +672,7 @@ class DeviceDispatcher:
                     state0, out0, *arrays, types=sig, **kw
                 )
                 streamed = arrays[0].shape[0] * arrays[0].shape[1]
-            final = jax.tree_util.tree_map(
-                lambda x: x[: packed.n_histories], final
-            )
-            return final, streamed
+            return first_rows(final, packed.n_histories), streamed
         (events, state0), (narrow_meta, b) = operands, extra
         streamed = None
         if use_pallas:
@@ -615,7 +682,7 @@ class DeviceDispatcher:
                 narrow_meta if narrow_meta is not None else (None, ())
             )
             final = replay_scan_pallas_teb(
-                state0, events, self.caps, base=nbase,
+                state0, events, packed.caps, base=nbase,
                 wide_cols=nwide, bt=self.bt, tb=self.tb,
             )
         else:
@@ -628,7 +695,7 @@ class DeviceDispatcher:
         if b < packed.batch:
             # grid padding is an implementation detail; the consumer
             # sees exactly its submitted batch
-            final = jax.tree_util.tree_map(lambda x: x[:b], final)
+            final = first_rows(final, b)
         return final, streamed
 
     def _use_pallas(self) -> bool:
@@ -713,10 +780,14 @@ def replay_stream(
     replication rebuilder uses.
 
     ``bucket=True`` (implies lane packing) sorts the stream into
-    geometric depth buckets first, so mixed-depth storms don't pad every
-    lane to the deepest straggler; the return value then carries the
-    original indices per batch: [(indices, packed, final_state), ...]
-    where row j of ``final_state`` is history ``indices[j]``.
+    capacity buckets no narrower than ``caps`` (``buckets``), so a
+    history with more pending entries than ``caps`` holds still
+    replays, and those at ``caps`` into geometric depth buckets, so
+    mixed-depth storms don't pad every lane to the deepest straggler;
+    the return value then
+    carries the original indices per batch: [(indices, packed,
+    final_state), ...] where row j of ``final_state`` is history
+    ``indices[j]``, with ``packed.caps`` its batch's capacities.
 
     ``resume``: optional per-history Optional[ops.pack.ResumeState]
     aligned with ``histories`` — resumed entries carry their event
@@ -734,10 +805,11 @@ def replay_stream(
         # common serving / small-rebuild shape) must not allocate
         # double-buffer headroom it can never use
         plan: List[Tuple] = []
-        for idxs, hs in depth_buckets(histories):
+        for idxs, hs, bcaps in buckets(
+                histories, caps, resume if any_resume else None):
             for j in range(0, len(hs), batch_size):
                 plan.append((idxs[j : j + batch_size],
-                             hs[j : j + batch_size]))
+                             hs[j : j + batch_size], bcaps))
         if not plan:
             return out
         d = DeviceDispatcher(
@@ -745,10 +817,11 @@ def replay_stream(
             kernel=kernel, lane_pack=True,
             lane_len=lane_len, scan_mode=scan_mode, metrics=metrics,
         )
-        for sub, hs in plan:
+        for sub, hs, bcaps in plan:
             d.submit(
                 sub, hs,
                 resume=[resume[i] for i in sub] if any_resume else None,
+                caps=bcaps,
             )
         d.finish()
         for idxs, packed, final in d.results():

@@ -19,13 +19,25 @@ that is string- or hash-keyed so the device never chases pointers:
   * **validation**: malformed histories (orphan completions, double fires,
     slot overflow) are rejected here with the same strictness as the host
     oracle, so the kernel can assume well-formed input.
+  * **slot-table widths**: :func:`slot_peaks` measures a history's peak
+    occupancy of every slot table from its event types, before packing,
+    and :func:`bucket_caps` rounds it up to the capacity bucket the
+    history is packed and launched in. The lowest free slot is the same
+    at any capacity at or above the peak, so a history's rows do not
+    depend on which bucket it lands in.
 
-Histories whose pending sets exceed `Capacities` raise
-``PackOverflowError`` — callers route those to the host replay path.
+A history that exceeds the `Capacities` it is packed with raises
+``PackOverflowError``. The rebuild path packs each history at the caps
+of its own bucket, so that error is left for what the device cannot
+hold at all: more than ``max_events`` events, a timestamp outside the
+packable window, more version-history items than ``max_version_items``,
+or a slot table wider than :data:`WIDEST`. Callers route those to the
+host replay path.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -52,7 +64,7 @@ class PackError(Exception):
 
 
 class PackOverflowError(PackError):
-    """History exceeds slot-table capacities — route to host replay."""
+    """History exceeds the capacities it is packed with."""
 
 
 @dataclasses.dataclass
@@ -299,8 +311,12 @@ class PackedHistories:
     def presence(self, bt: int) -> Optional[np.ndarray]:
         """[B/bt, T, 4] per-(batch-tile, step) presence bitmasks for the
         Pallas kernel (ops/replay_pallas.py). None when the batch is not
-        a multiple of ``bt`` (the kernel then computes them on device)."""
+        a multiple of ``bt``, or when a slot table is wider than the one
+        32-bit slot word these masks hold (the kernel then computes them
+        on device)."""
         if self.rows_concat is None or len(self.lengths) % bt:
+            return None
+        if max(getattr(self.caps, f) for f in SLOT_TABLES) > 32:
             return None
         from cadence_tpu.native import presence_masks
 
@@ -359,12 +375,88 @@ class _SlotTable:
         if key not in self.by_key:
             raise PackError(f"unknown {self.kind} key {key!r}")
         slot = self.by_key.pop(key)
-        # insert keeping order (capacities are small)
-        i = 0
-        while i < len(self.free) and self.free[i] < slot:
-            i += 1
-        self.free.insert(i, slot)
+        bisect.insort(self.free, slot)
         return slot
+
+
+# the slot tables, as their Capacities fields, in slot_peaks() order
+SLOT_TABLES = ("max_activities", "max_timers", "max_children",
+               "max_request_cancels", "max_signals_ext")
+
+# the widest slot table a device bucket holds: the top of the
+# round_scan_len grid that bucket_caps rounds to
+WIDEST = 384
+
+# event type -> (slot table index, +1 opens an entry / -1 closes one);
+# the same events pack_workflow allocates and releases slots for
+_SLOT_EFFECT = {
+    EventType.ActivityTaskScheduled: (0, 1),
+    EventType.ActivityTaskCompleted: (0, -1),
+    EventType.ActivityTaskFailed: (0, -1),
+    EventType.ActivityTaskTimedOut: (0, -1),
+    EventType.ActivityTaskCanceled: (0, -1),
+    EventType.TimerStarted: (1, 1),
+    EventType.TimerFired: (1, -1),
+    EventType.TimerCanceled: (1, -1),
+    EventType.StartChildWorkflowExecutionInitiated: (2, 1),
+    EventType.StartChildWorkflowExecutionFailed: (2, -1),
+    EventType.ChildWorkflowExecutionCompleted: (2, -1),
+    EventType.ChildWorkflowExecutionFailed: (2, -1),
+    EventType.ChildWorkflowExecutionCanceled: (2, -1),
+    EventType.ChildWorkflowExecutionTimedOut: (2, -1),
+    EventType.ChildWorkflowExecutionTerminated: (2, -1),
+    EventType.RequestCancelExternalWorkflowExecutionInitiated: (3, 1),
+    EventType.RequestCancelExternalWorkflowExecutionFailed: (3, -1),
+    EventType.ExternalWorkflowExecutionCancelRequested: (3, -1),
+    EventType.SignalExternalWorkflowExecutionInitiated: (4, 1),
+    EventType.SignalExternalWorkflowExecutionFailed: (4, -1),
+    EventType.ExternalWorkflowExecutionSignaled: (4, -1),
+}
+
+
+def slot_peaks(batches: Sequence[Sequence[HistoryEvent]],
+               resume: Optional[PackResume] = None) -> Tuple[int, ...]:
+    """Peak occupancy of each slot table (SLOT_TABLES order) over a
+    history, or over a suffix continuing ``resume``, from one pass over
+    the event types, without packing: the most entries ``pack_workflow``
+    holds at once in each table. A malformed history measures
+    something; packing it then raises."""
+    now = [0] * len(SLOT_TABLES)
+    if resume is not None:
+        now = [len(resume.activity_slots), len(resume.timer_slots),
+               len(resume.child_slots), len(resume.cancel_slots),
+               len(resume.signal_slots)]
+    peak = list(now)
+    effect = _SLOT_EFFECT.get
+    for batch in batches:
+        for ev in batch:
+            e = effect(ev.event_type)
+            if e is not None:
+                t, d = e
+                now[t] += d
+                if now[t] > peak[t]:
+                    peak[t] = now[t]
+    return tuple(peak)
+
+
+def bucket_caps(peaks: Sequence[int],
+                floor: Optional[S.Capacities] = None) -> S.Capacities:
+    """The capacity bucket of a history with these slot peaks: each
+    table rounded up on the ``round_scan_len`` grid, never below
+    ``floor`` (default ``Capacities()``); a history inside ``floor``
+    gets ``floor`` itself. Raises PackOverflowError for a table wider
+    than :data:`WIDEST`."""
+    floor = floor or S.Capacities()
+    wide = {}
+    for field, p in zip(SLOT_TABLES, peaks):
+        cap = getattr(floor, field)
+        if p > cap:
+            if p > WIDEST:
+                raise PackOverflowError(
+                    f"{p} pending exceed the widest {field} bucket "
+                    f"{WIDEST}")
+            wide[field] = round_scan_len(p)
+    return dataclasses.replace(floor, **wide) if wide else floor
 
 
 def _timeout(a: Dict[str, Any], key: str) -> int:

@@ -882,11 +882,13 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
         events = to_device(events)
     presence = None
     if on_tpu:
-        from .replay_pallas import BT, replay_scan_pallas_teb
+        from .replay_pallas import BT, fit_tile, replay_scan_pallas_teb
 
         # smallest whole tile covering the batch (small rebuild batches
-        # shouldn't pad to the full throughput tile)
-        bt = min(BT, ((b + 1023) // 1024) * 1024)
+        # shouldn't pad to the full throughput tile), narrowed where a
+        # wide state needs it — the host masks are built for that tile
+        bt, _ = fit_tile(packed.caps, min(BT, ((b + 1023) // 1024) * 1024),
+                         ev_bytes=events.shape[1] * events.dtype.itemsize)
         with TRACER.span("replay.layout") as sp:
             presence = packed.presence(bt)
             if sp and presence is not None:
@@ -921,6 +923,14 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
             sp.set_tag("bytes", sum(
                 int(x.nbytes) for x in jax.tree_util.tree_leaves(out)))
     return out
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def first_rows(tree, n: int):
+    """The first ``n`` rows of every leaf of a pytree, as one program:
+    sliced eagerly, each leaf would be an executable of its own to
+    compile for every new shape and to dispatch on every call."""
+    return jax.tree_util.tree_map(lambda x: x[:n], tree)
 
 
 def to_device(host, span: str = "replay.h2d", parent=None):

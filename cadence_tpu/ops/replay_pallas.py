@@ -20,12 +20,15 @@ Design:
   VMEM across the whole time axis (accumulator pattern) and flushes it
   once per batch tile.
 - **Predication**: every event-type group and every slot's update is
-  wrapped in ``@pl.when(jnp.any(mask))`` — a tile only pays for the
-  event types (and slots) actually present at that timestep. Real
-  replication storms are type-homogeneous across lanes at most steps,
-  so this skips most of the transition table most of the time; the
-  worst (fully mixed) case degrades to the branchless cost, never
-  above it.
+  gated on a scalar presence bit — a tile only pays for the event types
+  (and slots) actually present at that timestep. Real replication
+  storms are type-homogeneous across lanes at most steps, so this skips
+  most of the transition table most of the time; the worst (fully
+  mixed) case degrades to the branchless cost, never above it.
+- **Any capacity**: the slot update is one loop body over 32-slot
+  blocks at a dynamic row offset, so its traced size is the same at
+  every slot-table width, and the batch tile shrinks (``fit_tile``)
+  until a wide state still fits the VMEM the kernel asks for.
 
 Semantics are identical to ops/replay.py (the oracle's, i.e. the
 reference's stateBuilder.applyEvents,
@@ -160,14 +163,16 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
     SL mainly trades VMEM for fewer grid steps; bt=8192 (SL=64) was
     best there. Neither is re-measured yet (PERF.md).
 
-    presence_ref: [1, TB, 4] SMEM — per-step scalar gates for this
+    presence_ref: [1, TB, W] SMEM — per-step scalar gates for this
              tile: words 0-1 are the event-type bitmask (bit e of word
-             e//32 set iff some lane has type e), word 2 is the
-             slot-presence bitmask (bit s%32 set iff some lane's event
-             touches slot s), word 3 is padding. Precomputed in
-             parallel by XLA outside the kernel, so the sequential loop
-             gates each type's (and slot's) block on a SCALAR bit test
-             instead of a cross-lane ``jnp.any`` reduction.
+             e//32 set iff some lane has type e), word 2 + k is the
+             slot-presence bitmask of slots 32k..32k+31 (bit s%32 set
+             iff some lane's event touches slot s of any table), and
+             ``W = presence_words(caps)`` pads to at least 4. Precomputed
+             in parallel by XLA outside the kernel, so the sequential
+             loop gates each type's (and slot block's, and slot's)
+             update on a SCALAR bit test instead of a cross-lane
+             ``jnp.any`` reduction.
     ev_ref:  [TB, EV_N, 1, SL, 128] — the time block's events
     init_ref:[R, 1, SL, 128] — initial state block (only read at t==0)
     st:      [R, 1, SL, 128] — output state block, VMEM-resident across t
@@ -177,7 +182,13 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
 
     @pl.when(t_idx == 0)
     def _():
-        st[...] = init_ref[...]
+        # eight rows at a time: the copy's code does not grow with R
+        def copy(k, c):
+            rows = pl.ds(pl.multiple_of(k * 8, 8), 8)
+            st[rows] = init_ref[rows]
+            return c
+
+        lax.fori_loop(0, rm.rows_padded // 8, copy, 0)
 
     def rd(r):
         return st[r, 0]
@@ -188,7 +199,6 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
     def step(i, carry):
         w0 = presence_ref[0, i, 0]
         w1 = presence_ref[0, i, 1]
-        w_slot = presence_ref[0, i, 2]
 
         def present(*types):
             """Scalar: any lane in this tile has one of these types."""
@@ -386,17 +396,44 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
             return carry
 
         def for_slots(types, cap, fn):
+            """``fn(s, mask)`` for each slot ``s`` < ``cap`` that some
+            lane's event touches. One loop body at every capacity: the
+            32-slot blocks go by gated on their presence word, each
+            block's slots on their bit, and ``fn`` writes rows at a
+            dynamic offset on the state's leading (untiled) dimension.
+            The bits alias across slot tables — a false positive only
+            runs the masked writes with an all-false mask, a no-op."""
+            n_words = -(-cap // 32)
+            per_word = min(cap, 32)
+            guard = n_words > 1 and cap % 32
+
             @pl.when(present(*types))
             def _():
                 base_mask = m(*types)
-                for s_i in range(cap):
-                    # scalar slot-presence gate (bit aliases across slot
-                    # tables and mod 32 — a false positive only runs the
-                    # masked writes with an all-false mask, a no-op)
-                    @pl.when((((w_slot >> (s_i % 32)) & 1) != 0))
-                    def _(s_i=s_i):
-                        mask_s = base_mask & (slot == s_i)
-                        fn(s_i, mask_s)
+
+                def block(w):
+                    word = presence_ref[0, i, 2 + w]
+
+                    def one(k, c):
+                        s_i = w * 32 + k
+                        hit = ((word >> k) & 1) != 0
+                        if guard:  # the last block runs past cap
+                            hit = hit & (s_i < cap)
+
+                        @pl.when(hit)
+                        def _():
+                            fn(s_i, base_mask & (slot == s_i))
+                        return c
+
+                    @pl.when(word != 0)
+                    def _():
+                        lax.fori_loop(0, per_word, one, 0)
+
+                if n_words == 1:
+                    block(0)
+                else:
+                    lax.fori_loop(
+                        0, n_words, lambda w, c: (block(w), c)[1], 0)
 
         # ---- pending activities
         A = rm.act0
@@ -567,6 +604,43 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
 
 BT = 4096  # default batch tile = one (32, 128) int32 block per row
 
+# the scoped VMEM the replay kernel asks for (v5e has 128 MiB), and what
+# fit_tile leaves of it to the compiler's own temporaries
+VMEM_LIMIT = 100 * 1024 * 1024
+_VMEM_SPARE = 12 * 1024 * 1024
+
+
+def presence_words(caps: S.Capacities) -> int:
+    """Words of the kernel's per-step presence block: two of event-type
+    bits, one per 32 slots of the widest slot table, at least 4 (the
+    host packer's masks, ``PackedHistories.presence``)."""
+    widest = max(caps.max_activities, caps.max_timers, caps.max_children,
+                 caps.max_request_cancels, caps.max_signals_ext)
+    return 2 + max(2, -(-widest // 32))
+
+
+def fit_tile(caps: S.Capacities, bt: int = BT, tb: int = 16,
+             ev_bytes: int = S.EV_N * 4):
+    """(tile, state_buffers): the widest batch tile, ``bt`` or ``bt``
+    halved down to 1,024 lanes, whose VMEM blocks fit the kernel's
+    limit — the [R, tile] state in and out, double-buffered while that
+    fits at some tile and else single-buffered, and ``tb`` steps of
+    ``ev_bytes`` per lane of events, double-buffered. The default caps
+    keep a 4,096-lane tile, double-buffered; a wide slot table trades
+    lanes (the storm's batches fill a few dozen of them) for rows.
+    ``ev_bytes`` is the physical event columns times their itemsize."""
+    rows = RowMap(caps).rows_padded
+    for buffers in (2, 1):
+        t = bt
+        while t >= 1024 and t % 1024 == 0:
+            need = 2 * buffers * rows * t * 4 + 2 * tb * ev_bytes * t
+            if need <= VMEM_LIMIT - _VMEM_SPARE:
+                return t, buffers
+            t //= 2
+    raise ValueError(
+        f"a state of {rows} rows does not fit {VMEM_LIMIT} bytes of VMEM "
+        f"at any batch tile")
+
 
 def _phys_map(wide_cols):
     """Logical column -> physical int16 column start; wide columns
@@ -703,6 +777,8 @@ def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
     T, ev_n, B = events_teb.shape
     R = rm.rows_padded
     n_bt = B // bt
+    n_words = presence_words(caps)
+    _, buffers = fit_tile(caps, bt, tb, ev_n * events_teb.dtype.itemsize)
     ev5 = events_teb.reshape(T, ev_n, n_bt, sl, 128)
     rows5 = rows0.reshape(R, n_bt, sl, 128)
     if base is None:
@@ -726,20 +802,22 @@ def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
         word = jnp.where(et_valid, et // 32, 0)
         bit = jnp.where(et_valid, jnp.left_shift(1, et % 32), 0)
         slot_ok = et_valid & (slot_v >= 0)
+        slot_word = jnp.where(slot_ok, slot_v // 32, -1)
         slot_bit = jnp.where(slot_ok, jnp.left_shift(1, slot_v % 32), 0)
-        words = [
-            lax.reduce(
-                jnp.where(et_valid & (word == w), bit, 0),
-                jnp.int32(0), lax.bitwise_or, (2, 3),
-            )
-            for w in (0, 1)
-        ]
-        words.append(
-            lax.reduce(slot_bit, jnp.int32(0), lax.bitwise_or, (2, 3)))
-        words.append(jnp.zeros_like(words[0]))
-        presence = jnp.stack(words, axis=-1).astype(jnp.int32)
-        presence = jnp.transpose(presence, (1, 0, 2))  # [n_bt, T, 4]
 
+        def any_bits(bits):
+            return lax.reduce(bits, jnp.int32(0), lax.bitwise_or, (2, 3))
+
+        words = [any_bits(jnp.where(et_valid & (word == w), bit, 0))
+                 for w in (0, 1)]
+        words += [any_bits(jnp.where(slot_word == w, slot_bit, 0))
+                  for w in range(n_words - 2)]
+        presence = jnp.stack(words, axis=-1).astype(jnp.int32)
+        presence = jnp.transpose(presence, (1, 0, 2))  # [n_bt, T, W]
+
+    # a state block too large to double-buffer is fetched and written
+    # back once per batch tile (fit_tile)
+    state_mode = None if buffers == 2 else pl.Buffered(1)
     grid = (n_bt, T // tb)
     out = pl.pallas_call(
         functools.partial(_kernel, rm=rm, tb=tb, ablate=ablate,
@@ -747,7 +825,7 @@ def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
         out_shape=jax.ShapeDtypeStruct((R, n_bt, sl, 128), jnp.int32),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tb, 4), lambda b, t: (b, t, 0),
+            pl.BlockSpec((1, tb, n_words), lambda b, t: (b, t, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, S.EV_N), lambda b, t: (0, 0),
                          memory_space=pltpu.SMEM),
@@ -755,14 +833,16 @@ def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
                          lambda b, t: (t, 0, b, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((R, 1, sl, 128), lambda b, t: (0, b, 0, 0),
+                         pipeline_mode=state_mode,
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((R, 1, sl, 128), lambda b, t: (0, b, 0, 0),
+                               pipeline_mode=state_mode,
                                memory_space=pltpu.VMEM),
         # double-buffered blocks (events x2, init x2, out x2) exceed the
         # 16MiB default scoped-vmem budget once n_bt > 1; v5e has 128MiB
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(presence, base2, ev5, rows5)
     return out.reshape(R, B)
@@ -789,7 +869,10 @@ def replay_scan_pallas_teb(
     [EV_N] int32 (the affine narrow stream from ``narrow_events_teb`` —
     halves the HBM traffic the kernel is bound by). Pads B to a
     multiple of ``bt`` (invalid events + empty state) and T to a
-    multiple of ``tb`` (invalid events are no-ops).
+    multiple of ``tb`` (invalid events are no-ops). ``bt`` is the widest
+    tile; a wide state gets a narrower one (``fit_tile``), and host
+    ``presence`` masks built for another tile or word count are left
+    for the kernel to compute.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -797,11 +880,13 @@ def replay_scan_pallas_teb(
     narrow = events_teb.dtype == jnp.int16
     T, ev_n, B = events_teb.shape
     rm = RowMap(caps)
+    bt, _ = fit_tile(caps, bt, tb, ev_n * events_teb.dtype.itemsize)
     b_pad = (-B) % bt
     t_pad = (-T) % tb
     span = TRACER.current()
     if span is not None:  # event cells the kernel streams, padding in
         span.set_tag("cells", (B + b_pad) * (T + t_pad))
+        span.set_tag("state_rows", rm.rows_padded)
 
     if t_pad or b_pad:
         if narrow:
@@ -819,7 +904,10 @@ def replay_scan_pallas_teb(
 
     if presence is not None:
         presence = jnp.asarray(presence)
-        if b_pad:   # host masks don't cover the padded tiles
+        if b_pad or presence.shape[::2] != (B // bt,
+                                            presence_words(caps)):
+            # host masks don't cover the padded tiles, or were built
+            # for another tile or slot width
             presence = None
         elif t_pad:
             presence = jnp.pad(presence, ((0, 0), (0, t_pad), (0, 0)))
@@ -880,7 +968,8 @@ def replay_scan_pallas_packed(
     (sentinel ``n_init`` = the appended empty row); ``state`` should
     then be ``PackedLanes.lane_state0()``. Segment boundaries are
     tb-aligned, so the between-block flush/reset needs only the
-    block-final column of ``reset_row``.
+    block-final column of ``reset_row``. ``bt`` is the widest lane
+    tile; a wide state gets a narrower one (``fit_tile``).
     Returns (final_lane_state, out).
     """
     if interpret is None:
@@ -904,98 +993,87 @@ def replay_scan_pallas_packed(
                 "packed path — pack with pack_lanes(seg_align=tb)"
             )
     rm = RowMap(caps)
+    bt, _ = fit_tile(caps, bt, tb, ev_n * events_teb.dtype.itemsize)
     b_pad = (-L) % bt
     span = TRACER.current()
     if span is not None:  # event cells the kernel streams, padding in
         span.set_tag("cells", (L + b_pad) * T)
-    if b_pad:
-        if narrow:
-            # padding must reconstruct EV_TYPE == -1 through the base
-            # (same trick as replay_scan_pallas_teb)
-            phys, _ = _phys_map(wide_cols)
-            pad_type = jnp.int16(-1 - int(np.asarray(base)[S.EV_TYPE]))
-            fill = jnp.zeros((T, ev_n, L + b_pad), jnp.int16)
-            fill = fill.at[:, phys[S.EV_TYPE], :].set(pad_type)
-        else:
-            fill = jnp.zeros((T, ev_n, L + b_pad), jnp.int32)
-            fill = fill.at[:, S.EV_TYPE, :].set(-1)
-        events_teb = fill.at[:, :, :L].set(events_teb)
-        pad_state = jax.tree_util.tree_map(
-            jnp.asarray, S.empty_state(b_pad, caps)
-        )
-        rows0 = jnp.concatenate(
-            [state_to_rows(state, rm), state_to_rows(pad_state, rm)], axis=1
-        )
-        seg_end = jnp.concatenate(
-            [jnp.asarray(seg_end),
-             jnp.zeros((b_pad, T), dtype=jnp.asarray(seg_end).dtype)],
-            axis=0,
-        )
-        out_row = jnp.concatenate(
-            [jnp.asarray(out_row), jnp.zeros((b_pad, T), jnp.int32)], axis=0
-        )
-    else:
-        rows0 = state_to_rows(state, rm)
-    lb = L + b_pad
-    n_out = out0.exec_info.shape[0]
-    out_rows0 = state_to_rows(out0, rm)
-    empty_col = state_to_rows(
-        jax.tree_util.tree_map(jnp.asarray, S.empty_state(1, caps)), rm
-    )
-    if init is None:
-        # single empty template column; every reset gathers column 0
-        init_rows = empty_col
-        reset_b = jnp.zeros((T // tb, lb), jnp.int32)
-    else:
-        if reset_row is None:
-            raise ValueError("init requires reset_row")
-        n_init = init.exec_info.shape[0]
-        init_rows = jnp.concatenate(
-            [state_to_rows(jax.tree_util.tree_map(jnp.asarray, init),
-                           rm), empty_col],
-            axis=1,
-        )
-        rr = jnp.asarray(reset_row)
-        if b_pad:
-            rr = jnp.concatenate(
-                [rr, jnp.full((b_pad, T), n_init, jnp.int32)], axis=0
-            )
-        reset_b = jnp.transpose(rr[:, tb - 1 :: tb])  # [nb, lb]
-    nb = T // tb
-    ev_blocks = events_teb.reshape(nb, tb, ev_n, lb)
-    seg_b = jnp.transpose(jnp.asarray(seg_end)[:, tb - 1 :: tb])  # [nb, lb]
-    row_b = jnp.transpose(jnp.asarray(out_row)[:, tb - 1 :: tb])
+        span.set_tag("state_rows", rm.rows_padded)
+    if init is not None and reset_row is None:
+        raise ValueError("init requires reset_row")
     # base normalized to a concrete vector: the kernel only reads it on
     # the narrow path, and zeros reproduce the None default bit-for-bit
-    base_arr = (jnp.zeros((ev_n,), jnp.int32) if base is None
-                else jnp.asarray(base, jnp.int32))
-    args = (ev_blocks, rows0, out_rows0, seg_b, row_b, reset_b,
-            init_rows, base_arr)
-    if interpret and not any(isinstance(a, jax.core.Tracer) for a in args):
+    base_arr = (np.zeros((ev_n,), np.int32) if base is None
+                else base)
+    args = (state, out0, events_teb, seg_end, out_row, base_arr, init,
+            reset_row)
+    statics = dict(caps=caps, tb=tb, bt=bt, interpret=interpret,
+                   wide_cols=tuple(wide_cols))
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    if interpret and not any(isinstance(a, jax.core.Tracer)
+                             for a in leaves):
         exe = _interp_packed_exec(
-            caps, tb, bt, tuple(wide_cols),
-            tuple(_avkey(jnp.asarray(a)) for a in args))
-        rows, out = exe(*args)
-    else:
-        rows, out = _packed_scan_core(
-            *args, caps=caps, tb=tb, bt=bt, interpret=interpret,
-            wide_cols=tuple(wide_cols))
-    return (
-        rows_to_state(rows[:, :L], rm),
-        rows_to_state(out, rm),
-    )
+            tuple(sorted(statics.items())), tree,
+            tuple(_avkey(jnp.asarray(a)) for a in leaves))
+        return exe(*args)
+    return _packed_replay(*args, **statics)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("caps", "tb", "bt", "interpret",
                                     "wide_cols"))
-def _packed_scan_core(ev_blocks, rows0, out_rows0, seg_b, row_b,
-                      reset_b, init_rows, base, *, caps, tb, bt,
-                      interpret, wide_cols):
-    """The packed block scan as one stable-identity jitted computation:
-    eager per-batch calls reuse the executable cache instead of
-    re-tracing a fresh closure every invocation (the serving pump calls
-    this once per lane-packed batch)."""
+def _packed_replay(state, out0, events_teb, seg_end, out_row, base, init,
+                   reset_row, *, caps, tb, bt, interpret, wide_cols):
+    """The device half of ``replay_scan_pallas_packed`` as one program a
+    shape: the lane padding to whole tiles, the row layouts, the block
+    scan and the unpacking. Run eagerly, each of its ~45 small
+    operations would be an executable of its own: a backend compile
+    apiece for every new shape of a storm, a dispatch apiece on every
+    launch."""
+    rm = RowMap(caps)
+    T, ev_n, L = events_teb.shape
+    b_pad = (-L) % bt
+    base = jnp.asarray(base, jnp.int32)
+    # one empty_state column: the padding lanes' state and the reset
+    # template
+    empty_col = state_to_rows(S.empty_state(1, caps), rm)
+    rows0 = state_to_rows(state, rm)
+    seg_end = jnp.asarray(seg_end)
+    out_row = jnp.asarray(out_row, jnp.int32)
+    if b_pad:
+        # padding lanes read EV_TYPE == -1 (through the base on the
+        # narrow stream: EV_TYPE is never a wide column, so it is the
+        # first physical column)
+        pad_type = (-1 - base[S.EV_TYPE]).astype(events_teb.dtype)
+        fill = jnp.zeros((T, ev_n, b_pad), events_teb.dtype)
+        fill = fill.at[:, S.EV_TYPE, :].set(pad_type)
+        events_teb = jnp.concatenate([events_teb, fill], axis=2)
+        rows0 = jnp.concatenate(
+            [rows0, jnp.broadcast_to(empty_col, (rm.rows_padded, b_pad))],
+            axis=1)
+        seg_end = jnp.concatenate(
+            [seg_end, jnp.zeros((b_pad, T), seg_end.dtype)], axis=0)
+        out_row = jnp.concatenate(
+            [out_row, jnp.zeros((b_pad, T), jnp.int32)], axis=0)
+    lb = L + b_pad
+    nb = T // tb
+    out_rows0 = state_to_rows(out0, rm)
+    if init is None:
+        # single empty template column; every reset gathers column 0
+        init_rows = empty_col
+        reset_b = jnp.zeros((nb, lb), jnp.int32)
+    else:
+        n_init = init.exec_info.shape[0]
+        init_rows = jnp.concatenate([state_to_rows(init, rm), empty_col],
+                                    axis=1)
+        rr = jnp.asarray(reset_row, jnp.int32)
+        if b_pad:
+            rr = jnp.concatenate(
+                [rr, jnp.full((b_pad, T), n_init, jnp.int32)], axis=0)
+        reset_b = jnp.transpose(rr[:, tb - 1 :: tb])  # [nb, lb]
+    ev_blocks = events_teb.reshape(nb, tb, ev_n, lb)
+    seg_b = jnp.transpose(seg_end[:, tb - 1 :: tb])  # [nb, lb]
+    row_b = jnp.transpose(out_row[:, tb - 1 :: tb])
     n_out = out_rows0.shape[1]
 
     def body(carry, xs):
@@ -1018,18 +1096,19 @@ def _packed_scan_core(ev_blocks, rows0, out_rows0, seg_b, row_b,
         )
         return (rows, out), None
 
-    (rows, out), _ = jax.lax.scan(
+    (rows, out), _ = lax.scan(
         body, (rows0, out_rows0), (ev_blocks, seg_b, row_b, reset_b)
     )
-    return rows, out
+    return rows_to_state(rows[:, :L], rm), rows_to_state(out, rm)
 
 
 @functools.lru_cache(maxsize=64)
-def _interp_packed_exec(caps, tb, bt, wide_cols, avkey):
-    avals = [jax.ShapeDtypeStruct(k[0], k[1]) for k in avkey]
-    low = _packed_scan_core.lower(
-        *avals, caps=caps, tb=tb, bt=bt, interpret=True,
-        wide_cols=wide_cols)
+def _interp_packed_exec(statics, tree, avkey):
+    """Concrete interpret-mode calls (tests, CPU serving) compile once a
+    shape at XLA opt level 0, as ``_interp_rows_exec`` does."""
+    avals = jax.tree_util.tree_unflatten(
+        tree, [jax.ShapeDtypeStruct(k[0], k[1]) for k in avkey])
+    low = _packed_replay.lower(*avals, **dict(statics))
     return low.compile({"xla_backend_optimization_level": 0})
 
 

@@ -141,9 +141,13 @@ SG_N = 4
 
 @dataclasses.dataclass(frozen=True)
 class Capacities:
-    """Slot-table sizes. Histories whose pending sets exceed these are
-    rejected at pack time and routed to the host replay path (the
-    overflow-to-host escape hatch, SURVEY.md §7 hard part (b))."""
+    """Slot-table sizes. The defaults are the floor of the rebuild
+    path's capacity buckets (ops/pack.py ``bucket_caps``): a history
+    with more pending entries is packed at a wider bucket. What no
+    bucket holds (over ``max_events`` events, a slot table wider than
+    ``pack.WIDEST``) is rejected at pack time and routed to the host
+    replay path (the overflow-to-host escape hatch, SURVEY.md §7 hard
+    part (b))."""
 
     max_events: int = 1024        # T: scan length (padded)
     max_activities: int = 32      # A

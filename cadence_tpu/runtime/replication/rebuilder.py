@@ -4,11 +4,16 @@ Reference: service/history/nDCStateRebuilder.go:92-160 — page through
 ReadHistoryBranchByBatch, replay every batch through a fresh
 stateBuilder, close as snapshot, refresh tasks.
 
-TPU-native twist: ``rebuild_many`` is the batched path — it packs N
-runs' histories into the dense ``[B, T, E]`` tensor and rebuilds all of
-them in ONE replay_scan on device (the north-star replication-storm /
-conflict-resolution-storm configuration), falling back per-workflow to
-the host oracle when a history exceeds device capacities.
+TPU-native twist: ``rebuild_many`` is the batched path — it groups N
+runs' histories by the slot-table widths they need and, at the default
+widths, by depth (``ops.dispatch.buckets``: a fan-out parent with
+hundreds of activities in flight gets a wide state of its own),
+lane-packs each group and
+replays it on device (the north-star replication-storm /
+conflict-resolution-storm configuration). Only what the device cannot
+hold at all — more than 1,024 events, a timestamp outside the packable
+window, a slot table wider than the widest bucket — falls back,
+per batch, to the host oracle.
 
 Checkpointed incremental replay (cadence_tpu/checkpoint/): with a
 ``CheckpointManager`` attached, ``rebuild_many`` consults the store per
@@ -22,7 +27,8 @@ failure degrades that request to a full replay.
 Tracing: ``rebuild_many`` is a trace entry point (utils/tracing.py
 ``Tracer.entry``) — a child of the caller's span, else a root at the
 tracer's sample rate — with spans on the caller's thread for the reads
-(``rebuild.read``), each wait on the dispatcher (``rebuild.await``),
+(``rebuild.read``), the width and depth grouping (``dispatch.bucket``),
+each wait on the dispatcher (``rebuild.await``),
 each device batch's one fetch of its final state to the host
 (``rebuild.fetch``, tagged ``histories`` and ``bytes``), each row's
 unpack from those host arrays (``rebuild.unpack``) and task refresh
@@ -72,6 +78,19 @@ class RebuildRequest:
         self.next_event_id = next_event_id
         self.request_id = request_id
         self.version_history_items = version_history_items
+
+
+def _fits(batches, resume, caps) -> bool:
+    """Does a suffix continuing ``resume`` stay inside ``caps``, the
+    capacities its checkpoint row was recorded at?"""
+    from cadence_tpu.ops.pack import (
+        PackOverflowError, bucket_caps, slot_peaks,
+    )
+
+    try:
+        return bucket_caps(slot_peaks(batches, resume.pack), caps) == caps
+    except PackOverflowError:
+        return False
 
 
 class StateRebuilder:
@@ -259,17 +278,24 @@ class StateRebuilder:
     def rebuild_many(
         self, reqs: Sequence[RebuildRequest], use_device: bool = True,
     ) -> List[Tuple[MutableState, list, list]]:
-        """Rebuild N runs at once. The device path packs all histories
-        into one [B, T, E] tensor, replays them in a single vmapped scan,
-        and rehydrates MutableState per row; any run the packer cannot
-        express (capacity overflow, payload-dependent transition) falls
-        back to the host oracle.
+        """Rebuild N runs at once. The device path groups the histories
+        by the slot-table widths they need and, at the default widths, by
+        depth, lane-packs and replays each group at its own
+        ``Capacities``, and rehydrates
+        MutableState per row; a batch the device cannot hold (over
+        1,024 events, a timestamp outside the packable window, a slot
+        table wider than the widest bucket) or the packer rejects falls
+        back to the host oracle, and counts in the root span's
+        ``host_fallbacks``. Histories replayed in a bucket wider than
+        the default count in ``wide_histories`` (the root span's tag and
+        the registry counter).
 
         With a checkpoint manager attached each request first looks up
         its newest valid snapshot: hits read + replay only the event
         suffix (the snapshot row seeds the segment carry), tip hits skip
-        the device entirely, and the rebuilt tips are written back as
-        fresh checkpoints per the manager's policy."""
+        the device entirely, and the rebuilt tips of default-width
+        buckets are written back as fresh checkpoints per the manager's
+        policy."""
         span = TRACER.entry("rebuild_many", service="history")
         with span:
             if span:
@@ -286,7 +312,7 @@ class StateRebuilder:
             from cadence_tpu.ops.dispatch import (
                 DeviceDispatcher,
                 DispatchError,
-                depth_buckets,
+                buckets,
             )
             from cadence_tpu.ops.unpack import state_row_to_mutable_state
         except ImportError:  # jax not installed — host path
@@ -309,19 +335,22 @@ class StateRebuilder:
                 sp.set_tag("events", sum(
                     len(b) for h in histories for b in h[2]))
 
-        # storm drain: depth-bucket the stream (a few deep stragglers
-        # must not stretch every lane; a resumed run buckets by its
-        # SUFFIX depth), lane-pack each bucket (several whole histories
-        # per scan lane), and pump the chunks through the
-        # double-buffered host→device dispatcher (ops/dispatch.py) so
-        # packing batch k+1 overlaps replaying batch k; each failed
-        # chunk (capacity overflow etc.) falls back per-workflow to the
-        # host oracle
+        # storm drain: bucket the stream by slot-table width (default
+        # caps are the floor: a fan-out parent's wide state pads no
+        # narrow batch) and, at the floor, by depth (a few deep
+        # stragglers must not stretch every lane; a resumed run buckets
+        # by its SUFFIX depth), lane-pack each bucket (several whole histories per scan
+        # lane), and pump the chunks through the double-buffered
+        # host→device dispatcher (ops/dispatch.py) so packing batch k+1
+        # overlaps replaying batch k; each failed chunk (over 1,024
+        # events, a table wider than the widest bucket, etc.) falls back
+        # per-workflow to the host oracle
         chunk = self._resolve_chunk()
         plan = []
-        for idxs, hs in depth_buckets(histories):
+        for idxs, hs, bcaps in buckets(histories, caps, resumes):
             for j in range(0, len(hs), chunk):
-                plan.append((idxs[j : j + chunk], hs[j : j + chunk]))
+                plan.append((idxs[j : j + chunk], hs[j : j + chunk],
+                             bcaps))
         if not plan:
             return out
         # the dispatcher is built only once the chunk plan exists, so
@@ -332,15 +361,16 @@ class StateRebuilder:
             domain_resolver=self.domain_resolver, lane_pack=True,
             lane_len=self.lane_len, metrics=self._raw_metrics,
         )
-        for sub, hs in plan:
+        for sub, hs, bcaps in plan:
             d.submit(
                 tuple(pend_req[i] for i in sub),
                 hs,
                 resume=[resumes[i] for i in sub],
+                caps=bcaps,
             )
         d.finish()
         results = d.results(strict=False)
-        on_device = fallbacks = 0
+        on_device = fallbacks = wide = 0
         while True:
             with TRACER.span("rebuild.await"):
                 item = next(results, None)
@@ -376,10 +406,19 @@ class StateRebuilder:
                 with TRACER.span("rebuild.refresh"):
                     transfer, timer = refresh_tasks(ms)
                 out[gi] = (ms, transfer, timer)
-                self._record_checkpoint(r, packed, final, j)
+                if packed.caps == caps:
+                    # lookups are at the default caps, where a wide
+                    # bucket's row would never be read back
+                    self._record_checkpoint(r, packed, final, j)
             on_device += len(idxs)
+            if packed.caps != caps:
+                wide += len(idxs)
+        if wide:
+            (self._raw_metrics if self._raw_metrics is not None else NOOP
+             ).tagged(layer="device").inc("wide_histories", wide)
         if span:
             span.set_tag("device_histories", on_device)
+            span.set_tag("wide_histories", wide)
             span.set_tag("host_fallbacks", fallbacks)
         return out
 
@@ -408,6 +447,11 @@ class StateRebuilder:
                     )
                     resume = self.checkpoints.resume_state(ckpt)
                 except Exception:  # degraded store/decode: full replay
+                    batches, resume = self._read_batches(r), None
+                    self._degrade_hit()
+                if resume is not None and not _fits(batches, resume, caps):
+                    # the suffix outgrows the snapshot's caps, and a
+                    # wider bucket cannot take its row: full replay
                     batches, resume = self._read_batches(r), None
                     self._degrade_hit()
                 if resume is not None and not batches:

@@ -252,6 +252,15 @@ DEVICE_METRICS = (
     "jit_retraces",
 )
 
+# batched rebuilds (runtime/replication/rebuilder.py rebuild_many),
+# emitted under tags (layer=device) once per call:
+#
+#   wide_histories       counter — histories replayed on the device in a
+#                        capacity bucket wider than the default
+#                        Capacities() (fan-out parents with more pending
+#                        entries than it holds; ops/dispatch.buckets)
+REBUILD_METRICS = ("wide_histories",)
+
 # continuous-batching serving engine (cadence_tpu/serving/), emitted
 # under tags (layer=serving) by the ResidentEngine and
 # (layer=serving_harness) by the open-loop load harness:

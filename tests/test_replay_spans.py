@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from cadence_tpu.ops import schema as S
-from cadence_tpu.ops.pack import pack_histories
+from cadence_tpu.ops.pack import SLOT_TABLES, pack_histories
 from cadence_tpu.runtime.persistence.memory import create_memory_bundle
 from cadence_tpu.runtime.replication.rebuilder import (
     RebuildRequest,
@@ -34,7 +34,8 @@ CAPS = S.Capacities(max_events=64)
 
 REBUILD_SPANS = {"rebuild_many", "rebuild.read", "rebuild.await",
                  "rebuild.fetch", "rebuild.unpack", "rebuild.refresh",
-                 "dispatch.pack", "dispatch.h2d", "dispatch.launch"}
+                 "dispatch.bucket", "dispatch.pack", "dispatch.h2d",
+                 "dispatch.launch"}
 REPLAY_SPANS = {"replay_packed", "replay.layout", "replay.h2d",
                 "replay.launch", "replay.fetch"}
 
@@ -107,13 +108,13 @@ def test_rebuild_many_spans_join_one_trace_across_the_pumps(
     (top,) = _byname(spans, "rebuild_many")
     assert top.parent_id == root.span_id  # a child of the caller's span
     assert top.tags == {"requests": 7, "device_histories": 7,
-                        "host_fallbacks": 0}
+                        "wide_histories": 0, "host_fallbacks": 0}
     (read,) = _byname(spans, "rebuild.read")
     assert read.tags == {"histories": 7, "events": events}
     assert len(_byname(spans, "rebuild.unpack")) == 7
     assert len(_byname(spans, "rebuild.refresh")) == 7
     for s in spans:
-        if s.name.startswith("rebuild."):
+        if s.name.startswith("rebuild.") or s.name == "dispatch.bucket":
             assert s.thread == top.thread
             want = top.span_id
         elif s.name.startswith("dispatch."):
@@ -127,6 +128,13 @@ def test_rebuild_many_spans_join_one_trace_across_the_pumps(
     packs = _byname(spans, "dispatch.pack")
     assert sum(s.tags["histories"] for s in packs) == 7
     assert sum(s.tags["events"] for s in packs) == events
+    (bucket,) = _byname(spans, "dispatch.bucket")
+    # every history at the default caps; the peaks fit inside them
+    default_slots = sum(getattr(S.Capacities(), f) for f in SLOT_TABLES)
+    used = bucket.tags.pop("slots_used")
+    assert bucket.tags == {"histories": 7, "wide_histories": 0,
+                           "buckets": len(packs), "slots": 7 * default_slots}
+    assert 0 < used <= 7 * default_slots
     h2d = _byname(spans, "dispatch.h2d")
     assert sorted(s.tags["bytes"] for s in h2d) == sorted(moved)
     got = sorted((s.tags["events"], s.tags["cells"])

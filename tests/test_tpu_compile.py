@@ -133,6 +133,39 @@ def test_replay_packed_echo_lanes(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("activities", [32, 48, 384])
+def test_replay_packed_storm_buckets(one_chip, activities):
+    """The rebuild storm's packed kernel at the default caps and at wide
+    capacity buckets up to the widest: a narrow int16 stream of a few
+    dozen lanes, whose state fits the kernel's VMEM at the tile
+    ``fit_tile`` picks (single-buffered at the widest)."""
+    from cadence_tpu.ops.replay_pallas import (
+        _phys_map, fit_tile, replay_scan_pallas_packed)
+
+    caps = S.Capacities(max_activities=activities)
+    lanes, lane_len, tb, n_out = 24, 1024, 16, 48
+    wide = (S.EV_TS, S.EV_A0)
+    _, width = _phys_map(wide)
+    assert fit_tile(caps, ev_bytes=2 * width)[0] >= 1024
+
+    # the lane padding reads the base on the host, as the dispatcher
+    # passes it
+    base = np.zeros((S.EV_N,), np.int32)
+
+    def f(state, out0, ev, seg_end, out_row):
+        return replay_scan_pallas_packed(state, out0, ev, seg_end, out_row,
+                                         caps, tb=tb, interpret=False,
+                                         base=base, wide_cols=wide)
+
+    text = _compile(
+        f, _state_sds(lanes, caps, one_chip),
+        _state_sds(n_out, caps, one_chip),
+        _sds((lane_len, width, lanes), jnp.int16, one_chip),
+        _sds((lanes, lane_len), jnp.bool_, one_chip),
+        _sds((lanes, lane_len), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("shape", [(64, 128, 8), (T, B, 24), (T, 1024, 128)])
 def test_affine_segscan(one_chip, shape):
     from cadence_tpu.ops.replay_pallas import affine_segscan_pallas
