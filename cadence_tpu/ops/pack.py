@@ -297,9 +297,11 @@ class PackedHistories:
 
     def teb(self) -> np.ndarray:
         """[T, EV_N, B] field-major — the Pallas replay kernel's native
-        operand layout (ops/replay_pallas.py). Produced by the C++
-        sidecar's fused scatter so the replay path never pays a
-        device-side transpose of the event tensor."""
+        operand layout (ops/replay_pallas.py), on the host, by the C++
+        sidecar's fused scatter. The dispatcher narrows it on the host;
+        ``replay_packed`` does not call it on TPU: there one transpose
+        on the device (``ops.replay.teb_of_rows``) costs less than this
+        scatter of the same bytes."""
         if self.rows_concat is not None:
             from cadence_tpu.native import scatter_teb
 

@@ -783,8 +783,9 @@ def replay_packed(
     Accepts :class:`PackedHistories` (one history per lane) or
     :class:`PackedLanes` (ragged lane packing; rows come back per
     history). On TPU the PackedHistories path rides the Pallas
-    VMEM-resident kernel through the packer's field-major layout + host
-    presence masks (the serving-path configuration bench.py measures);
+    VMEM-resident kernel through a field-major layout made on the
+    device + host presence masks (the serving-path configuration
+    bench.py measures);
     elsewhere the default (``scan_mode="auto"``) is the parallel-in-time
     associative path (ops/assoc.py) whenever every present event type is
     provably affine, falling back to the sequential XLA scan otherwise —
@@ -820,12 +821,17 @@ def replay_packed(
 
 def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
     """replay_packed of a PackedHistories. Its spans: the transfers to
-    the device (``replay.h2d``: the state, then the events), the host
-    layout of the kernel's operands (``replay.layout``: the events, then
-    on TPU the presence masks, built once the events' transfer is
-    issued, so that the two can overlap), the
-    kernel call (``replay.launch``) and the fetch of the final state
-    (``replay.fetch``: the wait for the kernel, then the copy back)."""
+    the device (``replay.h2d``: the state, then the events), the layout
+    of the kernel's operands (``replay.layout``: the events, then on TPU
+    their device layout and the presence masks, built once the events'
+    transfer is issued, so that the two can overlap; tagged ``bytes``
+    laid out on the host and ``device_bytes`` laid out on the device),
+    the kernel call (``replay.launch``) and the fetch of the final state
+    (``replay.fetch``: the wait for the kernel, then the copy back).
+
+    On TPU the events travel batch-major, as the packer holds them, and
+    ``teb_of_rows`` lays them out field-major on the device: one pass
+    over HBM costs less than a host scatter of the same bytes."""
     if initial is None:
         initial = packed.initial
     state = initial if initial is not None else S.empty_state(packed.batch, packed.caps)
@@ -837,7 +843,10 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
     with TRACER.span("replay.layout") as sp:
         if on_tpu:
             kernel = "pallas_teb"
-            events = packed.teb()
+            # the packer's [B, T, EV_N] events as one [B, T·EV_N] matrix:
+            # a view, whose minor dimension is whole lanes of 128 on the
+            # device, where EV_N alone would pad 8x
+            events = packed.events.reshape(b, -1)
         else:
             kernel = "scan"
             if scan_mode != "scan":
@@ -877,7 +886,8 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
                     S.empty_state(bp - b, packed.caps),
                 )
         if sp:
-            sp.set_tag("bytes", int(events.nbytes))
+            sp.set_tag("bytes", 0 if on_tpu else int(events.nbytes))
+            sp.set_tag("device_bytes", 0)
     if kernel != "assoc_hybrid":  # the hybrid reads its events on the host
         events = to_device(events)
     presence = None
@@ -888,11 +898,14 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
         # shouldn't pad to the full throughput tile), narrowed where a
         # wide state needs it — the host masks are built for that tile
         bt, _ = fit_tile(packed.caps, min(BT, ((b + 1023) // 1024) * 1024),
-                         ev_bytes=events.shape[1] * events.dtype.itemsize)
+                         ev_bytes=S.EV_N * events.dtype.itemsize)
         with TRACER.span("replay.layout") as sp:
+            events = teb_of_rows(events)
             presence = packed.presence(bt)
-            if sp and presence is not None:
-                sp.set_tag("bytes", int(presence.nbytes))
+            if sp:
+                sp.set_tag("device_bytes", int(events.nbytes))
+                sp.set_tag("bytes", 0 if presence is None
+                           else int(presence.nbytes))
     with TRACER.span("replay.launch") as sp:
         if kernel == "pallas_teb":
             final = replay_scan_pallas_teb(
@@ -923,6 +936,16 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
             sp.set_tag("bytes", sum(
                 int(x.nbytes) for x in jax.tree_util.tree_leaves(out)))
     return out
+
+
+@jax.jit
+def teb_of_rows(rows):
+    """The Pallas teb kernel's operand [T, EV_N, B] (what
+    ``PackedHistories.teb()`` builds on the host) from the packer's
+    batch-major events seen as one [B, T·EV_N] matrix: its transpose,
+    the major dimension split. One program a (B, T)."""
+    b, n = rows.shape
+    return rows.T.reshape(n // S.EV_N, S.EV_N, b)
 
 
 @functools.partial(jax.jit, static_argnums=1)

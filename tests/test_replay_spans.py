@@ -235,7 +235,7 @@ def test_replay_packed_spans_count_what_they_move(scan_mode):
     T = packed.events.shape[1]
     ev_bytes = S.EV_N * bp * T * 4
     (layout,) = _byname(spans, "replay.layout")
-    assert layout.tags == {"bytes": ev_bytes}
+    assert layout.tags == {"bytes": ev_bytes, "device_bytes": 0}
     # the state goes first, its copy overlapping the layout; the events
     # after it (the grid's padding rows are made on the device)
     state_bytes = sum(

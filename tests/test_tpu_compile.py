@@ -96,6 +96,32 @@ def test_replay_teb_int32_retry_deep(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_replay_teb_laid_out_on_device_bulk(one_chip):
+    """The bulk replay's operand at its real size: 16,384 rows of 1,024
+    steps shipped batch-major, laid out field-major by ``teb_of_rows``,
+    alone (the program each bulk call runs before the kernel) and
+    feeding the teb kernel at the tile ``replay_packed`` picks."""
+    from cadence_tpu.ops.replay import teb_of_rows
+    from cadence_tpu.ops.replay_pallas import (
+        BT, fit_tile, replay_scan_pallas_teb)
+
+    rows_b = 2 * B
+    rows = _sds((rows_b, T * S.EV_N), jnp.int32, one_chip)
+    out = teb_of_rows.lower(rows).compile().out_info
+    assert (out.shape, out.dtype) == ((T, S.EV_N, rows_b), jnp.int32)
+    bt, _ = fit_tile(RETRY_CAPS, BT)
+
+    def f(state, rows, presence):
+        return replay_scan_pallas_teb(state, teb_of_rows(rows), RETRY_CAPS,
+                                      interpret=False, bt=bt,
+                                      presence=presence)
+
+    text = _compile(
+        f, _state_sds(rows_b, RETRY_CAPS, one_chip), rows,
+        _sds((rows_b // bt, T, 4), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text
+
+
 def test_replay_teb_int16_narrow_stream(one_chip):
     from cadence_tpu.ops.replay_pallas import _phys_map, replay_scan_pallas_teb
 
