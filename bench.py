@@ -1909,8 +1909,7 @@ def measure_copy_bw_gbps(nbytes: int = 1 << 28) -> float:
 
 def _bench_config(config: str, caps, batch: int, iters: int,
                   baseline_histories: int, bt: int, tb: int,
-                  use_pallas: bool, chain: int = 1,
-                  depth_curve: bool = False):
+                  use_pallas: bool, chain: int = 1):
     """Returns a per-config result dict.
 
     ``chain`` > 1 additionally times ``chain`` kernel executions inside
@@ -1962,65 +1961,6 @@ def _bench_config(config: str, caps, batch: int, iters: int,
             (2 * state_bytes + ev_bytes_step) / (dt / T) / 1e9, 1),
     }
 
-    # ---- associative (parallel-in-time) kernel: segmented composition
-    # of affine transition updates (ops/assoc.py) — O(log T) depth
-    # instead of the scan's O(T). Same batch, same types, same
-    # replay+refresh step; parity is asserted via the chained checksum
-    # before any number is recorded.
-    from cadence_tpu.ops.assoc import _assoc_core, events_fm_of
-
-    evf = jnp.asarray(events_fm_of(events))
-
-    def step_assoc(state):
-        final = _assoc_core(evf, state, types=types)
-        return final, refresh_tasks_device(final)
-
-    try:
-        dt_a, cs_a = _time_chained(jax.jit(step_assoc), state0, iters)
-        if cs_a != cs_xla:
-            results["assoc"] = {"error": "checksum mismatch vs xla"}
-        else:
-            results["assoc"] = {
-                "histories_per_sec": round(batch / dt_a, 2),
-                "batch_rebuild_ms": round(dt_a * 1000, 3),
-                "us_per_step": round(dt_a / T * 1e6, 3),
-                # the depth-insensitivity headline: wall time of the
-                # assoc kernel over the sequential scan's on this batch
-                "vs_scan": round(dt / dt_a, 2),
-            }
-    except Exception as exc:
-        results["assoc"] = {
-            "error": f"{type(exc).__name__}: {str(exc)[:160]}"}
-
-    # ---- us_per_step depth-scaling curve (assoc vs scan): replay event
-    # PREFIXES of geometrically growing depth. The scan's us_per_step is
-    # ~flat (cost O(T)); the assoc kernel's FALLS with depth (cost
-    # O(log T) depth, so wall time is sublinear in T) — the curve is the
-    # BENCH record of that crossover.
-    if depth_curve and "error" not in results["assoc"]:
-        curve = []
-        # two points bound the compile cost (each new scan length is a
-        # fresh — minutes-scale cold — sequential-scan compile)
-        for d in sorted({max(T // 4, 8), T}):
-            ev_d = events[:, :d]
-            ev_tm_d = jnp.asarray(
-                np.ascontiguousarray(np.transpose(ev_d, (1, 0, 2))))
-            evf_d = jnp.asarray(events_fm_of(ev_d))
-            dt_s, _ = _time_chained(
-                jax.jit(lambda s: (replay_scan(s, ev_tm_d, types=types),
-                                   None)),
-                state0, max(2, iters // 2))
-            dt_p, _ = _time_chained(
-                jax.jit(lambda s: (_assoc_core(evf_d, s, types=types),
-                                   None)),
-                state0, max(2, iters // 2))
-            curve.append({
-                "depth": d,
-                "scan_us_per_step": round(dt_s / d * 1e6, 3),
-                "assoc_us_per_step": round(dt_p / d * 1e6, 3),
-                "vs_scan": round(dt_s / dt_p, 2),
-            })
-        results["assoc"]["depth_curve"] = curve
     del ev_tm
 
     # ---- Pallas kernel (field-major events + host presence masks)
@@ -2153,7 +2093,7 @@ def _bench_config(config: str, caps, batch: int, iters: int,
         r = results.get(k, {})
         return r.get("histories_per_sec", -1.0)
 
-    best_key = max(("xla", "assoc", "pallas", "pallas16"), key=_rate)
+    best_key = max(("xla", "pallas", "pallas16"), key=_rate)
     best = results[best_key]
     # steady-state (dispatch-amortized) rate is the headline when the
     # chained run exists; the per-dispatch rate stays in "kernels".
@@ -2181,10 +2121,6 @@ def _bench_config(config: str, caps, batch: int, iters: int,
         "lanes_per_history": 1.0,
         "kernels": results,
     }
-    # the assoc-vs-scan trajectory BENCH_r06+ tracks, surfaced at
-    # config level so trend tooling doesn't dig through "kernels"
-    if "vs_scan" in results.get("assoc", {}):
-        out["vs_scan"] = results["assoc"]["vs_scan"]
     return out
 
 
@@ -2480,8 +2416,7 @@ def main(device: dict) -> None:
                 chain=int(os.environ.get(
                     "BENCH_CHAIN",
                     "4" if (config == "retry_deep" and use_pallas) else "1",
-                )),
-                depth_curve=(config == "retry_deep"))
+                )))
 
     head = results["retry_deep"]
     out = {

@@ -43,7 +43,7 @@ PASSES = ("surface", "jit", "locks", "metrics", "queue")
 # baseline to the rules that could actually fire, so entries belonging
 # to skipped passes are not reported (or strict-failed) as stale
 PASS_RULE_PREFIXES = {
-    "surface": ("SURFACE-", "SCHEMA-", "ASSOC-"),
+    "surface": ("SURFACE-", "SCHEMA-"),
     "jit": ("JIT-", "PALLAS-"),
     "locks": ("LOCK-",),
     "metrics": ("METRIC-",),

@@ -512,100 +512,6 @@ def diff_surface(
     return findings
 
 
-def _assoc_write_names(written: Set[str]) -> Set[str]:
-    """Kernel write labels → the names ASSOC_COVERAGE declares: exec
-    and vh labels stay per-column, slot tables collapse to table names
-    (the emission derives whole-row masked writes per table)."""
-    out: Set[str] = set()
-    for w in written:
-        head = w.split(":", 1)[0]
-        if head in ("activities", "timers", "children", "cancels",
-                    "signals"):
-            out.add(head)
-        else:
-            out.add(w)
-    return out
-
-
-def check_assoc_coverage(kmat: KernelMatrix) -> List[Finding]:
-    """ASSOC-UNPROVEN — prove the affine decomposition covers the traced
-    write matrix.
-
-    The parallel-in-time replay (ops/assoc.py) re-derives every kernel
-    transition as a composable update; its declared coverage
-    (``ASSOC_COVERAGE`` + ``ASSOC_COMMON``) is diffed here against the
-    *traced* writes of replay_step_cols. A new transition block (or a
-    new column in an existing block) that the emission does not cover
-    fails CI instead of silently diverging between the sequential and
-    associative kernels; the runtime classifier additionally routes any
-    type outside ``assoc_types()`` to the sequential fallback. Stale
-    ``schema.UPDATE_ALGEBRA`` entries (naming cells no emission covers)
-    are flagged too.
-    """
-    from cadence_tpu.core.enums import EventType
-    from cadence_tpu.ops import schema as S
-    from cadence_tpu.ops.assoc import (
-        ASSOC_COMMON, ASSOC_COVERAGE, assoc_types,
-    )
-
-    findings: List[Finding] = []
-    provable = assoc_types()
-
-    common = _assoc_write_names(kmat.common)
-    miss = sorted(common - ASSOC_COMMON)
-    if miss:
-        findings.append(Finding(
-            "ASSOC-UNPROVEN", "assoc:common",
-            f"the kernel preamble writes {miss} which the affine "
-            "decomposition's common coverage (ops/assoc.py ASSOC_COMMON)"
-            " does not declare — replay_assoc would silently diverge",
-        ))
-
-    declared: Set[str] = set(ASSOC_COMMON)
-    for g in kmat.groups:
-        names = sorted(EventType(t).name for t in g.types)
-        key = tuple(sorted(int(t) for t in g.types))
-        cov = ASSOC_COVERAGE.get(key)
-        bad_types = sorted(
-            EventType(t).name for t in g.types if int(t) not in provable
-        )
-        if bad_types:
-            findings.append(Finding(
-                "ASSOC-UNPROVEN", f"assoc:{names[0]}:types",
-                f"event type(s) {bad_types} have a kernel transition "
-                "block but are outside assoc_types() — the associative "
-                "path would mis-classify them as no-ops",
-            ))
-        if cov is None:
-            findings.append(Finding(
-                "ASSOC-UNPROVEN", f"assoc:{names[0]}:group",
-                f"kernel transition group {names} has no declared "
-                "affine coverage (ops/assoc.py ASSOC_COVERAGE) — its "
-                "writes are unproven for the associative path",
-            ))
-            continue
-        declared |= cov
-        miss = sorted(_assoc_write_names(g.written) - cov - ASSOC_COMMON)
-        if miss:
-            findings.append(Finding(
-                "ASSOC-UNPROVEN", f"assoc:{names[0]}:writes",
-                f"kernel group {names} writes {miss} which its declared "
-                "affine coverage does not include — extend the "
-                "ops/assoc.py emission (and ASSOC_COVERAGE) or route "
-                "the type to the sequential fallback",
-            ))
-
-    for label in sorted(S.UPDATE_ALGEBRA):
-        if label not in declared:
-            findings.append(Finding(
-                "ASSOC-UNPROVEN", f"assoc:algebra:{label}",
-                f"schema.UPDATE_ALGEBRA declares {label!r} "
-                f"({S.UPDATE_ALGEBRA[label]}) but no transition group's "
-                "assoc coverage writes it — stale metadata",
-            ))
-    return findings
-
-
 def check_ts_coverage(
     kmat: KernelMatrix, ns: Optional[dict] = None
 ) -> List[Finding]:
@@ -682,7 +588,6 @@ def run(repo_root: str) -> List[Finding]:
     kmat, otable, pack_handled, _ = build(repo_root)
     findings += diff_surface(kmat, otable, pack_handled=pack_handled)
     findings += check_ts_coverage(kmat)
-    findings += check_assoc_coverage(kmat)
     return findings
 
 

@@ -24,9 +24,6 @@ _CONTRACT_MODULES = (
     "cadence_tpu.ops.pack",
     "cadence_tpu.ops.replay",
     "cadence_tpu.ops.replay_pallas",
-    # the associative (parallel-in-time) kernel consumes checkpoint rows
-    # as segment base states — its semantics are part of the contract
-    "cadence_tpu.ops.assoc",
 )
 
 _FINGERPRINT: str = ""

@@ -233,9 +233,9 @@ class ServingConfig:
     When enabled, the history service keeps hot workflows' state rows
     resident in a fixed-``lanes`` device tensor: every persisted event
     batch marks the lane behind (O(1) on the persist path), the next
-    serving tick composes just the Δ suffix through the assoc affine
-    algebra, and serving reads answer from the resident row with no
-    replay. ``idleTicks`` is the LRU eviction horizon (a lane untouched
+    serving tick replays just the Δ suffix from the lane's row, and
+    serving reads answer from the resident row with no replay.
+    ``idleTicks`` is the LRU eviction horizon (a lane untouched
     that many ticks flushes back through the checkpoint plane and its
     slot is recycled for the admission queue). OFF by default: a
     disabled section builds nothing and the persist path pays nothing.

@@ -231,10 +231,10 @@ class TaskListType(enum.IntEnum):
 
 
 # Workflow close event type -> CloseStatus recorded on X_CLOSE_STATUS:
-# the single source of truth every replay kernel (sequential XLA scan,
-# Pallas, both associative evaluators in ops/assoc.py) derives its
-# close-status arithmetic from, so a new close type lands in all of
-# them at once instead of four hand-kept copies.
+# the single source of truth every replay kernel (the sequential XLA
+# scan and the Pallas kernels) derives its close-status arithmetic from,
+# so a new close type lands in all of them at once instead of hand-kept
+# copies.
 WORKFLOW_CLOSE_STATUS = (
     (EventType.WorkflowExecutionCompleted, CloseStatus.Completed),
     (EventType.WorkflowExecutionFailed, CloseStatus.Failed),

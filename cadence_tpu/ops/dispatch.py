@@ -10,7 +10,10 @@ and the jitted replay call return immediately, so pack(k+1) runs on the
 CPU while replay(k) runs on the device, and the bounded stage queue
 (``depth``) provides the double-buffer backpressure.
 
-Two storm levers ride on top of the pipeline:
+The kernel is the Pallas one on a TPU and the XLA scan elsewhere
+(``replay_pallas.on_tpu``); on a TPU the events travel as an int16
+stream where their values allow it (``_narrow``). Two storm levers ride
+on top of the pipeline:
 
 * **ragged lane packing** (``lane_pack=True``): the pack pump calls
   ops/pack.pack_lanes so several whole histories share each scan lane,
@@ -73,10 +76,9 @@ def _jit_cache_total() -> int:
     must degrade, never break dispatch."""
     total = 0
     try:
-        from .assoc import _assoc_core
         from .replay import replay_scan_jit, replay_scan_packed_jit
 
-        for fn in (replay_scan_jit, replay_scan_packed_jit, _assoc_core):
+        for fn in (replay_scan_jit, replay_scan_packed_jit):
             size = getattr(fn, "_cache_size", None)
             if size is not None:
                 total += int(size())
@@ -217,14 +219,11 @@ class DeviceDispatcher:
         self,
         caps: Optional[S.Capacities] = None,
         depth: int = 2,
-        kernel: str = "auto",
-        narrow: bool = True,
         domain_resolver=None,
         bt: int = 4096,
         tb: int = 16,
         lane_pack: bool = False,
         lane_len: Optional[int] = None,
-        scan_mode: str = "auto",
         metrics: Optional[Scope] = None,
     ) -> None:
         self.caps = caps or S.Capacities()
@@ -241,28 +240,6 @@ class DeviceDispatcher:
         self._metrics = (metrics if metrics is not None else NOOP).tagged(
             layer="device"
         )
-        # which time-axis kernel the run pump uses:
-        #   "scan"  — the sequential O(T)-depth kernels everywhere.
-        #   "assoc" — the parallel-in-time associative path
-        #             (ops/assoc.py) for both unpacked and lane-packed
-        #             batches (lane-packed falls back per batch when a
-        #             type is not provably affine).
-        #   "auto"  — assoc for both unpacked AND lane-packed XLA
-        #             batches when every present type is provably
-        #             affine (unpacked: scan depth is the cost, ~10x on
-        #             retry_deep/ndc_storm; lane-packed: the former
-        #             provenance-scatter regression on shallow batches
-        #             is gone — batch-major planes + the flat
-        #             scatter-max provenance measure 0.3-1.0x the
-        #             sequential packed scan across shallow shapes,
-        #             winning past ~128 histories), sequential for the
-        #             Pallas serving path on TPU.
-        if scan_mode not in ("auto", "scan", "assoc"):
-            raise ValueError(
-                "scan_mode must be 'auto', 'scan', or 'assoc' "
-                f"(got {scan_mode!r})"
-            )
-        self.scan_mode = scan_mode
         # threaded into pack_workflow: side-table target domains must
         # be RESOLVED ids, matching the host oracle (StateBuilder)
         self.domain_resolver = domain_resolver
@@ -276,12 +253,9 @@ class DeviceDispatcher:
         # i.e. the longest history in each batch)
         self.lane_pack = lane_pack
         self.lane_len = lane_len
-        # int16 narrow event stream (replay_pallas.narrow_events_teb):
-        # halves both the H2D transfer and the HBM stream the kernel is
-        # bound by; falls back per batch when a gating column is wide.
-        # The wide set only GROWS across batches (passed as force_wide)
+        # the Pallas kernels' int16 event stream (_narrow): the union of
+        # wide columns only GROWS across batches (passed as force_wide)
         # so the kernel specialization key stays stable mid-storm
-        self.narrow = narrow
         self._wide_set: set = set()
         # present-event-type union across batches: the packed scan's
         # static specialization key (replay.type_signature) — grows
@@ -290,7 +264,6 @@ class DeviceDispatcher:
         self._in: "queue.Queue" = queue.Queue()
         self._staged: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._out: "queue.Queue" = queue.Queue()
-        self._kernel = kernel
         self._packer = threading.Thread(
             target=self._pack_pump, name="dispatch-pack", daemon=True
         )
@@ -337,9 +310,9 @@ class DeviceDispatcher:
 
     def _pack_pump(self) -> None:
         try:
-            import jax  # noqa: F401
+            from .replay_pallas import on_tpu
 
-            from .pack import pack_histories  # noqa: F401
+            use_pallas = on_tpu()
         except Exception as e:
             # no usable jax on this host: every queued batch fails fast
             # (the rebuilder falls back per batch) instead of the pump
@@ -351,7 +324,6 @@ class DeviceDispatcher:
                     return
                 self._staged.put(DispatchError(item[0], e))
 
-        use_pallas = self._use_pallas()
         while True:
             item = self._in.get()
             if item is None:
@@ -399,7 +371,7 @@ class DeviceDispatcher:
         scope = self._device_scope(mode, use_pallas)
         scope.inc("device_batches")
         scope.record("host_stage_seconds", stage_s)
-        if mode.startswith("lanes"):
+        if mode == "lanes":
             real = packed.total_events
             width = packed.lanes
             scope.inc("lanes", packed.lanes)
@@ -424,64 +396,25 @@ class DeviceDispatcher:
             if delta:
                 self._metrics.inc("jit_retraces", delta)
 
-    def _assoc_enabled(self, use_pallas: bool) -> bool:
-        """Can any batch ride the associative kernels on this host?
-        Mirrors the serving facades' gate (replay_packed /
-        replay_packed_lanes): off-TPU only — a forced ``kernel="xla"``
-        on a TPU host must not route the never-TPU-validated assoc
-        kernel onto the TPU backend (the Pallas/TPU assoc path is an
-        open ROADMAP item)."""
-        if use_pallas or self.scan_mode == "scan":
-            return False
-        import jax
-
-        return jax.default_backend() != "tpu"
-
-    def _assoc_hist(self, use_pallas: bool, present) -> bool:
-        """Should this unpacked batch ride the associative kernel?
-        ``present`` is THIS batch's type set, not the monotone
-        ``_type_set`` — one batch carrying a (future) non-affine type
-        must not downgrade every later affine batch in the stream."""
-        if not self._assoc_enabled(use_pallas):
-            return False
-        from .replay import assoc_classify_types
-
-        _, non = assoc_classify_types(present)
-        return not non
-
-    def _assoc_lanes(self, use_pallas: bool, present) -> bool:
-        """Lane-packed twin of _assoc_hist: ``auto`` routes affine
-        batches to the associative kernel too (mirroring the serving
-        facade replay_packed_lanes — the dispatcher used to hold lanes
-        back on the since-fixed shallow-batch provenance-scatter
-        regression; see the scan_mode comment above)."""
-        if not self._assoc_enabled(use_pallas):
-            return False
-        from .replay import assoc_classify_types
-
-        _, non = assoc_classify_types(present)
-        return not non
-
     def _narrow(self, teb):
-        """The int16 stream of a field-major event tensor, or None
-        (narrowing off, or a gating column is wide) — then the kernel
-        takes ``teb`` as is. Returns (events, narrow_meta)."""
-        if self.narrow:
-            from .replay_pallas import narrow_events_teb
+        """The int16 stream of a field-major event tensor
+        (``replay_pallas.narrow_events_teb``): it halves both the H2D
+        transfer and the HBM stream the kernel is bound by. Where a
+        gating column is wide, the kernel takes ``teb`` as is. Returns
+        (events, narrow_meta), narrow_meta None for ``teb`` itself."""
+        from .replay_pallas import narrow_events_teb
 
-            narrowed = narrow_events_teb(
-                teb, force_wide=tuple(sorted(self._wide_set))
-            )
-            if narrowed is not None:
-                ev16, nbase, nwide = narrowed
-                self._wide_set.update(nwide)
-                return ev16, (nbase, nwide)
-        return teb, None
+        narrowed = narrow_events_teb(
+            teb, force_wide=tuple(sorted(self._wide_set))
+        )
+        if narrowed is None:
+            return teb, None
+        ev16, nbase, nwide = narrowed
+        self._wide_set.update(nwide)
+        return ev16, (nbase, nwide)
 
     def _pack_hist_item(self, batch_id, histories, use_pallas, caps,
                         resume=None, ctx=None):
-        import numpy as _np
-
         from .pack import pack_histories
         from .replay import to_device
 
@@ -498,44 +431,17 @@ class DeviceDispatcher:
                 sp.set_tag("histories", b)
                 sp.set_tag("events", int(packed.lengths.sum()))
                 sp.set_tag("lanes", packed.batch)
-            # present-type scan is a full [B, T] host pass; skip it when
-            # the assoc path is statically off (scan/pallas/TPU backend)
-            # — _assoc_hist would ignore the result and the "hist"
-            # branch replays unspecialized
-            present = None
-            if self._assoc_enabled(use_pallas):
-                present = [
-                    int(t)
-                    for t in _np.unique(packed.events[:, :, S.EV_TYPE])
-                    if t >= 0
-                ]
-                self._type_set.update(present)
             # checkpoint resume seeds the initial carries; padding rows
             # of packed.initial are empty_state, so the grid pad is
             # unchanged
             state0 = (packed.initial if packed.initial is not None
                       else S.empty_state(packed.batch, caps))
-            if present is not None and self._assoc_hist(use_pallas,
-                                                        present):
-                from .assoc import events_fm_of
-                from .replay import type_signature
-
-                # field-major column planes — the assoc kernel's operand
-                # layout; built host-side so the copy overlaps device
-                # work
-                mode = "hist_assoc"
-                events = events_fm_of(packed.events)
-                extra = (type_signature(self._type_set), b)
-            elif use_pallas:
-                mode = "hist"
+            if use_pallas:
                 events, narrow_meta = self._narrow(packed.teb())
-                extra = (narrow_meta, b)
             else:
-                mode = "hist"
-                events = packed.time_major()
-                extra = (None, b)
+                events, narrow_meta = packed.time_major(), None
         operands = to_device((events, state0), "dispatch.h2d", ctx)
-        return (mode, batch_id, packed, operands, extra)
+        return ("hist", batch_id, packed, operands, (narrow_meta, b))
 
     def _pack_lanes_item(self, batch_id, histories, use_pallas, caps,
                          resume=None, ctx=None):
@@ -555,43 +461,34 @@ class DeviceDispatcher:
                 sp.set_tag("lanes", packed.lanes)
             self._type_set.update(packed.present_types)
             sig = type_signature(self._type_set)
-            if self._assoc_lanes(use_pallas, packed.present_types):
-                from .assoc import assoc_lanes_operands, events_fm_of
-
-                init, hist_bm, seg_pos, seg_lane, seg_start = (
-                    assoc_lanes_operands(packed))
-                host = ((events_fm_of(packed.events), hist_bm, seg_pos,
-                         seg_lane, seg_start), init)
-                mode, extra = "lanes_assoc", (sig,)
+            narrow_meta = None
+            if use_pallas:
+                events, narrow_meta = self._narrow(packed.teb())
+                arrays = (events, packed.seg_end, packed.out_row)
             else:
-                narrow_meta = None
-                if use_pallas:
-                    events, narrow_meta = self._narrow(packed.teb())
-                    arrays = (events, packed.seg_end, packed.out_row)
-                else:
-                    arrays = packed.time_major()
-                # checkpoint resume: lanes whose first segment resumes
-                # seed from the snapshot row; segment-end resets gather
-                # the NEXT segment's initial row via the reset table
-                # (ops/replay.replay_scan_packed)
-                resume_extra = None
-                if packed.initial is not None:
-                    import numpy as _np
+                arrays = packed.time_major()
+            # checkpoint resume: lanes whose first segment resumes seed
+            # from the snapshot row; segment-end resets gather the NEXT
+            # segment's initial row via the reset table
+            # (ops/replay.replay_scan_packed)
+            resume_extra = None
+            if packed.initial is not None:
+                import numpy as _np
 
-                    reset = packed.reset_rows()                   # [L, T]
-                    resume_extra = (
-                        packed.initial, reset,
-                        _np.ascontiguousarray(reset.T),           # [T, L]
-                    )
-                out0 = S.empty_state(
-                    round_scan_len(packed.n_histories), caps)
-                host = (arrays, packed.lane_state0(), out0, resume_extra)
-                mode, extra = "lanes", (sig, narrow_meta)
+                reset = packed.reset_rows()                       # [L, T]
+                resume_extra = (
+                    packed.initial, reset,
+                    _np.ascontiguousarray(reset.T),               # [T, L]
+                )
+            out0 = S.empty_state(round_scan_len(packed.n_histories), caps)
+            host = (arrays, packed.lane_state0(), out0, resume_extra)
         operands = to_device(host, "dispatch.h2d", ctx)
-        return (mode, batch_id, packed, operands, extra)
+        return ("lanes", batch_id, packed, operands, (sig, narrow_meta))
 
     def _run_pump(self) -> None:
-        use_pallas = self._use_pallas()
+        from .replay_pallas import on_tpu
+
+        use_pallas = on_tpu()
         while True:
             item = self._staged.get()
             if item is None:
@@ -624,24 +521,6 @@ class DeviceDispatcher:
         span with its own, tile padding included."""
         from .replay import first_rows
 
-        if mode == "hist_assoc":
-            from .assoc import _assoc_core
-
-            (events, state0), (sig, b) = operands, extra
-            final = _assoc_core(events, state0, types=sig)
-            if b < packed.batch:
-                final = first_rows(final, b)
-            return final, events.shape[1] * events.shape[2]
-        if mode == "lanes_assoc":
-            from .assoc import _assoc_core
-
-            (evf, hist_bm, seg_pos, seg_lane, seg_start), init = operands
-            final = _assoc_core(
-                evf, init, hist_bm, seg_pos, seg_lane, seg_start,
-                types=extra[0],
-            )
-            return (first_rows(final, packed.n_histories),
-                    evf.shape[1] * evf.shape[2])
         if mode == "lanes":
             arrays, state0, out0, resume_extra = operands
             sig, narrow_meta = extra
@@ -697,13 +576,6 @@ class DeviceDispatcher:
             # sees exactly its submitted batch
             final = first_rows(final, b)
         return final, streamed
-
-    def _use_pallas(self) -> bool:
-        if self._kernel == "auto":
-            import jax
-
-            return jax.default_backend() == "tpu"
-        return self._kernel == "pallas"
 
     # -- consumer side ----------------------------------------------------
 
@@ -765,12 +637,10 @@ def replay_stream(
     caps: Optional[S.Capacities] = None,
     batch_size: int = 4096,
     depth: int = 2,
-    kernel: str = "auto",
     lane_pack: bool = False,
     lane_len: Optional[int] = None,
     bucket: bool = False,
     resume: Optional[Sequence] = None,
-    scan_mode: str = "auto",
     metrics: Optional[Scope] = None,
 ) -> List[Tuple]:
     """Replay a large history stream through the pipelined dispatcher.
@@ -814,8 +684,7 @@ def replay_stream(
             return out
         d = DeviceDispatcher(
             caps=caps, depth=staging_depth(len(plan), depth),
-            kernel=kernel, lane_pack=True,
-            lane_len=lane_len, scan_mode=scan_mode, metrics=metrics,
+            lane_pack=True, lane_len=lane_len, metrics=metrics,
         )
         for sub, hs, bcaps in plan:
             d.submit(
@@ -831,9 +700,8 @@ def replay_stream(
         return out
     n_batches = -(-len(histories) // batch_size)
     d = DeviceDispatcher(
-        caps=caps, depth=staging_depth(n_batches, depth), kernel=kernel,
-        lane_pack=lane_pack,
-        lane_len=lane_len, scan_mode=scan_mode, metrics=metrics,
+        caps=caps, depth=staging_depth(n_batches, depth),
+        lane_pack=lane_pack, lane_len=lane_len, metrics=metrics,
     )
     for i in range(0, len(histories), batch_size):
         d.submit(

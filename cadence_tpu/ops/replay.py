@@ -50,7 +50,7 @@ from .pack import PackedHistories, PackedLanes, round_scan_len
 
 
 # Transition-table groups: each tuple is the event-type set gating one
-# update block of replay_step. ``type_signature`` canonicalizes a
+# update block of replay_step_cols. ``type_signature`` canonicalizes a
 # batch's present-type set to the union of touched groups, so the
 # jit specialization key is "which blocks run", not the raw type list —
 # a bounded, storm-stable set of executables.
@@ -97,19 +97,8 @@ def _type_groups():
     return _TYPE_GROUPS
 
 
-def check_scan_mode(scan_mode: str, allowed=("auto", "scan", "assoc")):
-    """Reject unknown ``scan_mode`` strings up front: the kernel
-    selectors otherwise each read the string differently, so a typo
-    ("asoc", "Scan") would silently pick a kernel instead of erroring."""
-    if scan_mode not in allowed:
-        raise ValueError(
-            f"scan_mode must be one of {'/'.join(allowed)} "
-            f"(got {scan_mode!r})"
-        )
-
-
 def type_signature(present) -> tuple:
-    """Canonical static type set for ``replay_step(types=...)``.
+    """Canonical static type set for ``replay_step_cols(types=...)``.
 
     Expands the batch's present event types to whole transition groups
     (a group either runs or is statically skipped), returned as a sorted
@@ -253,7 +242,7 @@ def replay_step_cols(cols, ev: jnp.ndarray, types: Optional[tuple] = None):
     # the table and an unclamped gather's out-of-bounds semantics are
     # backend-defined; the clamped read keeps overflowed states (chained
     # bench iterations, not real histories) deterministic and identical
-    # across the scan / Pallas / assoc kernels. write_idx keeps the raw
+    # across the scan and Pallas kernels. write_idx keeps the raw
     # last_idx so same-version writes past capacity still match no slot.
     last_ver = jnp.take_along_axis(
         vh_v, jnp.minimum(last_idx, cap_v - 1)[:, None], axis=1)[:, 0]
@@ -483,17 +472,6 @@ def replay_step_cols(cols, ev: jnp.ndarray, types: Optional[tuple] = None):
     )
 
 
-def replay_step(
-    state: S.StateTensors, ev: jnp.ndarray, types: Optional[tuple] = None,
-) -> S.StateTensors:
-    """Apply one event row per workflow. ev: [B, EV_N] int32.
-
-    Single-step convenience wrapper over ``replay_step_cols`` (which the
-    scans use directly so the column conversion happens once per scan,
-    not once per step)."""
-    return cols_to_state(replay_step_cols(state_to_cols(state), ev, types))
-
-
 def replay_scan(
     state: S.StateTensors, events_tm: jnp.ndarray,
     unroll: Optional[int] = None,
@@ -511,12 +489,18 @@ def replay_scan(
     ``types``: static present-type tuple (``type_signature``) —
     statically skips transition blocks the batch cannot touch."""
     if unroll is None:
-        unroll = 8 if jax.default_backend() == "tpu" else 1
+        unroll = _unroll()
     final, _ = lax.scan(
         lambda s, ev: (replay_step_cols(s, ev, types=types), None),
         state_to_cols(state), events_tm, unroll=unroll,
     )
     return cols_to_state(final)
+
+
+def _unroll() -> int:
+    from .replay_pallas import on_tpu
+
+    return 8 if on_tpu() else 1
 
 
 replay_scan_jit = jax.jit(
@@ -617,7 +601,7 @@ def replay_scan_packed(
     Returns (final_lane_state, out) — callers read ``out``.
     """
     if unroll is None:
-        unroll = 8 if jax.default_backend() == "tpu" else 1
+        unroll = _unroll()
     caps = _caps_of(out0)
     n_out = out0.exec_info.shape[0]
     out_cols0 = state_to_cols(out0)
@@ -684,7 +668,6 @@ replay_scan_packed_jit = jax.jit(
 def replay_packed_lanes(
     packed: PackedLanes, specialize: bool = True,
     initial: Optional[S.StateTensors] = None,
-    scan_mode: str = "auto",
 ) -> S.StateTensors:
     """Replay a lane-packed batch; returns numpy state with one row per
     history, in input order (``packed.side`` indexes it directly).
@@ -695,35 +678,14 @@ def replay_packed_lanes(
     its row instead of ``empty_state``, bit-identically to replaying
     the full history from scratch.
 
-    ``scan_mode``: ``"scan"`` = the sequential O(T)-depth kernels;
-    ``"assoc"`` = the parallel-in-time associative path (ops/assoc.py,
-    segment resets ride the packer's segment table); ``"auto"`` picks
-    assoc off-TPU when every present type is provably affine — the
-    sequential scan otherwise. The lane-packed assoc path has no
-    per-event hybrid chunker, so a batch with a non-affine type falls
-    back to the sequential packed scan under BOTH ``"auto"`` and a
-    forced ``"assoc"``. On TPU every ``scan_mode`` rides the serving
-    kernels below (the Pallas/TPU assoc path is still an open item —
-    see ROADMAP).
-
     On TPU, lanes packed with ``seg_align`` a multiple of the Pallas
     time block ride the chunked VMEM-resident kernel
     (ops/replay_pallas.py replay_scan_pallas_packed); everywhere else —
     and for unaligned packings — the XLA scan handles arbitrary segment
     boundaries."""
-    check_scan_mode(scan_mode)
-    caps = packed.caps
-    if scan_mode != "scan" and jax.default_backend() != "tpu":
-        from .assoc import classify_types, replay_assoc_lanes
+    from .replay_pallas import on_tpu, replay_scan_pallas_packed
 
-        _, non = classify_types(packed.present_types)
-        if not non:
-            # unspecialized on this facade: one compile per SHAPE. The
-            # per-type-set specialization only pays when a storm reuses
-            # one signature (the dispatcher grows a monotone set for
-            # exactly that); here it would recompile per batch.
-            return replay_assoc_lanes(
-                packed, initial=initial, specialize=False)
+    caps = packed.caps
     if initial is None:
         initial = packed.initial
     n_pad = round_scan_len(packed.n_histories)
@@ -743,10 +705,7 @@ def replay_packed_lanes(
         init_j = jax.tree_util.tree_map(jnp.asarray, initial)
         reset = packed.reset_rows()
     types = type_signature(packed.present_types) if specialize else None
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and packed.seg_align % 8 == 0:
-        from .replay_pallas import replay_scan_pallas_packed
-
+    if on_tpu() and packed.seg_align % 8 == 0:
         _, out = replay_scan_pallas_packed(
             state0, out0, jnp.asarray(packed.teb()),
             jnp.asarray(packed.seg_end), jnp.asarray(packed.out_row),
@@ -776,7 +735,6 @@ def replay_packed_lanes(
 def replay_packed(
     packed,
     initial: Optional[S.StateTensors] = None,
-    scan_mode: str = "auto",
 ) -> S.StateTensors:
     """Replay a packed batch on the default device; returns numpy state.
 
@@ -784,24 +742,15 @@ def replay_packed(
     :class:`PackedLanes` (ragged lane packing; rows come back per
     history). On TPU the PackedHistories path rides the Pallas
     VMEM-resident kernel through a field-major layout made on the
-    device + host presence masks (the serving-path configuration
-    bench.py measures);
-    elsewhere the default (``scan_mode="auto"``) is the parallel-in-time
-    associative path (ops/assoc.py) whenever every present event type is
-    provably affine, falling back to the sequential XLA scan otherwise —
-    all paths are bit-identical (tests/test_fuzz_differential.py).
-    ``scan_mode="scan"`` forces the sequential kernels;
-    ``scan_mode="assoc"`` forces the associative one (hybrid-chunking
-    around any nonaffine steps) — off TPU only: on a TPU backend every
-    mode rides the Pallas/sequential serving path, the TPU assoc
-    benchmark being an open ROADMAP item. The XLA batch dimension is padded to
-    the geometric shape grid (``round_scan_len``) so a storm of
-    arbitrary batch sizes compiles a bounded set of executables.
+    device + host presence masks; elsewhere it rides the sequential XLA
+    scan, its batch dimension padded to the geometric shape grid
+    (``round_scan_len``) so a storm of arbitrary batch sizes compiles a
+    bounded set of executables. Both are bit-identical to the host
+    oracle (tests/test_replay_differential.py).
 
     A trace entry point (``Tracer.entry``): the ``replay_packed`` span is
     a child of the caller's span, else a root at the tracer's sample
     rate."""
-    check_scan_mode(scan_mode)
     span = TRACER.entry("replay_packed", service="replay")
     with span:
         if isinstance(packed, PackedLanes):
@@ -811,15 +760,14 @@ def replay_packed(
             # initial: [n_histories] per-history resume carries
             # (checkpoint rows); defaults to packed.initial from
             # pack_lanes(resume=...)
-            return replay_packed_lanes(
-                packed, initial=initial, scan_mode=scan_mode)
+            return replay_packed_lanes(packed, initial=initial)
         if span:
             span.set_tag("histories", packed.batch)
             span.set_tag("events", int(packed.lengths.sum()))
-        return _replay_histories(packed, initial, scan_mode)
+        return _replay_histories(packed, initial)
 
 
-def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
+def _replay_histories(packed, initial) -> S.StateTensors:
     """replay_packed of a PackedHistories. Its spans: the transfers to
     the device (``replay.h2d``: the state, then the events), the layout
     of the kernel's operands (``replay.layout``: the events, then on TPU
@@ -838,45 +786,23 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
     state = to_device(state)
     if packed.batch == 0:
         return jax.tree_util.tree_map(np.asarray, state)
+    from .replay_pallas import BT, fit_tile, on_tpu, replay_scan_pallas_teb
+
     b = bp = packed.batch
-    on_tpu = jax.default_backend() == "tpu"
+    tpu = on_tpu()
     with TRACER.span("replay.layout") as sp:
-        if on_tpu:
-            kernel = "pallas_teb"
+        if tpu:
             # the packer's [B, T, EV_N] events as one [B, T·EV_N] matrix:
             # a view, whose minor dimension is whole lanes of 128 on the
             # device, where EV_N alone would pad 8x
             events = packed.events.reshape(b, -1)
         else:
-            kernel = "scan"
-            if scan_mode != "scan":
-                from .assoc import classify_types
-
-                present = [
-                    int(t)
-                    for t in np.unique(packed.events[:, :, S.EV_TYPE])
-                    if t >= 0
-                ]
-                _, non = classify_types(present)
-                if scan_mode == "assoc" or not non:
-                    # hybrid: sequential steps only at nonaffine events
-                    kernel = "assoc_hybrid" if non else "assoc"
             # the XLA batch dimension pads to the geometric shape grid
             bp = round_scan_len(b)
-            if kernel == "scan":
-                events = packed.time_major()               # [T, B, EV_N]
-                shape = (events.shape[0], bp - b, S.EV_N)
-            else:
-                from .assoc import events_fm_of
-
-                events = events_fm_of(packed.events)       # [EV_N, B, T]
-                shape = (S.EV_N, bp - b, events.shape[2])
+            events = packed.time_major()                   # [T, B, EV_N]
             if bp > b:
-                pad = np.zeros(shape, np.int32)
-                if kernel == "scan":
-                    pad[:, :, S.EV_TYPE] = -1
-                else:
-                    pad[S.EV_TYPE] = -1
+                pad = np.zeros((events.shape[0], bp - b, S.EV_N), np.int32)
+                pad[:, :, S.EV_TYPE] = -1
                 events = np.concatenate([events, pad], axis=1)
                 state = jax.tree_util.tree_map(
                     lambda x, p: jnp.concatenate(
@@ -886,14 +812,10 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
                     S.empty_state(bp - b, packed.caps),
                 )
         if sp:
-            sp.set_tag("bytes", 0 if on_tpu else int(events.nbytes))
+            sp.set_tag("bytes", 0 if tpu else int(events.nbytes))
             sp.set_tag("device_bytes", 0)
-    if kernel != "assoc_hybrid":  # the hybrid reads its events on the host
-        events = to_device(events)
-    presence = None
-    if on_tpu:
-        from .replay_pallas import BT, fit_tile, replay_scan_pallas_teb
-
+    events = to_device(events)
+    if tpu:
         # smallest whole tile covering the batch (small rebuild batches
         # shouldn't pad to the full throughput tile), narrowed where a
         # wide state needs it — the host masks are built for that tile
@@ -907,28 +829,18 @@ def _replay_histories(packed, initial, scan_mode: str) -> S.StateTensors:
                 sp.set_tag("bytes", 0 if presence is None
                            else int(presence.nbytes))
     with TRACER.span("replay.launch") as sp:
-        if kernel == "pallas_teb":
+        if tpu:
             final = replay_scan_pallas_teb(
                 state, events, packed.caps,
                 interpret=False, bt=bt, presence=presence,
             )
-        elif kernel == "scan":
-            final = replay_scan_jit(state, events)
-        elif kernel == "assoc":
-            from .assoc import replay_assoc_fm
-
-            # unspecialized: one compile per shape (see the lanes
-            # branch above)
-            final = replay_assoc_fm(state, events)
         else:
-            from .assoc import replay_assoc
-
-            final = replay_assoc(state, events_fm=events)
+            final = replay_scan_jit(state, events)
         if bp > b:
             final = jax.tree_util.tree_map(lambda x: x[:b], final)
         if sp:
             sp.set_tag("events", int(packed.lengths.sum()))
-            if kernel != "pallas_teb":  # the Pallas kernel tags its own
+            if not tpu:  # the Pallas kernel tags its own
                 sp.set_tag("cells", bp * packed.events.shape[1])
     with TRACER.span("replay.fetch") as sp:
         out = jax.tree_util.tree_map(np.asarray, final)
@@ -965,12 +877,3 @@ def to_device(host, span: str = "replay.h2d", parent=None):
             sp.set_tag("bytes", sum(
                 int(x.nbytes) for x in jax.tree_util.tree_leaves(host)))
         return jax.tree_util.tree_map(jnp.asarray, host)
-
-
-# Parallel-in-time entry points (ops/assoc.py): replay_assoc is the
-# chunked hybrid over an unpacked time-major tensor — associative
-# composition over affine runs, short sequential scans at any step the
-# classifier cannot prove affine. Re-exported here because replay.py is
-# the kernel facade the dispatcher and rebuild paths import from.
-from .assoc import replay_assoc  # noqa: E402,F401
-from .assoc import classify_types as assoc_classify_types  # noqa: E402,F401

@@ -60,6 +60,14 @@ from cadence_tpu.utils.tracing import TRACER
 from . import schema as S
 
 
+def on_tpu() -> bool:
+    """Whether the default device is a TPU: the one question that picks
+    every replay path. There the Pallas kernels run compiled; elsewhere
+    the XLA scans run, and a Pallas call is interpreted. Tests steer a
+    TPU branch onto the CPU by patching this function."""
+    return jax.default_backend() == "tpu"
+
+
 @dataclasses.dataclass(frozen=True)
 class RowMap:
     """Static row offsets of each state tensor inside the [R, B] matrix."""
@@ -150,18 +158,17 @@ def rows_to_state(rows, rm: RowMap) -> S.StateTensors:
 
 
 def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
-            tb: int, ablate: int = 0, narrow: bool = False,
-            wide_cols: tuple = ()):
+            tb: int, narrow: bool = False, wide_cols: tuple = ()):
     """One (batch-tile, time-block) grid step.
 
     The batch tile is shaped (SL, 128) with SL a multiple of 8 — whole
     int32 VPU tiles — so every row update runs at full sublane x lane
     utilization (a flat [BT] row would occupy 1 of 8 sublanes). A
     pre-PR-1 v5e run found the kernel bound by streaming the event
-    blocks from HBM, not by the step body (an empty-body ablation,
-    ablate=5, took the same wall time as the full FSM at B=65536), so
-    SL mainly trades VMEM for fewer grid steps; bt=8192 (SL=64) was
-    best there. Neither is re-measured yet (PERF.md).
+    blocks from HBM, not by the step body (an empty step body took the
+    same wall time as the full FSM at B=65536), so SL mainly trades
+    VMEM for fewer grid steps; bt=8192 (SL=64) was best there. Neither
+    is re-measured yet (PERF.md).
 
     presence_ref: [1, TB, W] SMEM — per-step scalar gates for this
              tile: words 0-1 are the event-type bitmask (bit e of word
@@ -215,7 +222,7 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
             # reconstruct as stored16 + base[c]; wide columns as
             # (lo16 & 0xffff) | hi16 << 16 — exact int32 either way.
             # The reconstruction ALU is VPU noise against the stream
-            # the kernel is bound by (module docstring / ablation note)
+            # the kernel is bound by (module docstring / step-body note)
             phys, _ = _phys_map(wide_cols)
 
             def fld(c):
@@ -243,9 +250,6 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
 
         X = rm.exec0
 
-        if ablate >= 5:
-            return carry
-
         def m(*types):
             out = et == int(types[0])
             for t in types[1:]:
@@ -257,9 +261,6 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
         wr(X + S.X_CUR_VERSION, valid, version)
         wr(X + S.X_NEXT_EVENT_ID, valid, ev_id + 1)
         wr(X + S.X_LAST_FIRST_EVENT_ID, valid, batch_first)
-
-        if ablate >= 4:
-            return carry
 
         # ---- version-history AddOrUpdateItem
         cap_v = caps.max_version_items
@@ -282,9 +283,6 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
             wr(rm.vh0 + 2 * i_v, wmask, ev_id)
             wr(rm.vh0 + 2 * i_v + 1, wmask, version)
         wr(rm.vhlen, valid & ~same, vh_len + 1)
-
-        if ablate >= 3:
-            return carry
 
         # ---- workflow lifecycle
         @pl.when(present(E.WorkflowExecutionStarted))
@@ -329,9 +327,6 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
             wr(X + S.X_SIGNAL_COUNT, m_sig, rd(X + S.X_SIGNAL_COUNT) + 1)
 
         # ---- decision sub-FSM
-        if ablate >= 2:
-            return carry
-
         @pl.when(present(E.DecisionTaskScheduled))
         def _():
             m_dsch = m(E.DecisionTaskScheduled)
@@ -392,9 +387,6 @@ def _kernel(presence_ref, base_ref, ev_ref, init_ref, st, *, rm: RowMap,
                 wr(X + col, no_increment, 0)
 
         # ---- slot-table helper: per-slot predicated updates
-        if ablate >= 1:
-            return carry
-
         def for_slots(types, cap, fn):
             """``fn(s, mask)`` for each slot ``s`` < ``cap`` that some
             lane's event touches. One loop body at every capacity: the
@@ -657,8 +649,8 @@ def narrow_events_teb(events_teb, force_wide=()):
     """Narrow an int32 [T, EV_N, B] event tensor to an int16 stream.
 
     The kernel is bound by streaming the event tensor from HBM (the
-    empty-body ablation measures the same wall time as the full FSM —
-    module docstring), so shrinking the stream's bytes is the per-tile
+    empty step body measures the same wall time as the full FSM —
+    ``_kernel``), so shrinking the stream's bytes is the per-tile
     throughput lever. Each column whose value span fits int16 is stored
     affine (``ev - base[c]``, base = column midrange); a wide column
     (hash-valued attributes, raw timestamps) is stored EXACTLY as two
@@ -707,8 +699,7 @@ def narrow_events_teb(events_teb, force_wide=()):
 
 def _replay_rows_pallas(events_teb, rows0, caps: S.Capacities,
                         tb: int, interpret: bool, bt: int = BT,
-                        ablate: int = 0, presence=None, base=None,
-                        wide_cols: tuple = ()):
+                        presence=None, base=None, wide_cols: tuple = ()):
     """Dispatch wrapper: concrete interpret-mode calls (the CPU parity
     path — tests and CPU serving, never the TPU hot path) go through a
     cached AOT lower/compile at XLA opt level 0. Interpret tracing +
@@ -724,12 +715,12 @@ def _replay_rows_pallas(events_teb, rows0, caps: S.Capacities,
                 None if presence is None else jnp.asarray(presence),
                 None if base is None else jnp.asarray(base))
         exe = _interp_rows_exec(
-            caps, tb, bt, ablate, tuple(wide_cols),
+            caps, tb, bt, tuple(wide_cols),
             tuple(_avkey(a) for a in args))
         return exe(*args)
     return _replay_rows_pallas_jit(
-        events_teb, rows0, caps, tb, interpret, bt, ablate, presence,
-        base, tuple(wide_cols))
+        events_teb, rows0, caps, tb, interpret, bt, presence, base,
+        tuple(wide_cols))
 
 
 def _avkey(x):
@@ -737,23 +728,23 @@ def _avkey(x):
 
 
 @functools.lru_cache(maxsize=64)
-def _interp_rows_exec(caps, tb, bt, ablate, wide_cols, avkey):
+def _interp_rows_exec(caps, tb, bt, wide_cols, avkey):
     avals = [
         None if k is None else jax.ShapeDtypeStruct(k[0], k[1])
         for k in avkey
     ]
     low = _replay_rows_pallas_jit.lower(
-        avals[0], avals[1], caps, tb, True, bt, ablate, avals[2],
-        avals[3], wide_cols)
+        avals[0], avals[1], caps, tb, True, bt, avals[2], avals[3],
+        wide_cols)
     return low.compile({"xla_backend_optimization_level": 0})
 
 
 @functools.partial(jax.jit,
                    static_argnames=("caps", "tb", "interpret", "bt",
-                                    "ablate", "wide_cols"))
+                                    "wide_cols"))
 def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
                             tb: int, interpret: bool, bt: int = BT,
-                            ablate: int = 0, presence=None, base=None,
+                            presence=None, base=None,
                             wide_cols: tuple = ()):
     """events_teb: [T, EV_N, B] int32 — or the int16 narrow stream from
     ``narrow_events_teb`` (physical layout, with ``base`` [EV_N] int32
@@ -820,8 +811,8 @@ def _replay_rows_pallas_jit(events_teb, rows0, caps: S.Capacities,
     state_mode = None if buffers == 2 else pl.Buffered(1)
     grid = (n_bt, T // tb)
     out = pl.pallas_call(
-        functools.partial(_kernel, rm=rm, tb=tb, ablate=ablate,
-                          narrow=narrow, wide_cols=wide_cols),
+        functools.partial(_kernel, rm=rm, tb=tb, narrow=narrow,
+                          wide_cols=wide_cols),
         out_shape=jax.ShapeDtypeStruct((R, n_bt, sl, 128), jnp.int32),
         grid=grid,
         in_specs=[
@@ -855,7 +846,6 @@ def replay_scan_pallas_teb(
     tb: int = 16,
     interpret: bool | None = None,
     bt: int = BT,
-    ablate: int = 0,
     presence=None,
     base=None,
     wide_cols: tuple = (),
@@ -875,7 +865,7 @@ def replay_scan_pallas_teb(
     for the kernel to compute.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     events_teb = jnp.asarray(events_teb)
     narrow = events_teb.dtype == jnp.int16
     T, ev_n, B = events_teb.shape
@@ -921,8 +911,7 @@ def replay_scan_pallas_teb(
         )
 
     rows = _replay_rows_pallas(events_teb, rows0, caps, tb, interpret, bt,
-                               ablate, presence, base,
-                               wide_cols=tuple(wide_cols))
+                               presence, base, wide_cols=tuple(wide_cols))
     return rows_to_state(rows[:, :B], rm)
 
 
@@ -973,7 +962,7 @@ def replay_scan_pallas_packed(
     Returns (final_lane_state, out).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     events_teb = jnp.asarray(events_teb)
     narrow = events_teb.dtype == jnp.int16
     if narrow and base is None:
@@ -1119,7 +1108,6 @@ def replay_scan_pallas(
     tb: int = 16,
     interpret: bool | None = None,
     bt: int = BT,
-    ablate: int = 0,
 ) -> S.StateTensors:
     """Drop-in equivalent of ops.replay.replay_scan on the Pallas kernel.
 
@@ -1131,98 +1119,4 @@ def replay_scan_pallas(
     events_teb = jnp.transpose(jnp.asarray(events_tm), (0, 2, 1))
     return replay_scan_pallas_teb(
         state, events_teb, caps, tb=tb, interpret=interpret, bt=bt,
-        ablate=ablate,
     )
-
-
-# --------------------------------------------------------------------------
-# Blocked associative combine for the parallel-in-time replay
-# (ops/assoc.py). Composes per-step affine updates (mul, add) into
-# inclusive segmented prefixes: each grid step holds one tb-long time
-# block VMEM-resident, walks it sequentially on-chip, and carries the
-# running composition across blocks in scratch — the O(T) HBM traffic
-# of the composition stream is paid exactly once, block by block,
-# instead of lax.associative_scan's strided multi-level passes.
-# --------------------------------------------------------------------------
-
-
-def _affine_scan_kernel(mul_ref, add_ref, rst_ref, om_ref, oa_ref,
-                        mc, ac, *, tb: int):
-    """One (lane tile, time block): mul/add [TB, C, BL], rst [TB, 1, BL];
-    scratch carries the running (mul, add) composition [C, BL] across
-    the time blocks of a lane tile. Lanes are the minor (vector-lane)
-    dimension and the reset row broadcasts over the C sublanes — Mosaic
-    has no cheap [L] -> [L, 1] relayout, so the kernel never reshapes."""
-
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        mc[...] = jnp.ones(mc.shape, jnp.int32)
-        ac[...] = jnp.zeros(ac.shape, jnp.int32)
-
-    def step(i, carry):
-        m = mul_ref[i]
-        a = add_ref[i]
-        rb = rst_ref[i] != 0
-        # segment starts absorb the carry (the segmented combine)
-        pm = jnp.where(rb, m, mc[...] * m)
-        pa = jnp.where(rb, a, ac[...] * m + a)
-        mc[...] = pm
-        ac[...] = pa
-        om_ref[i] = pm
-        oa_ref[i] = pa
-        return carry
-
-    lax.fori_loop(0, tb, step, 0)
-
-
-def _affine_lane_tile(L: int, C: int, tb: int) -> int:
-    """Widest lane tile (multiple of 128) dividing L whose double-
-    buffered blocks (4 of [tb, C, bl] plus the [tb, 1, bl] reset, each
-    padded to 8 sublanes) fit 12 MiB of the 16 MiB default scoped VMEM."""
-    rows = 4 * (-(-C // 8) * 8) + 8
-    for bl in (1024, 512, 256, 128):
-        if L % bl == 0 and 2 * tb * rows * bl * 4 <= 12 << 20:
-            return bl
-    return L
-
-
-def affine_segscan_pallas(mul, add, rst, tb: int = 8,
-                          interpret: bool | None = None):
-    """Segmented inclusive prefix composition of affine updates.
-
-    mul/add: [T, L, C] int32; rst: [T, L] (nonzero = step begins a new
-    segment). Returns (mul, add) prefixes — bit-identical to
-    ops.assoc.affine_segscan over the same stream
-    (tests/test_replay_pallas.py). ``T`` must be a multiple of ``tb``.
-    The kernel runs lane-dense on [T, C, L] (the transposes fuse with
-    the caller's own layout change in ops/assoc.py).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    T, L, C = mul.shape
-    if T % tb:
-        raise ValueError(f"T={T} not a multiple of tb={tb}")
-    bl = _affine_lane_tile(L, C, tb)
-    mul_t = jnp.transpose(jnp.asarray(mul, jnp.int32), (0, 2, 1))
-    add_t = jnp.transpose(jnp.asarray(add, jnp.int32), (0, 2, 1))
-    rst_t = jnp.asarray(rst, jnp.int32)[:, None, :]
-    blk = pl.BlockSpec((tb, C, bl), lambda l, t: (t, 0, l))
-    om, oa = pl.pallas_call(
-        functools.partial(_affine_scan_kernel, tb=tb),
-        grid=(L // bl, T // tb),
-        in_specs=[blk, blk,
-                  pl.BlockSpec((tb, 1, bl), lambda l, t: (t, 0, l))],
-        out_specs=[blk, blk],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, C, L), jnp.int32),
-            jax.ShapeDtypeStruct((T, C, L), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((C, bl), jnp.int32),
-            pltpu.VMEM((C, bl), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(mul_t, add_t, rst_t)
-    return (jnp.transpose(om, (0, 2, 1)), jnp.transpose(oa, (0, 2, 1)))

@@ -264,51 +264,6 @@ def rebase_state_row(row: Dict[str, Any], delta_s: int) -> Dict[str, Any]:
     return out
 
 
-# --------------------------------------------------------------------------
-# Per-column update algebra — the metadata the parallel-in-time replay
-# (ops/assoc.py) and the ASSOC-UNPROVEN static-analysis rule share.
-#
-# Every kernel write to a state cell must compose associatively for the
-# segmented-scan replay to be sound. Four algebras cover the transition
-# surface:
-#
-#   set      x -> v            the mul=0 affine case (last-writer-wins;
-#                              provenance resolution). The default.
-#   counter  x -> x + d        the mul=1 affine case (prefix sums).
-#   fsm      x -> f(x)         bounded function table closed under
-#                              composition (X_STATE's Created->Running
-#                              promotion; {identity, promote, const}).
-#   rle      run-length        the version-history add_or_update: append
-#                              on version change, recovered from a
-#                              segmented prefix count of change flags.
-#
-# A column NOT listed here is "set". A new kernel transition that reads
-# prior state in any other shape (cross-column arithmetic, data-
-# dependent control) has no declared algebra — the analysis gate then
-# reports ASSOC-UNPROVEN and the runtime classifier routes the type to
-# the sequential fallback.
-# --------------------------------------------------------------------------
-
-UPDATE_ALGEBRA = {
-    "exec:X_STATE": "fsm",
-    "exec:X_SIGNAL_COUNT": "counter",
-    "exec:X_DEC_ATTEMPT": "counter",
-    "vh:event_id": "rle",
-    "vh:version": "rle",
-    "vh:len": "rle",
-}
-
-DEFAULT_ALGEBRA = "set"
-
-ALGEBRAS = ("set", "counter", "fsm", "rle")
-
-
-def update_algebra(label: str) -> str:
-    """Composition algebra of one state-cell label (``exec:X_*``,
-    ``vh:*``, or a slot-table label like ``activities:AC_*``)."""
-    return UPDATE_ALGEBRA.get(label, DEFAULT_ALGEBRA)
-
-
 # (prefix, count constant) per column table — the reflection surface
 # shared with cadence_tpu/analysis/transition_surface.py
 _COLUMN_GROUPS = (
@@ -362,17 +317,6 @@ def validate(ns: Dict[str, Any] = None) -> None:
                     f"ROW_TS_COLS[{field!r}] column {c} outside its table "
                     f"(N={counts[field]})"
                 )
-    for label, algebra in ns["UPDATE_ALGEBRA"].items():
-        if algebra not in ns["ALGEBRAS"]:
-            raise AssertionError(
-                f"UPDATE_ALGEBRA[{label!r}] = {algebra!r} is not one of "
-                f"{ns['ALGEBRAS']}"
-            )
-        kind, _, col = label.partition(":")
-        if kind == "exec" and col not in ns:
-            raise AssertionError(
-                f"UPDATE_ALGEBRA names unknown exec column {col!r}"
-            )
 
 
 validate()

@@ -37,38 +37,15 @@ def _state_specs(sharding: NamedSharding) -> S.StateTensors:
 
 
 @functools.lru_cache(maxsize=8)
-def replay_sharded_fn(mesh: Mesh, scan_mode: str = "scan"):
-    """jit(replay+refresh) with batch-axis shardings over ``mesh``.
-
-    ``scan_mode="scan"`` consumes time-major [T, B, EV_N] events through
-    the sequential scan; ``"assoc"`` consumes field-major [EV_N, B, T]
-    events through the parallel-in-time associative kernel
-    (cadence_tpu/ops/assoc.py), wrapped in ``shard_map`` so the
-    per-history provenance reductions stay shard-local — the assoc path
-    is elementwise over B like the scan, so batch sharding adds zero
-    collectives either way.
+def replay_sharded_fn(mesh: Mesh):
+    """jit(replay+refresh) with batch-axis shardings over ``mesh``: the
+    sequential scan over time-major [T, B, EV_N] events, elementwise
+    over B, so batch sharding adds zero collectives.
 
     Returns fn(state, events) -> (final_state, refreshed_tasks); both
     outputs stay sharded on device.
     """
     st_spec = shard_spec(mesh)
-
-    if scan_mode == "assoc":
-        from cadence_tpu.ops.assoc import _assoc_core
-
-        def step_local(state: S.StateTensors, events_fm: jnp.ndarray):
-            final = _assoc_core(events_fm, state)
-            return final, refresh_tasks_device(final)
-
-        sharded = jax.shard_map(
-            step_local,
-            mesh=mesh,
-            in_specs=(P(SHARD_AXIS), P(None, SHARD_AXIS, None)),
-            out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-            check_vma=False,
-        )
-        return jax.jit(sharded, donate_argnums=(0,))
-
     ev_spec = events_spec(mesh)
 
     def step(state: S.StateTensors, events_tm: jnp.ndarray):
@@ -89,19 +66,12 @@ def replay_packed_sharded(
     packed: PackedHistories,
     mesh: Mesh,
     initial: Optional[S.StateTensors] = None,
-    scan_mode: str = "scan",
 ) -> Tuple[S.StateTensors, RefreshedTasks]:
     """Replay a packed batch across the mesh; returns numpy pytrees.
 
     The batch must be padded to a multiple of the shard-axis size
-    (``pack_histories(pad_batch_to=...)``). ``scan_mode="assoc"`` rides
-    the parallel-in-time kernel (O(log T) depth per shard) —
-    bit-identical to the scan (tests/test_parallel.py).
+    (``pack_histories(pad_batch_to=...)``).
     """
-    from cadence_tpu.ops.replay import check_scan_mode
-
-    # no "auto" here: the sharded facade is an explicit two-kernel API
-    check_scan_mode(scan_mode, allowed=("scan", "assoc"))
     n_shard = mesh.shape[SHARD_AXIS]
     if packed.batch % n_shard != 0:
         raise ValueError(
@@ -109,22 +79,14 @@ def replay_packed_sharded(
             "pack with pad_batch_to"
         )
     state = initial if initial is not None else S.empty_state(packed.batch, packed.caps)
-    if scan_mode == "assoc":
-        from cadence_tpu.ops.assoc import events_fm_of
-
-        ev = events_fm_of(packed.events)
-        ev_sharding = NamedSharding(mesh, P(None, SHARD_AXIS, None))
-    else:
-        ev = packed.time_major()
-        ev_sharding = events_spec(mesh)
-    fn = replay_sharded_fn(mesh, scan_mode)
+    fn = replay_sharded_fn(mesh)
     final, tasks = fn(
         jax.device_put(state, shard_spec(mesh))
         if initial is not None
         else jax.tree_util.tree_map(
             lambda x: jax.device_put(jnp.asarray(x), shard_spec(mesh)), state
         ),
-        jax.device_put(jnp.asarray(ev), ev_sharding),
+        jax.device_put(jnp.asarray(packed.time_major()), events_spec(mesh)),
     )
     to_np = lambda t: jax.tree_util.tree_map(np.asarray, t)
     return to_np(final), to_np(tasks)

@@ -138,10 +138,9 @@ class StateRebuilder:
         # as large as the chip comfortably holds (32k rows on TPU). CPU
         # test meshes keep the small chunk (compile time scales with B
         # there). A backend that fails to initialize raises here.
-        import jax
+        from cadence_tpu.ops.replay_pallas import on_tpu
 
-        backend = jax.default_backend()
-        self._backend_chunk = 32768 if backend == "tpu" else 4096
+        self._backend_chunk = 32768 if on_tpu() else 4096
         return self._backend_chunk
 
     # -- history paging ------------------------------------------------

@@ -13,12 +13,9 @@ O(Δ) suffix composition:
   ResumeState seam) or cold-replaying the prefix through the existing
   double-buffered dispatcher (``ops.dispatch.replay_stream``);
 * ``append()`` stages just the Δ suffix against the workflow's lane;
-* ``tick()`` runs ONE fused device step composing every pending suffix
-  against its lane via the associative affine update algebra
-  (``ops/assoc.py`` / ``schema.UPDATE_ALGEBRA``) — lanes whose Δ
-  carries a type the classifier cannot prove affine fall back to the
-  sequential packed scan in the same tick (a second, sequential-kernel
-  batch), exactly the hybrid discipline of ``replay_assoc``;
+* ``tick()`` runs ONE fused device step replaying every pending suffix
+  from its lane's row as one lane-packed batch
+  (``ops.replay.replay_packed_lanes``);
 * ``read()`` answers decision/query requests straight from the
   resident row — no replay, no history read;
 * eviction (LRU-idle + on-close) flushes a lane's row back through
@@ -30,8 +27,7 @@ Correctness invariants (tests/test_serving.py):
 
 * **differential**: resident state after K appends is byte-identical
   to a cold ``rebuild_many``/``replay_packed`` of the full history —
-  for affine-only Δs, hybrid non-affine Δs, recycle-then-readmit, and
-  checkpoint-resume seeding;
+  for appended Δs, recycle-then-readmit, and checkpoint-resume seeding;
 * **generation stamp**: every lane slot carries a generation bumped on
   recycle; a stale in-flight append (ticket from a previous tenancy)
   can never land on a recycled slot;
@@ -179,7 +175,6 @@ class ResidentEngine:
         history=None,
         metrics: Optional[Scope] = None,
         idle_ticks: int = 256,
-        affine_types: Optional[frozenset] = None,
         admission: Optional[AdmissionPolicy] = None,
         tick_interval_s: float = 0.0,
     ) -> None:
@@ -196,10 +191,6 @@ class ResidentEngine:
         # persistence HistoryManager for admit_from_store / read-through
         self.history = history
         self.idle_ticks = int(idle_ticks)
-        # test seam mirroring replay_assoc(affine_types=...): may only
-        # SHRINK the proven-affine set (forces lanes onto the
-        # sequential fallback), never grow it
-        self._affine_types = affine_types
         self._metrics = (
             metrics if metrics is not None else NOOP
         ).tagged(layer="serving")
@@ -408,7 +399,7 @@ class ResidentEngine:
                     packed = pack_lanes(
                         [hist], caps=self.caps, resume=[rs]
                     )
-                    final = self._replay(packed, scan_mode="auto")
+                    final = self._replay(packed)
                     rows.append((packed, final, 0))
                 except Exception:
                     rows.append(None)
@@ -653,9 +644,7 @@ class ResidentEngine:
 
     def tick(self) -> Dict:
         """One serving tick: ONE fused device step composes every
-        pending Δ against its lane (affine Δs through the assoc
-        algebra, non-affine Δs through the sequential packed scan),
-        then eviction/recycle and admission refill. Returns tick
+        pending Δ against its lane, then eviction/recycle and admission refill. Returns tick
         stats. Ticks SERIALIZE (``_tick_lock``): concurrent callers
         (every dirty read composes-first) queue behind the running
         tick instead of racing its base-row snapshots; Δs staged while
@@ -715,97 +704,83 @@ class ResidentEngine:
             "recycled": recycled, "tick_seconds": dt,
         }
 
-    def _delta_types(self, batches) -> frozenset:
-        return frozenset(
-            int(e.event_type) for b in batches for e in b
-        )
-
-    def _replay(self, packed, scan_mode: str):
+    def _replay(self, packed):
         from cadence_tpu.ops.replay import replay_packed_lanes
 
-        return replay_packed_lanes(packed, scan_mode=scan_mode)
+        # unspecialized: the event types of a tick's Δs change from tick
+        # to tick, and each new type signature would compile the scan
+        # again, a stall the next reads wait out; one executable a shape
+        return replay_packed_lanes(packed, specialize=False)
 
     def _compose(self, work) -> Tuple[int, int, int, int]:
-        """The fused step: split pending lanes into the affine group
-        (assoc algebra) and the sequential-fallback group, pack + run
-        each as one device batch, commit rows under the lock."""
-        from cadence_tpu.ops.assoc import classify_types
-
+        """The fused step: pack every pending lane's Δ, run them as one
+        device batch, commit rows under the lock."""
         if not work:
             return 0, 0, 0, 0
-        groups: Dict[str, List] = {"auto": [], "scan": []}
-        for item in work:
-            _, non = classify_types(
-                self._delta_types(item[3]), self._affine_types
-            )
-            groups["scan" if non else "auto"].append(item)
         composed = replayed = failures = stale = 0
         staleness_ms: List[float] = []
-        for mode, items in groups.items():
-            if not items:
-                continue
-            histories = [
-                (lane.workflow_id, lane.run_id, batches)
-                for _, _, lane, batches, _ in items
-            ]
-            resumes = [rs for *_, rs in items]
-            results: List[Optional[Tuple]] = []
-            try:
-                packed = pack_lanes(
-                    histories, caps=self.caps, resume=resumes
-                )
-                final = self._replay(packed, scan_mode=mode)
-                results = [(packed, final, j) for j in range(len(items))]
-            except Exception:
-                # one malformed Δ must not poison the whole tick:
-                # degrade to per-lane composition, fail only the bad one
-                for hist, rs in zip(histories, resumes):
-                    try:
-                        pk = pack_lanes(
-                            [hist], caps=self.caps, resume=[rs]
-                        )
-                        results.append(
-                            (pk, self._replay(pk, scan_mode=mode), 0)
-                        )
-                    except Exception:
-                        results.append(None)
-            with self._lock:
-                for (slot, gen, lane, batches, _), row in zip(
-                    items, results
-                ):
-                    if row is None:
-                        # the Δ is unreplayable: free the lane; the
-                        # history store remains the source of truth and
-                        # a readmit-from-store recovers the workflow.
-                        # Generation-checked like the commit branch — a
-                        # slot recycled + re-seated mid-step must not
-                        # be clobbered (its tenant's _by_key entry
-                        # would dangle onto the next occupant)
-                        failures += 1
-                        if (self._slot_gen[slot] == gen
-                                and self._slots[slot] is lane):
-                            self._release_slot(slot, lane.key)
-                        continue
-                    if (self._slot_gen[slot] != gen
-                            or self._slots[slot] is not lane):
-                        stale += 1  # recycled mid-step: never lands
-                        continue
-                    packed, final, j = row
-                    self._commit_row(slot, lane, packed, final, j)
-                    composed += 1
-                    replayed += sum(len(b) for b in batches)
-                    if lane.dirty_since:
-                        # staleness: first-dirty → composed. Reset to
-                        # "now" (not 0) when Δs staged mid-compose —
-                        # their clock started while this step ran
-                        now = _time.monotonic()
-                        staleness_ms.append(
-                            (now - lane.dirty_since) * 1e3
-                        )
-                        lane.dirty_since = now if (
-                            lane.pending
-                            or lane.behind_through > lane.next_staged
-                        ) else 0.0
+        histories = [
+            (lane.workflow_id, lane.run_id, batches)
+            for _, _, lane, batches, _ in work
+        ]
+        resumes = [rs for *_, rs in work]
+        results: List[Optional[Tuple]] = []
+        try:
+            packed = pack_lanes(
+                histories, caps=self.caps, resume=resumes
+            )
+            final = self._replay(packed)
+            results = [(packed, final, j) for j in range(len(work))]
+        except Exception:
+            # one malformed Δ must not poison the whole tick:
+            # degrade to per-lane composition, fail only the bad one
+            for hist, rs in zip(histories, resumes):
+                try:
+                    pk = pack_lanes(
+                        [hist], caps=self.caps, resume=[rs]
+                    )
+                    results.append(
+                        (pk, self._replay(pk), 0)
+                    )
+                except Exception:
+                    results.append(None)
+        with self._lock:
+            for (slot, gen, lane, batches, _), row in zip(
+                work, results
+            ):
+                if row is None:
+                    # the Δ is unreplayable: free the lane; the
+                    # history store remains the source of truth and
+                    # a readmit-from-store recovers the workflow.
+                    # Generation-checked like the commit branch — a
+                    # slot recycled + re-seated mid-step must not
+                    # be clobbered (its tenant's _by_key entry
+                    # would dangle onto the next occupant)
+                    failures += 1
+                    if (self._slot_gen[slot] == gen
+                            and self._slots[slot] is lane):
+                        self._release_slot(slot, lane.key)
+                    continue
+                if (self._slot_gen[slot] != gen
+                        or self._slots[slot] is not lane):
+                    stale += 1  # recycled mid-step: never lands
+                    continue
+                packed, final, j = row
+                self._commit_row(slot, lane, packed, final, j)
+                composed += 1
+                replayed += sum(len(b) for b in batches)
+                if lane.dirty_since:
+                    # staleness: first-dirty → composed. Reset to
+                    # "now" (not 0) when Δs staged mid-compose —
+                    # their clock started while this step ran
+                    now = _time.monotonic()
+                    staleness_ms.append(
+                        (now - lane.dirty_since) * 1e3
+                    )
+                    lane.dirty_since = now if (
+                        lane.pending
+                        or lane.behind_through > lane.next_staged
+                    ) else 0.0
         for ms in staleness_ms:
             self._metrics.record("serving_staleness_ms", ms)
         return composed, replayed, failures, stale
@@ -973,7 +948,7 @@ class ResidentEngine:
             packed = pack_lanes(
                 [(workflow_id, run_id, batches)], caps=self.caps
             )
-            final = self._replay(packed, scan_mode="auto")
+            final = self._replay(packed)
         except Exception as e:
             self._log.warn(f"serving cold read failed ({e}); miss")
             self._metrics.inc("serving_cold_read_failures")

@@ -219,7 +219,7 @@ FAILOVER_METRICS = (
 
 # device-dispatch telemetry (ops/dispatch.py), emitted by the
 # dispatcher per staged/replayed batch under tags (layer=device,
-# kernel=xla|pallas, mode=hist|lanes|hist_assoc|lanes_assoc). Nothing
+# kernel=xla|pallas, mode=hist|lanes). Nothing
 # here waits for the device: kernel time comes from a device profile,
 # where the dispatcher's dispatch.launch span (utils/tracing.py) sits on
 # the same clock.

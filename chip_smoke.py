@@ -617,7 +617,7 @@ def phase_four_chip(z, seed, clock, on_tpu):
     if set(mesh.devices.flat) != set(devices):
         raise SystemExit("the mesh does not span jax.devices()")
     t0 = time.perf_counter()
-    got, _ = replay_packed_sharded(packed, mesh, scan_mode="scan")
+    got, _ = replay_packed_sharded(packed, mesh)
     sharded_s = time.perf_counter() - t0
     bad_sharded = _rows_mismatched(got, want)
 
@@ -626,7 +626,7 @@ def phase_four_chip(z, seed, clock, on_tpu):
     state0 = jax.device_put(
         jax.tree_util.tree_map(np.asarray, S.empty_state(B, caps)),
         shard_spec(mesh))
-    final = replay_sharded_fn(mesh, "scan")(
+    final = replay_sharded_fn(mesh)(
         state0, jax.device_put(packed.time_major(), events_spec(mesh)))[0]
     _spans(final.exec_info, devices, "sharded replay output")
     t0 = time.perf_counter()

@@ -57,3 +57,33 @@ def pytest_collection_modifyitems(config, items):
 @pytest.fixture(scope="session")
 def jax_devices():
     return jax.devices()
+
+
+@pytest.fixture
+def tpu_branch_on_cpu(monkeypatch):
+    """Steer the replay paths' TPU branches onto the CPU: the kernel
+    choice (``replay_pallas.on_tpu``) answers TPU, and the Pallas
+    kernels, which compile only for a TPU, run interpreted, the
+    dispatcher's at its smallest tiles (1,024 lanes, 8 steps). Callers
+    reach the kernels through the module at call time, so the patches
+    hold for the test's duration."""
+    import functools
+
+    from cadence_tpu.ops import replay_pallas
+    from cadence_tpu.ops.dispatch import DeviceDispatcher
+
+    init = DeviceDispatcher.__init__
+
+    def small_tiles(self, *a, **k):
+        init(self, *a, **dict(k, bt=1024, tb=8))
+
+    monkeypatch.setattr(DeviceDispatcher, "__init__", small_tiles)
+    monkeypatch.setattr(replay_pallas, "on_tpu", lambda: True)
+    for name in ("replay_scan_pallas_teb", "replay_scan_pallas_packed"):
+        kernel = getattr(replay_pallas, name)
+
+        @functools.wraps(kernel)
+        def interpreted(*a, _kernel=kernel, **kw):
+            return _kernel(*a, **dict(kw, interpret=True))
+
+        monkeypatch.setattr(replay_pallas, name, interpreted)

@@ -2250,12 +2250,9 @@ class TestOverloadChaos:
 
         Determinism discipline: the workload is built from FIXED-SHAPE
         chunks (2 signals + one decision cycle = 5 events, constant
-        type set) and every compose is pinned to the sequential
-        fallback, so the executable set is exactly {k chunks → one
+        type set), so the executable set is exactly {k chunks → one
         span-width grid bucket} — the warm phase compiles ALL of them
-        up front and jit time can never masquerade as staleness (the
-        hybrid auto split is proven byte-identical in
-        tests/test_serving.py; this member measures the pump)."""
+        up front and jit time can never masquerade as staleness."""
         import numpy as np
 
         from cadence_tpu.core import history_factory as F
@@ -2323,9 +2320,7 @@ class TestOverloadChaos:
         # touch — the seat shape, and one compose per chunk-span width
         # (a fault-stalled catch-up composes up to ALL CHUNKS chunks in
         # one step, so every k is reachable)
-        warm_engine = ResidentEngine(
-            lanes=2, caps=caps, affine_types=frozenset(),
-        )
+        warm_engine = ResidentEngine(lanes=2, caps=caps)
         for k in range(1, CHUNKS + 1):
             t = warm_engine.admit(
                 "dom", f"warm-wf-{k}", f"warm-run-{k}", batches=prefix
@@ -2349,7 +2344,7 @@ class TestOverloadChaos:
             scope = Scope()
             engine = ResidentEngine(
                 lanes=4, caps=caps, history=bundle.history,
-                metrics=scope, affine_types=frozenset(),
+                metrics=scope,
             )
             sched.disarm()  # clean seeding; the storm hits the pump
             seeded = []

@@ -5,6 +5,7 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from cadence_tpu.ops import schema as S
 from cadence_tpu.ops.dispatch import (
@@ -32,10 +33,22 @@ def _oneshot(histories):
     state0 = jax.tree_util.tree_map(
         jnp.asarray, S.empty_state(packed.batch, CAPS)
     )
-    return packed, replay_scan(state0, jnp.asarray(packed.time_major()))
+    # not unrolled, whichever branch a test steers the kernels onto:
+    # this is the reference, run on the CPU
+    return packed, replay_scan(
+        state0, jnp.asarray(packed.time_major()), unroll=1)
 
 
-def test_pipelined_stream_matches_oneshot():
+@pytest.fixture(params=["xla", "pallas"])
+def kernel(request):
+    """The dispatcher's kernels on the CPU: the XLA scans, or the Pallas
+    kernels (the TPU branch) in interpret mode at small tiles."""
+    if request.param == "pallas":
+        request.getfixturevalue("tpu_branch_on_cpu")
+    return request.param
+
+
+def test_pipelined_stream_matches_oneshot(kernel):
     hs = _histories(24)
     got = replay_stream(hs, caps=CAPS, batch_size=8, depth=2)
     assert len(got) == 3
@@ -83,9 +96,6 @@ def test_strict_results_raise():
         assert e.batch_id == "boom"
 
 
-import pytest
-
-
 def _oneshot_snapshot(history):
     from cadence_tpu.ops.unpack import state_row_to_snapshot
 
@@ -126,7 +136,7 @@ def test_bucketed_lane_packed_stream_preserves_identity_and_order():
         assert seen[i] == _oneshot_snapshot(h), f"history {i} diverged"
 
 
-def test_lane_packed_dispatcher_matches_oneshot():
+def test_lane_packed_dispatcher_matches_oneshot(kernel):
     d = DeviceDispatcher(caps=CAPS, lane_pack=True, lane_len=128)
     hs = _histories(10, seed=21)
     d.submit("b0", hs)
@@ -188,13 +198,13 @@ def test_depth_buckets_geometric_grouping():
 
 
 @pytest.mark.slow
-def test_pallas_narrow_serving_path_interpret():
+def test_pallas_narrow_serving_path_interpret(tpu_branch_on_cpu):
     """The dispatcher's pallas+narrow serving path end-to-end on CPU
     (interpret mode): pack → narrow int16 → kernel → state parity with
     the XLA oneshot. On hardware this is the production storm-drain
     configuration; interpret mode proves the wiring and semantics."""
     hs = _histories(6, seed=9)
-    d = DeviceDispatcher(caps=CAPS, kernel="pallas", bt=1024, tb=8)
+    d = DeviceDispatcher(caps=CAPS)
     d.submit(0, hs)
     d.finish()
     out = list(d.results())
@@ -209,49 +219,3 @@ def test_pallas_narrow_serving_path_interpret():
         jax.tree_util.tree_leaves(want),
     ):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_scan_mode_auto_matches_forced_scan():
-    """The dispatcher's default (assoc for unpacked XLA batches) must be
-    byte-identical to scan_mode="scan" — the same batches through both
-    kernels."""
-    hs = _histories(8, seed=9)
-    got_auto = replay_stream(hs, caps=CAPS, batch_size=8)
-    got_scan = replay_stream(hs, caps=CAPS, batch_size=8,
-                             scan_mode="scan")
-    for (pa, fa), (ps, fs) in zip(got_auto, got_scan):
-        for a, b in zip(
-            jax.tree_util.tree_leaves(fa), jax.tree_util.tree_leaves(fs)
-        ):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_scan_mode_assoc_lane_packed_matches_scan():
-    """scan_mode="assoc" on the lane-packed pipeline: segment resets and
-    per-history output rows through the associative path."""
-    hs = _histories(10, seed=10)
-    got_a = replay_stream(hs, caps=CAPS, batch_size=10, lane_pack=True,
-                          scan_mode="assoc")
-    got_s = replay_stream(hs, caps=CAPS, batch_size=10, lane_pack=True,
-                          scan_mode="scan")
-    assert len(got_a) == len(got_s) == 1
-    (pa, fa), (ps, fs) = got_a[0], got_s[0]
-    for a, b in zip(
-        jax.tree_util.tree_leaves(fa), jax.tree_util.tree_leaves(fs)
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_scan_mode_validated():
-    """Unknown scan_mode strings must raise up front — the kernel
-    selectors read the string in different places, so a typo would
-    otherwise silently pick a kernel."""
-    import pytest
-
-    from cadence_tpu.ops.replay import replay_packed
-
-    with pytest.raises(ValueError, match="scan_mode"):
-        DeviceDispatcher(caps=CAPS, scan_mode="asoc")
-    with pytest.raises(ValueError, match="scan_mode"):
-        replay_packed(pack_histories(_histories(2), caps=CAPS),
-                      scan_mode="Scan")

@@ -7,7 +7,6 @@ legal history, and the device kernel must agree with the host oracle on
 all of them.
 """
 
-import pytest
 
 from cadence_tpu.core.task_refresher import refresh_tasks
 from cadence_tpu.ops.pack import pack_histories
@@ -157,286 +156,3 @@ def test_fuzzer_event_ids_contiguous():
     batches = HistoryFuzzer(seed=3, caps=CAPS).generate(target_events=60)
     flat = [e for batch in batches for e in batch]
     assert [e.event_id for e in flat] == list(range(1, len(flat) + 1))
-
-
-def _state_fields_equal(a, b):
-    import numpy as np
-
-    from cadence_tpu.ops.schema import STATE_ROW_FIELDS
-
-    for f in STATE_ROW_FIELDS:
-        if not np.array_equal(np.asarray(getattr(a, f)),
-                              np.asarray(getattr(b, f))):
-            return f
-    return None
-
-
-def test_fuzz_assoc_three_way_parity():
-    """assoc(resolve) == assoc(segscan) == sequential scan == oracle on
-    fuzzed unpacked batches — the parallel-in-time decomposition must be
-    byte-identical to the scan it replaces, for BOTH evaluation
-    strategies of the affine composition (ops/assoc.py)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from cadence_tpu.ops import assoc
-    from cadence_tpu.ops import schema as S
-    from cadence_tpu.ops.replay import replay_scan_jit, type_signature
-
-    n = 12
-    histories = []
-    for seed in range(n):
-        fz = HistoryFuzzer(seed=1000 + seed, caps=CAPS)
-        histories.append((
-            f"wf-{seed}", f"run-{seed}",
-            fz.generate(target_events=30 + (seed % 5) * 30,
-                        close=seed % 3 != 0),
-        ))
-    packed = pack_histories(histories, caps=CAPS)
-    types = type_signature(packed.events[:, :, S.EV_TYPE][
-        packed.events[:, :, S.EV_TYPE] >= 0])
-    seq = jax.tree_util.tree_map(np.asarray, replay_scan_jit(
-        jax.tree_util.tree_map(
-            jnp.asarray, S.empty_state(packed.batch, CAPS)),
-        jnp.asarray(packed.time_major()), types=types,
-    ))
-    evf = assoc.events_fm_of(packed.events)
-    for impl in ("resolve", "segscan"):
-        got = assoc.replay_assoc_fm(
-            S.empty_state(packed.batch, CAPS), evf, types=types,
-            impl=impl)
-        bad = _state_fields_equal(got, seq)
-        assert bad is None, f"assoc[{impl}] != scan in field {bad}"
-
-    # ...and the scan_mode="assoc" facade agrees with the host oracle
-    # at snapshot level (the bar every kernel path must clear)
-    from cadence_tpu.ops.replay import replay_packed
-
-    final = replay_packed(packed, scan_mode="assoc")
-    for i, (wf, run, batches) in enumerate(histories):
-        ms = oracle_replay(batches, workflow_id=wf, run_id=run)
-        assert state_row_to_snapshot(final, i, packed.epoch_s) == \
-            mutable_state_to_snapshot(ms), f"seed {i} diverged vs oracle"
-
-
-def test_fuzz_assoc_lane_packed_resume_parity():
-    """Lane-packed + checkpoint-resumed batches through the associative
-    path: segment boundaries reset composition (the packer's segment
-    table) and resumed init rows are the leading segment element — both
-    byte-identical to the sequential packed scan, for both impls,
-    including a zero-suffix (checkpoint at tip) segment."""
-    from cadence_tpu.checkpoint import checkpoint_from_replay
-    from cadence_tpu.ops import assoc
-    from cadence_tpu.ops.pack import pack_lanes
-    from cadence_tpu.ops.replay import replay_packed
-    from cadence_tpu.runtime.persistence.records import BranchToken
-
-    n = 6
-    histories = []
-    for seed in range(n):
-        fz = HistoryFuzzer(seed=2000 + seed, caps=CAPS)
-        histories.append((
-            f"wf-{seed}", f"run-{seed}",
-            fz.generate(target_events=24 + (seed % 4) * 24,
-                        close=seed % 3 == 0),
-        ))
-
-    # plain lane-packed
-    lanes = pack_lanes(histories, caps=CAPS, target_lane_len=128)
-    want = replay_packed(lanes, scan_mode="scan")
-    for impl in ("resolve", "segscan"):
-        got = assoc.replay_assoc_lanes(lanes, impl=impl)
-        bad = _state_fields_equal(got, want)
-        assert bad is None, f"lanes assoc[{impl}] != scan in field {bad}"
-
-    # checkpoint-resumed suffix packing
-    resume, suffixes = [], []
-    for i, (wf, run, batches) in enumerate(histories):
-        cut = len(batches) if i == n - 1 else max(
-            1, (len(batches) * (1 + i % 3)) // 4)
-        pk = pack_histories([(wf, run, batches[:cut])], caps=CAPS)
-        pre = replay_packed(pk, scan_mode="scan")
-        ck = checkpoint_from_replay(
-            BranchToken(tree_id=run, branch_id="b").to_json().encode(),
-            pre, 0, pk.side[0], pk.epoch_s, CAPS,
-        )
-        resume.append(ck.resume_state())
-        suffixes.append((wf, run, batches[cut:]))
-    lanes_r = pack_lanes(
-        suffixes, caps=CAPS, target_lane_len=128, resume=resume)
-    want_r = replay_packed(lanes_r, scan_mode="scan")
-    for impl in ("resolve", "segscan"):
-        got_r = assoc.replay_assoc_lanes(lanes_r, impl=impl)
-        bad = _state_fields_equal(got_r, want_r)
-        assert bad is None, \
-            f"resumed assoc[{impl}] != scan in field {bad}"
-
-
-def test_assoc_hybrid_nonaffine_fallback():
-    """The chunked hybrid seam: with timer transitions artificially
-    declared nonaffine, replay_assoc must split the time axis at those
-    steps (sequential single-step scans between associative runs) and
-    still be byte-identical to the sequential scan."""
-    from cadence_tpu.ops import assoc
-    from cadence_tpu.ops import schema as S
-    from cadence_tpu.ops.replay import replay_packed
-
-    histories = []
-    for seed in range(4):
-        fz = HistoryFuzzer(seed=3000 + seed, caps=CAPS)
-        histories.append((
-            f"wf-{seed}", f"run-{seed}",
-            fz.generate(target_events=48, close=seed % 2 == 0),
-        ))
-    packed = pack_histories(histories, caps=CAPS)
-    want = replay_packed(packed, scan_mode="scan")
-
-    from cadence_tpu.core.enums import EventType as E
-
-    restricted = assoc.assoc_types() - {
-        int(E.TimerStarted), int(E.TimerFired), int(E.TimerCanceled),
-    }
-    # the fuzzed batches must actually contain nonaffine steps, or the
-    # seam is not exercised
-    present = {int(t) for t in packed.events[:, :, 0].ravel() if t >= 0}
-    _, non = assoc.classify_types(present, frozenset(restricted))
-    assert non, "fuzz batch has no timer events; raise target_events"
-
-    got = assoc.replay_assoc(
-        S.empty_state(packed.batch, CAPS), packed.time_major(),
-        affine_types=frozenset(restricted),
-    )
-    bad = _state_fields_equal(got, want)
-    assert bad is None, f"hybrid != scan in field {bad}"
-
-
-@pytest.mark.slow
-def test_assoc_depth_scaling_sublinear():
-    """The point of the tentpole: sequential-scan wall time is O(depth),
-    the associative path's is sublinear. At depth 8192 the assoc kernel
-    must beat the scan outright, and growing depth 8x from 1024 must
-    cost the assoc path well under 8x."""
-    import random
-    import time
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from cadence_tpu.ops import assoc
-    from cadence_tpu.ops import schema as S
-    from cadence_tpu.ops.replay import replay_scan_jit, type_signature
-    from cadence_tpu.testing import workloads as W
-
-    caps = S.Capacities(
-        max_events=8192, max_activities=4, max_timers=2, max_children=2,
-        max_request_cancels=2, max_signals_ext=2, max_version_items=2,
-    )
-    rng = random.Random(7)
-    histories = [
-        (f"wf-{i}", f"run-{i}", W.retry_deep_history(rng, depth=8000))
-        for i in range(8)
-    ]
-    packed = pack_histories(histories, caps=caps)
-    batch = packed.batch
-    types = type_signature(
-        int(t) for t in np.unique(packed.events[:, :, S.EV_TYPE])
-        if t >= 0)
-
-    def timed(fn, n=2):
-        jax.block_until_ready(fn())          # compile + warm
-        t0 = time.perf_counter()
-        for _ in range(n):
-            jax.block_until_ready(fn())
-        return (time.perf_counter() - t0) / n
-
-    def state0():
-        return jax.tree_util.tree_map(
-            jnp.asarray, S.empty_state(batch, caps))
-
-    def at_depth(d):
-        ev = packed.events[:, :d]
-        ev_tm = jnp.asarray(
-            np.ascontiguousarray(np.transpose(ev, (1, 0, 2))))
-        evf = jnp.asarray(assoc.events_fm_of(ev))
-        t_scan = timed(
-            lambda: replay_scan_jit(state0(), ev_tm, types=types))
-        s0 = state0()
-        t_assoc = timed(
-            lambda: assoc._assoc_core(evf, s0, types=types))
-        return t_scan, t_assoc
-
-    scan_1k, assoc_1k = at_depth(1024)
-    scan_8k, assoc_8k = at_depth(8192)
-    # parity at full depth first — a fast wrong kernel is worthless
-    evf = jnp.asarray(assoc.events_fm_of(packed.events))
-    got = jax.tree_util.tree_map(
-        np.asarray,
-        assoc._assoc_core(evf, state0(), types=types))
-    want = jax.tree_util.tree_map(
-        np.asarray,
-        replay_scan_jit(
-            state0(), jnp.asarray(packed.time_major()), types=types))
-    bad = _state_fields_equal(got, want)
-    assert bad is None, f"assoc != scan at depth 8192 in field {bad}"
-
-    assert assoc_8k < scan_8k, (
-        f"assoc ({assoc_8k * 1e3:.1f} ms) must beat the sequential scan "
-        f"({scan_8k * 1e3:.1f} ms) at depth 8192"
-    )
-    # 8x depth must cost well under 8x assoc wall time (sublinear);
-    # the scan, by contrast, scales ~linearly
-    assert assoc_8k < 6 * assoc_1k, (
-        f"assoc wall time not sublinear in depth: "
-        f"{assoc_1k * 1e3:.1f} ms @1k -> {assoc_8k * 1e3:.1f} ms @8k"
-    )
-
-
-def test_fuzz_shallow_lanes_assoc_parity():
-    """Shallow lane-packed batches (many short histories per lane) —
-    the shape on which the assoc path's provenance scatters used to
-    regress and auto held lanes back. Now both assoc impls must be
-    byte-identical to the sequential packed scan, AND the dispatcher's
-    scan_mode="auto" lane-packed pipeline must route them through the
-    associative kernel with identical bytes (the former gate held auto
-    on the sequential scan)."""
-    from cadence_tpu.ops import assoc
-    from cadence_tpu.ops.dispatch import replay_stream
-    from cadence_tpu.ops.pack import pack_lanes
-    from cadence_tpu.ops.replay import replay_packed
-
-    histories = []
-    for seed in range(40):
-        fz = HistoryFuzzer(seed=7000 + seed, caps=CAPS)
-        histories.append((
-            f"wf-{seed}", f"run-{seed}",
-            fz.generate(target_events=6 + seed % 7, close=seed % 2 == 0),
-        ))
-
-    lanes = pack_lanes(histories, caps=CAPS, target_lane_len=96)
-    assert max(len(s) for s in lanes.lane_segments) > 1, (
-        "not actually shallow-packed: need several histories per lane"
-    )
-    want = replay_packed(lanes, scan_mode="scan")
-    for impl in ("resolve", "segscan"):
-        got = assoc.replay_assoc_lanes(lanes, impl=impl)
-        bad = _state_fields_equal(got, want)
-        assert bad is None, (
-            f"shallow lanes assoc[{impl}] != scan in field {bad}"
-        )
-
-    # dispatcher auto now routes shallow lane-packed batches to assoc
-    import jax
-    import numpy as np
-
-    auto = replay_stream(histories, caps=CAPS, batch_size=40,
-                         lane_pack=True, lane_len=96)
-    scan = replay_stream(histories, caps=CAPS, batch_size=40,
-                         lane_pack=True, lane_len=96, scan_mode="scan")
-    assert len(auto) == len(scan) == 1
-    for a, b in zip(
-        jax.tree_util.tree_leaves(auto[0][1]),
-        jax.tree_util.tree_leaves(scan[0][1]),
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

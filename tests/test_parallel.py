@@ -92,20 +92,3 @@ def test_ndc_snapshot_exchange(packed, single_device_final):
     assert int(max_version) == int(
         single_device_final.exec_info[:, S.X_CUR_VERSION].max()
     )
-
-
-def test_batch_sharded_assoc_matches_scan(packed, single_device_final):
-    """scan_mode="assoc" across the mesh: the parallel-in-time kernel is
-    elementwise over the batch like the scan, so sharding it adds no
-    collectives and the result stays byte-identical."""
-    mesh = make_mesh(jax.devices()[:8], seq=1)
-    final_s, tasks_s = replay_packed_sharded(packed, mesh)
-    final_a, tasks_a = replay_packed_sharded(packed, mesh,
-                                             scan_mode="assoc")
-    assert_states_equal(final_a, final_s)
-    assert_states_equal(final_a, single_device_final)
-    for a, b in zip(
-        jax.tree_util.tree_leaves(tasks_a),
-        jax.tree_util.tree_leaves(tasks_s),
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

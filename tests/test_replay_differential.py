@@ -5,6 +5,8 @@ identical canonical snapshot to replaying the same batches through
 ``StateBuilder.apply_events`` host-side.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -42,21 +44,99 @@ def oracle_replay(batches, domain_id="dom", workflow_id="wf", run_id="run"):
     return ms
 
 
-def assert_parity(batches_per_workflow):
+KERNELS = ["scan", "pallas_teb", "pallas_packed"]
+
+# the Pallas kernels run interpreted, every batch padded to one scan
+# length and one tile, so that each traces once
+PALLAS_CAPS = S.Capacities(max_events=32)
+PALLAS_BT, PALLAS_TB = 1024, 8
+
+
+def _pallas_teb(histories):
+    """The Pallas teb kernel on the operands ``_replay_histories``
+    builds on a TPU: the packer's batch-major rows laid out by
+    ``teb_of_rows``, and the host's presence masks."""
+    import jax
+    import jax.numpy as jnp
+
+    from cadence_tpu.ops.replay import teb_of_rows
+    from cadence_tpu.ops.replay_pallas import replay_scan_pallas_teb
+
+    packed = pack_histories(histories, caps=PALLAS_CAPS,
+                            pad_batch_to=PALLAS_BT)
+    presence = packed.presence(PALLAS_BT)
+    assert presence is not None
+    final = replay_scan_pallas_teb(
+        jax.tree_util.tree_map(
+            jnp.asarray, S.empty_state(packed.batch, PALLAS_CAPS)),
+        teb_of_rows(jnp.asarray(packed.events.reshape(packed.batch, -1))),
+        PALLAS_CAPS, tb=PALLAS_TB, interpret=True, bt=PALLAS_BT,
+        presence=presence)
+    final = jax.tree_util.tree_map(np.asarray, final)
+    return [state_row_to_snapshot(final, i, packed.epoch_s)
+            for i in range(len(histories))]
+
+
+def _pallas_packed(histories, resume=None):
+    """The Pallas packed kernel on ``pack_lanes(..., seg_align=tb)``,
+    the lanes' time axis padded with invalid steps to the one scan
+    length."""
+    import jax
+    import jax.numpy as jnp
+
+    from cadence_tpu.ops.replay_pallas import replay_scan_pallas_packed
+
+    lanes = pack_lanes(histories, caps=PALLAS_CAPS, seg_align=PALLAS_TB)
+    T, L = PALLAS_CAPS.max_events, lanes.lanes
+    t = lanes.scan_len
+    assert t <= T
+    events = np.zeros((T, S.EV_N, L), np.int32)
+    events[:, S.EV_TYPE] = -1
+    events[:t] = lanes.teb()
+    seg_end = np.zeros((L, T), bool)
+    seg_end[:, :t] = lanes.seg_end
+    out_row = np.zeros((L, T), np.int32)
+    out_row[:, :t] = lanes.out_row
+    on_device = functools.partial(jax.tree_util.tree_map, jnp.asarray)
+    _, out = replay_scan_pallas_packed(
+        on_device(S.empty_state(L, PALLAS_CAPS)),
+        on_device(S.empty_state(round_scan_len(len(histories)),
+                                PALLAS_CAPS)),
+        jnp.asarray(events), jnp.asarray(seg_end), jnp.asarray(out_row),
+        PALLAS_CAPS, tb=PALLAS_TB, interpret=True, bt=PALLAS_BT)
+    return split_lane_snapshots(
+        lanes, jax.tree_util.tree_map(np.asarray, out))
+
+
+def kernel_snapshots(histories, kernel="scan"):
+    """Each history's snapshot after a replay through ``kernel``:
+    ``scan`` is ``replay_packed`` off the TPU (the XLA scan), the two
+    Pallas kernels are what the TPU runs."""
+    if kernel == "pallas_teb":
+        return _pallas_teb(histories)
+    if kernel == "pallas_packed":
+        return _pallas_packed(histories)
+    packed = pack_histories(histories)
+    final = replay_packed(packed)
+    return [state_row_to_snapshot(final, i, packed.epoch_s)
+            for i in range(len(histories))]
+
+
+def assert_parity(batches_per_workflow, kernel="scan"):
     """Replay every workflow both ways and compare snapshots."""
     histories = [
         (f"wf-{i}", f"run-{i}", batches)
         for i, batches in enumerate(batches_per_workflow)
     ]
-    packed = pack_histories(histories)
-    final = replay_packed(packed)
+    got = kernel_snapshots(histories, kernel)
     for i, (_, _, batches) in enumerate(histories):
-        kernel_snap = state_row_to_snapshot(final, i, packed.epoch_s)
+        kernel_snap = got[i]
         oracle_snap = mutable_state_to_snapshot(
             oracle_replay(batches, workflow_id=f"wf-{i}", run_id=f"run-{i}")
         )
         assert kernel_snap == oracle_snap, (
-            f"workflow {i} diverged:\nkernel={kernel_snap}\noracle={oracle_snap}"
+            f"workflow {i} diverged on {kernel}:\nkernel={kernel_snap}"
+            f"\noracle={oracle_snap}"
         )
 
 
@@ -310,13 +390,15 @@ ALL_SCENARIOS = [
 
 
 class TestKernelOracleParity:
+    @pytest.mark.parametrize("kernel", KERNELS)
     @pytest.mark.parametrize("scenario", ALL_SCENARIOS, ids=lambda f: f.__name__)
-    def test_single(self, scenario):
-        assert_parity([scenario()])
+    def test_single(self, scenario, kernel):
+        assert_parity([scenario()], kernel)
 
-    def test_mixed_batch(self):
+    @pytest.mark.parametrize("kernel", KERNELS)
+    def test_mixed_batch(self, kernel):
         """All scenarios in one padded, ragged device batch."""
-        assert_parity([fn() for fn in ALL_SCENARIOS])
+        assert_parity([fn() for fn in ALL_SCENARIOS], kernel)
 
     def test_batch_padding(self):
         histories = [("wf", "run", echo_batches())]
@@ -489,7 +571,8 @@ class TestTransitionCoverage:
     histories these tests generate, or the differential fuzz only
     *samples* the surface the checker *covers*."""
 
-    def test_continued_as_new_parity(self):
+    @pytest.mark.parametrize("kernel", KERNELS)
+    def test_continued_as_new_parity(self, kernel):
         """CaN is kernel-handled but needs new-run history on the
         oracle side, so it gets its own parity check (the shared
         assert_parity helper can't thread the new run through)."""
@@ -504,9 +587,7 @@ class TestTransitionCoverage:
         sb.apply_events(
             "dom", "req", "wf-can", "run-can", list(batches[-1]), new_run
         )
-        packed = pack_histories([("wf-can", "run-can", batches)])
-        final = replay_packed(packed)
-        got = state_row_to_snapshot(final, 0, packed.epoch_s)
+        (got,) = kernel_snapshots([("wf-can", "run-can", batches)], kernel)
         want = mutable_state_to_snapshot(ms)
         assert got == want
 

@@ -96,7 +96,7 @@ def test_device_layout_compiles_once_a_batch_shape():
 
 
 def test_tpu_branch_hands_the_kernel_the_host_scatters_operand(
-        monkeypatch):
+        request, monkeypatch):
     """``replay_packed``'s TPU branch, steered onto the CPU: the kernel
     (run interpreted) receives exactly ``teb()``, the answers equal the
     XLA scan's, and the layout span counts the bytes laid out on the
@@ -107,18 +107,17 @@ def test_tpu_branch_hands_the_kernel_the_host_scatters_operand(
         max_events=32, max_activities=2, max_timers=2, max_children=2,
         max_request_cancels=1, max_signals_ext=1, max_version_items=2)
     packed = _histories(caps, 6, 24, seed=8)
-    want = replay_packed(packed, scan_mode="scan")
+    want = replay_packed(packed)
 
-    real = replay_pallas.replay_scan_pallas_teb
+    request.getfixturevalue("tpu_branch_on_cpu")
+    interpreted = replay_pallas.replay_scan_pallas_teb
     seen = []
 
-    def interpreted(state, events, caps, **kw):
+    def recorded(state, events, caps, **kw):
         seen.append(np.asarray(events))
-        return real(state, events, caps, **dict(kw, interpret=True))
+        return interpreted(state, events, caps, **kw)
 
-    monkeypatch.setattr(replay_pallas, "replay_scan_pallas_teb",
-                        interpreted)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(replay_pallas, "replay_scan_pallas_teb", recorded)
     TRACER.configure(sample_rate=0.0)
     TRACER.clear()
     try:

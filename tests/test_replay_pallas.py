@@ -400,45 +400,3 @@ def test_narrow_wide_columns_split_exactly():
     ev2 = np.zeros((2, S.EV_N, 4), np.int32)
     ev2[0, S.EV_TYPE, 0] = 100000
     assert narrow_events_teb(ev2) is None
-
-
-def test_affine_segscan_pallas_blocked_combine():
-    """The blocked associative combine (interpret mode) must match the
-    XLA segmented associative scan on random affine-update streams —
-    resets mid-block, at block boundaries, and multi-block carries."""
-    import jax.numpy as jnp
-
-    from cadence_tpu.ops.assoc import affine_segscan
-    from cadence_tpu.ops.replay_pallas import affine_segscan_pallas
-
-    rng = np.random.default_rng(17)
-    T, L, C = 48, 8, 5
-    mul = jnp.asarray(rng.integers(0, 2, (T, L, C), dtype=np.int32))
-    add = jnp.asarray(rng.integers(-9, 99, (T, L, C), dtype=np.int32))
-    rst = jnp.asarray(rng.random((T, L)) < 0.2).at[0].set(True)
-    # force one reset exactly at a block boundary (carry must absorb)
-    rst = rst.at[16, 3].set(True)
-
-    rst3 = jnp.broadcast_to(rst[:, :, None], mul.shape)
-    want_m, want_a = affine_segscan(mul, add, rst3, axis=0)
-    got_m, got_a = affine_segscan_pallas(mul, add, rst, tb=8,
-                                         interpret=True)
-    np.testing.assert_array_equal(np.asarray(got_m), np.asarray(want_m))
-    np.testing.assert_array_equal(np.asarray(got_a), np.asarray(want_a))
-
-
-def test_affine_segscan_pallas_counter_semantics():
-    """A pure counter stream (mul=1, add=delta) must compose to prefix
-    sums with segment resets — the mul=1 special case of the algebra."""
-    import jax.numpy as jnp
-
-    from cadence_tpu.ops.replay_pallas import affine_segscan_pallas
-
-    T, L, C = 16, 4, 1
-    mul = jnp.ones((T, L, C), jnp.int32)
-    add = jnp.ones((T, L, C), jnp.int32)
-    rst = jnp.zeros((T, L), bool).at[0].set(True).at[8, 2].set(True)
-    _, got_a = affine_segscan_pallas(mul, add, rst, tb=8, interpret=True)
-    got = np.asarray(got_a)[:, 2, 0]
-    assert list(got[:8]) == list(range(1, 9))
-    assert list(got[8:]) == list(range(1, 9))  # reset restarted the sum

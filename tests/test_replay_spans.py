@@ -214,14 +214,24 @@ def _packed(n=6, depth=20, seed=7):
     return pack_histories(hs, caps=CAPS)
 
 
-@pytest.mark.parametrize("scan_mode", ["auto", "scan"])
-def test_replay_packed_spans_count_what_they_move(scan_mode):
+@pytest.mark.parametrize("branch", ["tpu_branch", "scan"])
+def test_replay_packed_spans_count_what_they_move(branch, monkeypatch):
+    """Each branch of ``_replay_histories`` tags what it moves: the XLA
+    scan lays its time-major events out on the host, the TPU branch
+    (steered onto the CPU, its kernel stubbed) ships the packer's rows
+    as they are and lays them out on the device."""
+    from cadence_tpu.ops import replay_pallas
     from cadence_tpu.ops.grid import round_scan_len
     from cadence_tpu.ops.replay import replay_packed
 
     packed = _packed()
+    tpu = branch == "tpu_branch"
+    if tpu:
+        monkeypatch.setattr(replay_pallas, "on_tpu", lambda: True)
+        monkeypatch.setattr(replay_pallas, "replay_scan_pallas_teb",
+                            lambda state, events, caps, **kw: state)
     with TRACER.trace("caller", sampled=True) as root:
-        final = replay_packed(packed, scan_mode=scan_mode)
+        final = replay_packed(packed)
     spans = _trace_of(root)
     assert {s.name for s in spans} == REPLAY_SPANS | {"caller"}
     (top,) = _byname(spans, "replay_packed")
@@ -231,11 +241,21 @@ def test_replay_packed_spans_count_what_they_move(scan_mode):
     for s in spans:
         if s.name.startswith("replay."):
             assert s.parent_id == top.span_id and s.thread == top.thread
-    bp = round_scan_len(packed.batch)
+    bp = packed.batch if tpu else round_scan_len(packed.batch)
     T = packed.events.shape[1]
     ev_bytes = S.EV_N * bp * T * 4
-    (layout,) = _byname(spans, "replay.layout")
-    assert layout.tags == {"bytes": ev_bytes, "device_bytes": 0}
+    layouts = _byname(spans, "replay.layout")
+    if tpu:
+        # no byte laid out on the host, every event byte on the device
+        # (six rows are no whole tile, so the kernel makes its own
+        # presence masks)
+        assert [s.tags for s in layouts] == [
+            {"bytes": 0, "device_bytes": 0},
+            {"bytes": 0, "device_bytes": ev_bytes},
+        ]
+    else:
+        assert [s.tags for s in layouts] == [
+            {"bytes": ev_bytes, "device_bytes": 0}]
     # the state goes first, its copy overlapping the layout; the events
     # after it (the grid's padding rows are made on the device)
     state_bytes = sum(
@@ -243,9 +263,10 @@ def test_replay_packed_spans_count_what_they_move(scan_mode):
         jax.tree_util.tree_leaves(S.empty_state(packed.batch, CAPS)))
     h2d = _byname(spans, "replay.h2d")
     assert [s.tags["bytes"] for s in h2d] == [state_bytes, ev_bytes]
-    assert h2d[0].start_s < layout.start_s < h2d[1].start_s
+    assert h2d[0].start_s < layouts[0].start_s < h2d[1].start_s
     (launch,) = _byname(spans, "replay.launch")
-    assert launch.tags == {"events": events, "cells": bp * T}
+    assert launch.tags == (
+        {"events": events} if tpu else {"events": events, "cells": bp * T})
     (fetch,) = _byname(spans, "replay.fetch")
     assert fetch.tags == {"bytes": sum(
         int(x.nbytes) for x in jax.tree_util.tree_leaves(final))}
@@ -253,7 +274,7 @@ def test_replay_packed_spans_count_what_they_move(scan_mode):
     assert kids <= top.dur_us
 
 
-def test_pallas_kernels_tag_the_cells_they_stream():
+def test_pallas_kernels_tag_the_cells_they_stream(tpu_branch_on_cpu):
     """Both Pallas kernels count what they stream after tile padding;
     the teb kernel pads the batch to its tile and time to its block."""
     import jax.numpy as jnp
@@ -267,8 +288,7 @@ def test_pallas_kernels_tag_the_cells_they_stream():
     hs = [(f"wf-{i}", f"run-{i}", W.retry_deep_history(rng, depth=8))
           for i in range(3)]
     with TRACER.trace("caller", sampled=True) as root:
-        with DeviceDispatcher(caps=caps, kernel="pallas", bt=1024,
-                              tb=8) as d:
+        with DeviceDispatcher(caps=caps, bt=1024, tb=8) as d:
             d.submit(0, hs)
             d.finish()
             ((_, packed, _),) = list(d.results())

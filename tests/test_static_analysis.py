@@ -250,88 +250,6 @@ class TestTransitionSurface:
 
 
 # --------------------------------------------------------------------------
-# pass 1b — ASSOC-UNPROVEN (affine-decomposition coverage)
-# --------------------------------------------------------------------------
-
-
-class TestAssocCoverage:
-    def test_clean_tree(self, surface):
-        kmat, _, _, _ = surface
-        assert transition_surface.check_assoc_coverage(kmat) == []
-
-    def test_assoc_types_cover_every_kernel_block(self):
-        from cadence_tpu.ops.assoc import assoc_types
-
-        handled = transition_surface.kernel_handled_types()
-        assert handled <= assoc_types(), (
-            "kernel transition blocks outside the affine classifier"
-        )
-
-    def test_uncovered_write_fires(self, surface):
-        import dataclasses
-
-        from cadence_tpu.core.enums import EventType as E
-
-        kmat, _, _, _ = surface
-        groups = []
-        for g in kmat.groups:
-            w = set(g.written)
-            if int(E.TimerStarted) in g.types:
-                # pretend the kernel's TimerStarted block grew an exec
-                # write the emission never derived
-                w.add("exec:X_WORKFLOW_TIMEOUT")
-            groups.append(dataclasses.replace(g, written=w))
-        bad = transition_surface.KernelMatrix(
-            common=set(kmat.common), common_ts=set(kmat.common_ts),
-            groups=groups,
-        )
-        fs = transition_surface.check_assoc_coverage(bad)
-        assert any(
-            f.rule == "ASSOC-UNPROVEN" and f.anchor.endswith(":writes")
-            and "X_WORKFLOW_TIMEOUT" in f.message
-            for f in fs
-        ), fs
-
-    def test_unproven_group_fires(self, surface):
-        from cadence_tpu.core.enums import EventType as E
-
-        kmat, _, _, _ = surface
-        bad = transition_surface.KernelMatrix(
-            common=set(kmat.common), common_ts=set(kmat.common_ts),
-            groups=list(kmat.groups) + [transition_surface.GroupTrace(
-                types=(int(E.MarkerRecorded),),
-                written={"exec:X_STATE"}, ts_cols=set(),
-            )],
-        )
-        fs = transition_surface.check_assoc_coverage(bad)
-        assert any(
-            f.rule == "ASSOC-UNPROVEN" and f.anchor.endswith(":group")
-            for f in fs
-        ), fs
-
-    def test_stale_algebra_metadata_fires(self, surface, monkeypatch):
-        from cadence_tpu.ops import schema as S
-
-        kmat, _, _, _ = surface
-        monkeypatch.setitem(
-            S.UPDATE_ALGEBRA, "timers:TI_STATUS", "counter")
-        fs = transition_surface.check_assoc_coverage(kmat)
-        assert any(
-            f.rule == "ASSOC-UNPROVEN"
-            and f.anchor == "assoc:algebra:timers:TI_STATUS"
-            for f in fs
-        ), fs
-
-    def test_update_algebra_values_validated(self):
-        from cadence_tpu.ops import schema as S
-
-        ns = dict(vars(S))
-        ns["UPDATE_ALGEBRA"] = {"exec:X_STATE": "quantum"}
-        with pytest.raises(AssertionError, match="quantum"):
-            S.validate(ns)
-
-
-# --------------------------------------------------------------------------
 # pass 2 — jit hazards
 # --------------------------------------------------------------------------
 

@@ -358,10 +358,7 @@ class TestDeviceTelemetry:
         from cadence_tpu.ops.dispatch import replay_stream
 
         metrics = Scope()
-        out = replay_stream(
-            self._histories(), batch_size=3, kernel="xla",
-            metrics=metrics,
-        )
+        out = replay_stream(self._histories(), batch_size=3, metrics=metrics)
         assert len(out) == 2
         reg = metrics.registry
         assert reg.counter_value("device_batches") == 2
@@ -386,8 +383,7 @@ class TestDeviceTelemetry:
 
         metrics = Scope()
         out = replay_stream(
-            self._histories(), batch_size=6, kernel="xla",
-            lane_pack=True, lane_len=32, scan_mode="scan",
+            self._histories(), batch_size=6, lane_pack=True, lane_len=32,
             metrics=metrics,
         )
         reg = metrics.registry
@@ -414,8 +410,8 @@ class TestDeviceTelemetry:
         monkeypatch.setattr(jax, "block_until_ready", refuse)
         metrics = Scope()
         out = replay_stream(
-            self._histories(), batch_size=3, kernel="xla",
-            lane_pack=True, lane_len=32, metrics=metrics,
+            self._histories(), batch_size=3, lane_pack=True, lane_len=32,
+            metrics=metrics,
         )
         assert len(out) == 2
         assert metrics.registry.counter_value("device_batches") == 2

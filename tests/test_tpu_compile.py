@@ -192,24 +192,12 @@ def test_replay_packed_storm_buckets(one_chip, activities):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("shape", [(64, 128, 8), (T, B, 24), (T, 1024, 128)])
-def test_affine_segscan(one_chip, shape):
-    from cadence_tpu.ops.replay_pallas import affine_segscan_pallas
-
-    t, lanes, cols = shape
-    text = _compile(
-        lambda m, a, r: affine_segscan_pallas(m, a, r, interpret=False),
-        _sds(shape, jnp.int32, one_chip), _sds(shape, jnp.int32, one_chip),
-        _sds((t, lanes), jnp.int32, one_chip))
-    assert "tpu_custom_call" in text
-
-
 def test_replay_sharded_is_shard_local(mesh4):
     from cadence_tpu.parallel import replay_sharded_fn
     from cadence_tpu.parallel.mesh import events_spec, shard_spec
 
     caps = RETRY_CAPS
-    fn = replay_sharded_fn(mesh4, "scan")
+    fn = replay_sharded_fn(mesh4)
     text = fn.lower(
         _state_sds(4 * B, caps, shard_spec(mesh4)),
         _sds((T, 4 * B, S.EV_N), jnp.int32, events_spec(mesh4)),

@@ -18,12 +18,7 @@ from cadence_tpu.checkpoint import CheckpointManager, MemoryCheckpointStore
 from cadence_tpu.core import history_factory as F
 from cadence_tpu.core.enums import EventType
 from cadence_tpu.ops import schema as S
-from cadence_tpu.ops.dispatch import (
-    DeviceDispatcher,
-    buckets,
-    depth_buckets,
-    history_depth,
-)
+from cadence_tpu.ops.dispatch import buckets, depth_buckets, history_depth
 from cadence_tpu.ops.grid import round_scan_len
 from cadence_tpu.ops.pack import (
     SLOT_TABLES,
@@ -183,22 +178,13 @@ def _counting(rb):
     return calls, host
 
 
-@pytest.fixture(params=["auto", "scan", "pallas"])
-def kernel_path(request, monkeypatch):
-    """rebuild_many's dispatcher on the CPU: the default path (the
-    associative kernels), the sequential XLA packed scan, or the Pallas
-    packed kernel in interpret mode at small tiles."""
-    init = DeviceDispatcher.__init__
-
-    def patched(self, *a, **k):
-        init(self, *a, **k)
-        if request.param == "scan":
-            self.scan_mode = "scan"
-        elif request.param == "pallas":
-            self._kernel = "pallas"
-            self.bt, self.tb = 1024, 8
-
-    monkeypatch.setattr(DeviceDispatcher, "__init__", patched)
+@pytest.fixture(params=["xla", "pallas"])
+def kernel_path(request):
+    """rebuild_many's dispatcher on the CPU: the XLA packed scan, or the
+    Pallas packed kernel (its TPU branch) in interpret mode at small
+    tiles."""
+    if request.param == "pallas":
+        request.getfixturevalue("tpu_branch_on_cpu")
     return request.param
 
 
