@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 from .enums import EventType
 from .ids import EMPTY_EVENT_TASK_ID
@@ -91,8 +91,58 @@ def encode_batch(events: Iterable[HistoryEvent]) -> bytes:
     return json.dumps([e.to_dict() for e in events], separators=(",", ":")).encode()
 
 
+_EVENT_TYPES = {int(t): t for t in EventType}
+
+
+def _restore_bytes(d: Dict[str, Any]) -> Any:
+    """``object_hook``: the tag ``_jsonable`` writes for bytes, undone."""
+    if len(d) == 1 and "__bytes__" in d:
+        return d["__bytes__"].encode("latin-1")
+    return d
+
+
+def _parse(data: bytes) -> Any:
+    text = data.decode()
+    # a key can only read "__bytes__" if it is written so, or escaped
+    if b'"__bytes__"' in data or b"\\u" in data:
+        return json.loads(text, object_hook=_restore_bytes)
+    return json.loads(text)
+
+
+def _events(batch: List[Dict[str, Any]]) -> List[HistoryEvent]:
+    out = []
+    for d in batch:
+        eid, et = d["event_id"], d["event_type"]
+        try:
+            et = _EVENT_TYPES[et]
+        except (KeyError, TypeError):
+            et = EventType(et)  # raises as it always did
+        out.append(HistoryEvent(
+            eid, et, d["version"], d["timestamp"],
+            d.get("task_id", EMPTY_EVENT_TASK_ID), d.get("attributes", {}),
+        ))
+    return out
+
+
+def decode_batches(blobs: Sequence[bytes]) -> List[List[HistoryEvent]]:
+    """Decode a page of stored batches with one JSON parse.
+
+    Each blob is a JSON array, so the page joined into one array parses
+    to a list of batches; the bytes tags are restored during the parse,
+    and only when the page holds one. A page that does not split into
+    one batch per blob, or fails, is decoded blob by blob, so a corrupt
+    blob raises what it raises alone."""
+    try:
+        batches = _parse(b"[" + b",".join(blobs) + b"]")
+        if len(batches) == len(blobs):
+            return [_events(b) for b in batches]
+    except (ValueError, TypeError, KeyError, AttributeError):
+        pass  # a corrupt blob: decoded alone below, it raises as it did
+    return [_events(_parse(b)) for b in blobs]
+
+
 def decode_batch(blob: bytes) -> List[HistoryEvent]:
-    return [HistoryEvent.from_dict(d) for d in json.loads(blob.decode())]
+    return decode_batches([blob])[0]
 
 
 @dataclasses.dataclass

@@ -14,7 +14,7 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
-from cadence_tpu.core.events import HistoryEvent, decode_batch, encode_batch
+from cadence_tpu.core.events import HistoryEvent, decode_batches, encode_batch
 from cadence_tpu.core.tasks import ReplicationTask, TimerTask, TransferTask
 from cadence_tpu.utils.locks import make_guarded, make_rlock
 
@@ -640,7 +640,7 @@ class MemoryHistoryManager(I.HistoryManager):
                 token = collected[page_size][0]
             else:
                 page, token = collected, 0
-            return [decode_batch(blob) for _, blob in page], token
+            return decode_batches([blob for _, blob in page]), token
 
     def fork_history_branch(
         self, branch: BranchToken, fork_node_id: int

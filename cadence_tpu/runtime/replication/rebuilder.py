@@ -27,8 +27,9 @@ failure degrades that request to a full replay.
 Tracing: ``rebuild_many`` is a trace entry point (utils/tracing.py
 ``Tracer.entry``) — a child of the caller's span, else a root at the
 tracer's sample rate — with spans on the caller's thread for the reads
-(``rebuild.read``), the width and depth grouping (``dispatch.bucket``),
-each wait on the dispatcher (``rebuild.await``),
+(``rebuild.read``, tagged ``nodes`` and ``pages``: the batches read and
+the store reads that fetched them), the width and depth grouping
+(``dispatch.bucket``), each wait on the dispatcher (``rebuild.await``),
 each device batch's one fetch of its final state to the host
 (``rebuild.fetch``, tagged ``histories`` and ``bytes``), each row's
 unpack from those host arrays (``rebuild.unpack``) and task refresh
@@ -38,6 +39,7 @@ the dispatcher's pumps add theirs under the same trace.
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from cadence_tpu.core.events import HistoryEvent
@@ -147,7 +149,10 @@ class StateRebuilder:
 
     def _read_batches(
         self, req: RebuildRequest, min_event_id: int = 1,
+        tally: Optional[Counter] = None,
     ) -> List[List[HistoryEvent]]:
+        """Page the run's batches out of the store; ``tally`` counts the
+        ``pages`` read (one JSON parse each) and the ``nodes`` in them."""
         branch = BranchToken.from_json(req.branch_token.decode())
         out: List[List[HistoryEvent]] = []
         token = 0
@@ -157,6 +162,9 @@ class StateRebuilder:
                 page_size=256, next_token=token,
             )
             out.extend(batches)
+            if tally is not None:
+                tally["pages"] += 1
+                tally["nodes"] += len(batches)
             if not token:
                 return out
 
@@ -327,12 +335,15 @@ class StateRebuilder:
 
         # consult checkpoints, read only what must be replayed
         with TRACER.span("rebuild.read") as sp:
+            tally: Counter = Counter()
             histories, resumes, pend_req = self._read_pending(
-                reqs, caps, out)
+                reqs, caps, out, tally)
             if sp:
                 sp.set_tag("histories", len(histories))
                 sp.set_tag("events", sum(
                     len(b) for h in histories for b in h[2]))
+                sp.set_tag("nodes", tally["nodes"])
+                sp.set_tag("pages", tally["pages"])
 
         # storm drain: bucket the stream by slot-table width (default
         # caps are the floor: a fan-out parent's wide state pads no
@@ -421,12 +432,12 @@ class StateRebuilder:
             span.set_tag("host_fallbacks", fallbacks)
         return out
 
-    def _read_pending(self, reqs, caps, out):
+    def _read_pending(self, reqs, caps, out, tally):
         """Consult serving and checkpoints per request and read only
         what must be replayed. Fills ``out`` for requests answered
         without the device; returns the pending (wf, run, suffix
         batches), their Optional[ResumeState]s, and each one's request
-        index."""
+        index. Every read counts in ``tally``."""
         histories = []           # pending (wf, run, suffix batches)
         resumes = []             # aligned Optional[ResumeState]
         pend_req: List[int] = []  # pending index -> request index
@@ -437,21 +448,21 @@ class StateRebuilder:
                 continue
             ckpt = self._consult_checkpoint(r, caps)
             if ckpt is None:
-                batches = self._read_batches(r)
+                batches = self._read_batches(r, tally=tally)
                 resume = None
             else:
                 try:
                     batches = self._read_batches(
-                        r, min_event_id=ckpt.event_id + 1
+                        r, min_event_id=ckpt.event_id + 1, tally=tally
                     )
                     resume = self.checkpoints.resume_state(ckpt)
                 except Exception:  # degraded store/decode: full replay
-                    batches, resume = self._read_batches(r), None
+                    batches, resume = self._read_batches(r, tally=tally), None
                     self._degrade_hit()
                 if resume is not None and not _fits(batches, resume, caps):
                     # the suffix outgrows the snapshot's caps, and a
                     # wider bucket cannot take its row: full replay
-                    batches, resume = self._read_batches(r), None
+                    batches, resume = self._read_batches(r, tally=tally), None
                     self._degrade_hit()
                 if resume is not None and not batches:
                     # tip hit: nothing to replay — rehydrate directly
@@ -465,7 +476,7 @@ class StateRebuilder:
                         self._commit_hit(ckpt)
                         continue
                     except Exception:
-                        batches, resume = self._read_batches(r), None
+                        batches, resume = self._read_batches(r, tally=tally), None
                         self._degrade_hit()
                 if resume is not None:
                     self._commit_hit(ckpt)

@@ -4,9 +4,12 @@ One suite, N backends — the reference's persistence-tests pattern
 (/root/reference/common/persistence/persistence-tests/): every test runs
 against both the memory and sqlite bundles via the fixture param."""
 
+import json
+
 import pytest
 
 from cadence_tpu.core import history_factory as F
+from cadence_tpu.core.events import encode_batch
 from cadence_tpu.core.enums import TimerTaskType, TransferTaskType
 from cadence_tpu.core.tasks import ReplicationTask, TimerTask, TransferTask
 from cadence_tpu.runtime.persistence import (
@@ -29,6 +32,7 @@ from cadence_tpu.runtime.persistence import (
     create_memory_bundle,
     create_sqlite_bundle,
 )
+from cadence_tpu.testing.event_generator import HistoryFuzzer
 
 SHARD = 1
 RANGE = 1
@@ -290,6 +294,73 @@ def test_history_fork(bundle):
     assert len(h.get_history_tree("tree1")) == 2
     h.delete_history_branch(fork)
     assert len(h.get_history_tree("tree1")) == 1
+
+
+def _mixed_history():
+    """Fuzzed batches of several depths, then batches whose attributes
+    hold bytes nested in dicts and lists: more than two 256-node pages."""
+    fuzzer = HistoryFuzzer(seed=5)
+    batches, eid = [], 1
+    while len(batches) < 600:
+        for batch in fuzzer.generate(target_events=120):
+            shift = eid - batch[0].event_id
+            for e in batch:
+                e.event_id += shift
+            eid = batch[-1].event_id + 1
+            batches.append(batch)
+    for i in range(40):
+        marker = F.marker_recorded(
+            eid, 10, 1_700_000_000_000_000_000, marker_name="__bytes__",
+            details=b"\x00\xff")
+        marker.attributes["details"] = [b"\x00\xff", {"k": b"v"}]
+        marker.attributes["header"] = {"fields": {"trace": b"t%d" % i}}
+        batches.append([marker])
+        eid += 1
+    return batches
+
+
+def _set_node_blob(h, branch, node_id, blob):
+    """Overwrite one stored node's bytes (backend-peeking)."""
+    if hasattr(h, "_nodes"):  # memory backend
+        nodes = h._nodes[(branch.tree_id, branch.branch_id)]
+        nodes[node_id] = (nodes[node_id][0], blob)
+        return
+    with h.db.txn() as c:  # sqlite backend
+        c.execute(
+            "UPDATE history_nodes SET blob=? WHERE tree_id=? AND "
+            "branch_id=? AND node_id=?",
+            (blob, branch.tree_id, branch.branch_id, node_id),
+        )
+
+
+def test_history_pages_round_trip(bundle):
+    h = bundle.history
+    branch = h.new_history_branch("tree-pages")
+    written = _mixed_history()
+    for txn, batch in enumerate(written, start=1):
+        h.append_history_nodes(branch, batch, transaction_id=txn)
+    got, tokens, token = [], [], 0
+    while True:
+        page, token = h.read_history_branch(
+            branch, 1, 1 << 60, page_size=256, next_token=token)
+        got += page
+        tokens.append(token)
+        if not token:
+            break
+    assert got == written
+    # a page's token is the node id (first event id) of the next page
+    ids = [b[0].event_id for b in written]
+    assert tokens == [ids[256], ids[512], 0]
+    assert isinstance(got[-1][0].attributes["details"][0], bytes)
+
+    # a corrupt blob in a page raises as decoding it alone always has
+    _set_node_blob(h, branch, ids[300], encode_batch(written[300])[:-9])
+    with pytest.raises(json.JSONDecodeError):
+        h.read_history_branch(branch, 1, 1 << 60, page_size=256,
+                              next_token=ids[256])
+    # the page before it still reads
+    page, token = h.read_history_branch(branch, 1, 1 << 60, page_size=256)
+    assert page == written[:256] and token == ids[256]
 
 
 def _tree_node_count(h, tree_id):

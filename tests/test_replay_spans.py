@@ -56,17 +56,18 @@ def stored():
     bundle = create_memory_bundle()
     history = bundle.history
     fuzzer = HistoryFuzzer(seed=41)
-    reqs, events = [], 0
+    reqs, events, nodes = [], 0, 0
     for i in range(7):
         batches = fuzzer.generate(target_events=12 if i % 3 else 40)
         events += sum(len(b) for b in batches)
+        nodes += len(batches)
         branch = history.new_history_branch(tree_id=f"run-{i}")
         for txn, batch in enumerate(batches, start=1):
             history.append_history_nodes(branch, batch, transaction_id=txn)
         reqs.append(RebuildRequest(
             domain_id="dom", workflow_id=f"wf-{i}", run_id=f"run-{i}",
             branch_token=branch.to_json().encode()))
-    yield StateRebuilder(history, lane_len=128), reqs, events
+    yield StateRebuilder(history, lane_len=128), reqs, events, nodes
     bundle.close()
 
 
@@ -84,7 +85,7 @@ def test_rebuild_many_spans_join_one_trace_across_the_pumps(
 
     from cadence_tpu.ops import replay
 
-    rb, reqs, events = stored
+    rb, reqs, events, nodes = stored
     moved, launched = [], []
     to_device, launch = replay.to_device, DeviceDispatcher._launch
 
@@ -110,7 +111,8 @@ def test_rebuild_many_spans_join_one_trace_across_the_pumps(
     assert top.tags == {"requests": 7, "device_histories": 7,
                         "wide_histories": 0, "host_fallbacks": 0}
     (read,) = _byname(spans, "rebuild.read")
-    assert read.tags == {"histories": 7, "events": events}
+    assert read.tags == {"histories": 7, "events": events,
+                         "nodes": nodes, "pages": 7}
     assert len(_byname(spans, "rebuild.unpack")) == 7
     assert len(_byname(spans, "rebuild.refresh")) == 7
     for s in spans:
@@ -152,13 +154,48 @@ def test_rebuild_many_spans_join_one_trace_across_the_pumps(
     assert min(s.start_s for s in fetches) < first_unpack
 
 
+def test_rebuild_read_counts_the_nodes_and_pages_it_reads(monkeypatch):
+    """``rebuild.read`` carries ``nodes`` (the batches read) and
+    ``pages`` (the store reads, one JSON parse each); a deep history
+    reads in several 256-node pages."""
+    bundle = create_memory_bundle()
+    history = bundle.history
+    stored = [W.retry_deep_history(random.Random(11), depth=600),
+              HistoryFuzzer(seed=3).generate(target_events=30)]
+    assert len(stored[0]) > 512
+    reqs = []
+    for i, batches in enumerate(stored):
+        branch = history.new_history_branch(tree_id=f"deep-{i}")
+        for txn, batch in enumerate(batches, start=1):
+            history.append_history_nodes(branch, batch, transaction_id=txn)
+        reqs.append(RebuildRequest(
+            domain_id="dom", workflow_id=f"wf-{i}", run_id=f"run-{i}",
+            branch_token=branch.to_json().encode()))
+    reads = []
+    read = history.read_history_branch
+
+    def spy(*a, **k):
+        batches, token = read(*a, **k)
+        reads.append(len(batches))
+        return batches, token
+
+    monkeypatch.setattr(history, "read_history_branch", spy)
+    with TRACER.trace("caller", sampled=True) as root:
+        out = StateRebuilder(history, lane_len=1024).rebuild_many(reqs)
+    assert all(o is not None for o in out)
+    (sp,) = _byname(_trace_of(root), "rebuild.read")
+    assert sp.tags["nodes"] == sum(reads) == sum(len(b) for b in stored)
+    assert sp.tags["pages"] == len(reads) == 3 + 1
+    bundle.close()
+
+
 def test_rebuild_many_unpacks_rows_from_host_arrays(stored, monkeypatch):
     """Each row is unpacked from the batch's host copy, so no row costs
     a device gather; the answers are the host replayer's."""
     from cadence_tpu.ops import unpack
     from cadence_tpu.ops.unpack import mutable_state_to_snapshot
 
-    rb, reqs, _ = stored
+    rb, reqs, *_ = stored
     seen = []
     orig = unpack.state_row_to_mutable_state
 
@@ -176,7 +213,7 @@ def test_rebuild_many_unpacks_rows_from_host_arrays(stored, monkeypatch):
 
 
 def test_rebuild_many_roots_its_own_trace_at_the_sample_rate(stored):
-    rb, reqs, _ = stored
+    rb, reqs, *_ = stored
     TRACER.configure(sample_rate=1.0)
     rb.rebuild_many(reqs[:2])
     (top,) = [s for s in TRACER.spans() if s.name == "rebuild_many"]
@@ -188,7 +225,7 @@ def test_fallback_span_counts_the_batch_rebuilt_on_the_host(
         stored, monkeypatch):
     from cadence_tpu.ops.dispatch import DeviceDispatcher
 
-    rb, reqs, _ = stored
+    rb, reqs, *_ = stored
 
     def broken(self, *a, **k):
         raise RuntimeError("launch refused")
@@ -332,7 +369,7 @@ def test_unsampled_paths_build_no_span_and_open_no_annotation(
     monkeypatch.setattr(tracing, "Span", CountingSpan)
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
     TRACER.set_profiler_prefix("t.")  # on, but nothing is sampled
-    rb, reqs, _ = stored
+    rb, reqs, *_ = stored
     assert TRACER.current() is None and TRACER.sample_rate == 0.0
     rb.rebuild_many(reqs)
     replay_packed(_packed())
@@ -347,7 +384,7 @@ def test_unsampled_paths_build_no_span_and_open_no_annotation(
 def test_annotations_land_on_the_profilers_host_planes(stored, tmp_path):
     from jax.profiler import ProfileData
 
-    rb, reqs, _ = stored
+    rb, reqs, *_ = stored
     rb.rebuild_many(reqs[:2])  # compile outside the profile
     TRACER.set_profiler_prefix("t.")
     jax.profiler.start_trace(str(tmp_path))
